@@ -37,7 +37,8 @@ Three instruments, one module:
    avals, flops omitted) — never a crash. :func:`roofline` joins the
    analytic cost with measured dispatch time into per-executable MFU,
    arithmetic intensity, and a compute-bound vs HBM-bound verdict
-   against the platform roof (:func:`set_roof` to override);
+   against the device's roof (:data:`DEVICE_PEAKS`, keyed by
+   ``device_kind``; :func:`set_roof` to override);
    :func:`ledger` flattens it into the ``xla`` entry of
    ``OpProfiler.LEDGERS`` so ``/api/health``, ``/api/metrics`` and
    ``print_statistics`` all carry it for free. CAVEATS: dispatch wall
@@ -243,15 +244,39 @@ EXEC_SITES: Dict[str, Dict[str, str]] = {
         "drill": "test_xprof counted sub-executable test"},
 }
 
-#: Platform rooflines: (peak flops/s, peak memory bytes/s). The TPU row
-#: is the published v5e bf16 peak + HBM bandwidth; the CPU row is a
-#: NOMINAL single-core planning roof for the build container (MFU/bound
-#: verdicts against it are approximate by construction — override with
-#: :func:`set_roof` when the host is characterized).
-PLATFORM_ROOFS: Dict[str, Tuple[float, float]] = {
-    "tpu": (197e12, 819e9),
+#: Device rooflines keyed by ``jax.devices()[0].device_kind``:
+#: (peak flops/s, peak memory bytes/s) of ONE chip. The one table of
+#: peaks in the repository (``bench.py`` imports it).
+#:
+#: - ``"TPU v5 lite"`` is how JAX names a TPU v5e chip. Google Cloud
+#:   documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s of HBM.
+#: - ``"cpu"`` is a NOMINAL single-core planning roof for the CPU drills
+#:   (MFU/bound verdicts against it are approximate by construction and
+#:   say nothing about a chip — override with :func:`set_roof` when the
+#:   host is characterized).
+#:
+#: A device that is not in the table is an error, not a default.
+DEVICE_PEAKS: Dict[str, Tuple[float, float]] = {
+    "TPU v5 lite": (197e12, 819e9),
     "cpu": (5e10, 2e10),
 }
+
+
+def device_peaks(device=None) -> Tuple[float, float]:
+    """``(peak flops/s, peak bytes/s)`` of ``device`` (default: the first
+    JAX device) from :data:`DEVICE_PEAKS`; raises for a device kind the
+    table does not hold."""
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    try:
+        return DEVICE_PEAKS[device.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device.device_kind!r} "
+            f"(platform {device.platform!r}): add its row, with its "
+            "source, to common.xprof.DEVICE_PEAKS") from None
 
 
 def _now() -> float:
@@ -341,16 +366,10 @@ class ExecutableCensus:
         with self._lock:
             self._roof = (float(peak_flops), float(peak_bytes_per_s))
 
-    def _platform_roof(self) -> Tuple[Optional[float], Optional[float]]:
+    def _platform_roof(self) -> Tuple[float, float]:
         if self._roof is not None:
             return self._roof
-        try:
-            import jax
-
-            plat = jax.devices()[0].platform
-        except Exception:
-            plat = "cpu"
-        return PLATFORM_ROOFS.get(plat, PLATFORM_ROOFS["cpu"])
+        return device_peaks()
 
     # -- registration -----------------------------------------------------
     def _entry(self, name: str) -> _Entry:

@@ -15,9 +15,8 @@ total record count. Every chunk stores ``chunk_records`` records (the last
 one fewer) column-major: all of column 0's records contiguously, then
 column 1, ... Fixed shapes + raw dtypes mean the reader is a ``np.memmap``
 slice-and-reshape — no parsing, no decode; training reads run at page-cache
-speed, which is what makes a disk-fed ResNet TPU-bound instead of
-PIL-decode-bound (BASELINE.md round-3 disk row: 34 img/s on this 1-core
-host vs ~2.5k device-resident).
+speed, which is meant to make a disk-fed ResNet TPU-bound instead of
+PIL-decode-bound (the disk-fed rate is not measured on this chip).
 
 Components:
 - :class:`BinaryRecordWriter` — streaming writer.
@@ -247,7 +246,7 @@ class BinaryRecordDataSetIterator:
         # raw_numpy=True yields (x, y) numpy tuples instead of DataSet:
         # DataSet/NDArray construction eagerly device-puts, which must NOT
         # happen on a prefetch worker thread (AsyncDataSetIterator stages
-        # raw tuples consumer-side; see its round-4 relay notes)
+        # raw tuples consumer-side; see the note in its __iter__)
         self.raw_numpy = bool(raw_numpy)
         names = [n for n, _, _ in self._c.columns]
         for col in (feature_col, label_col):

@@ -17,6 +17,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import warnings
 from typing import Optional, Tuple
 
 import numpy as np
@@ -26,6 +27,7 @@ _SRC = os.path.join(_HERE, "datavec_native.cpp")
 
 _lib = None
 _tried = False
+_error: Optional[str] = None
 
 
 # Sanitizer build flavor (SURVEY §5.2: ASAN/UBSAN flavors for native code,
@@ -42,19 +44,33 @@ def _so_path() -> str:
         _HERE, f"libdatavec_native{'_' + _SANITIZE if _SANITIZE else ''}.so")
 
 
-def _build() -> bool:
+def _build() -> Optional[str]:
+    """Compile the library from ``datavec_native.cpp``; returns None on
+    success, else what went wrong."""
     flags = ["-O3"]
     if _SANITIZE:
         flags = ["-O1", "-g", f"-fsanitize={_SANITIZE}",
                  "-fno-omit-frame-pointer"]
+    cmd = ["g++", *flags, "-shared", "-fPIC", "-std=c++17", _SRC,
+           "-o", _so_path()]
     try:
-        subprocess.run(
-            ["g++", *flags, "-shared", "-fPIC", "-std=c++17", _SRC,
-             "-o", _so_path()],
-            check=True, capture_output=True, timeout=120)
-        return True
-    except Exception:
-        return False
+        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+    except FileNotFoundError:
+        return "g++ not found"
+    except subprocess.TimeoutExpired:
+        return "g++ timed out after 120 s"
+    except subprocess.CalledProcessError as e:
+        return (f"g++ exited {e.returncode}: "
+                f"{e.stderr.decode(errors='replace')[-2000:]}")
+    return None
+
+
+def _unavailable(why: str) -> None:
+    """The numpy fallback is correct but slower: say once why it is on."""
+    global _error
+    _error = why
+    warnings.warn(f"libdatavec_native unavailable ({why}); host ETL falls "
+                  "back to numpy", RuntimeWarning, stacklevel=4)
 
 
 def _load():
@@ -65,11 +81,14 @@ def _load():
     so = _so_path()
     if not os.path.exists(so) or \
             os.path.getmtime(so) < os.path.getmtime(_SRC):
-        if not _build():
+        err = _build()
+        if err is not None:
+            _unavailable(err)
             return None
     try:
         lib = ctypes.CDLL(so)
-    except OSError:
+    except OSError as e:
+        _unavailable(f"dlopen failed: {e}")
         return None
     lib.sg_pairs.restype = ctypes.c_int64
     lib.sg_pairs.argtypes = [
@@ -87,6 +106,12 @@ def _load():
 
 def available() -> bool:
     return _load() is not None
+
+
+def load_error() -> Optional[str]:
+    """Why :func:`available` is False (build or load failure), else None."""
+    _load()
+    return _error
 
 
 def sg_pairs(ids: np.ndarray, offsets: np.ndarray, window: int,

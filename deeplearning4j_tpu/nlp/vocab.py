@@ -181,9 +181,9 @@ def unigram_int_table(cache: VocabCache, power: float = 0.75,
     """Power-of-two int32 negative-sampling table: word i occupies a number
     of slots proportional to f_i^power (reference: InMemoryLookupTable's
     1e8-entry table; sized 2^20 here so a device draw is
-    ``random_bits & (size-1)`` + one gather — measured ~20× cheaper per
-    round than searchsorted over the exact CDF on TPU, see BASELINE.md
-    round-3 Word2Vec audit). Words with probability < 1/size get no slot —
+    ``random_bits & (size-1)`` + one gather, chosen over searchsorted on
+    the exact CDF; not measured on this chip). Words with probability
+    < 1/size get no slot —
     the same truncation the reference's finite table applies."""
     assert size & (size - 1) == 0, "size must be a power of two"
     counts = cache.counts().astype(np.float64)

@@ -30,19 +30,10 @@ Table accumulation has two lowerings, selected by the static ``dense`` flag:
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from .registry import op
-
-# jax.enable_x64 only exists on newer jax; 0.4.x spells it
-# jax.experimental.enable_x64 — same semantics (see pallas_attention)
-_enable_x64 = getattr(jax, "enable_x64", None)
-if _enable_x64 is None:
-    from jax.experimental import enable_x64 as _enable_x64
 
 # Vocab threshold below which SequenceVectors picks the dense one-hot MXU
 # update. Round-3 measurement (module docstring) shows scatter wins at every
@@ -66,83 +57,23 @@ def _table_add(table, idx, grads, dense: bool):
     return table.at[idx].add(grads.astype(table.dtype))
 
 
-def _bag_kernel(idx_ref, row_ref, mask_ref, count_ref, o_ref, *,
-                n_w: int, mean: bool):
-    # Grid is (B, W) with W innermost: the out block (one pooled row) is
-    # revisited across the W iterations and accumulates in VMEM; the
-    # table row for (b, w) is DMA'd in by the scalar-prefetch index map —
-    # the [B, W, D] gathered tensor never materializes in HBM. ``idx_ref``
-    # is consumed by the index maps only.
-    del idx_ref
-    w = pl.program_id(1)
-
-    @pl.when(w == jnp.int32(0))
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref[...])
-
-    o_ref[...] += row_ref[...] * mask_ref[0, 0]
-
-    if mean:
-        @pl.when(w == jnp.int32(n_w - 1))
-        def _final():
-            o_ref[...] = o_ref[...] / count_ref[0, 0]
-
-
-def _bag_pallas(table, indices, mask, counts, mean: bool, interpret: bool):
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, W = indices.shape
-    D = table.shape[1]
-    kernel = functools.partial(_bag_kernel, n_w=W, mean=mean)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, W),
-        in_specs=[
-            pl.BlockSpec((1, D), lambda b, w, idx: (idx[b, w], 0)),
-            pl.BlockSpec((1, 1), lambda b, w, idx: (b, w)),
-            pl.BlockSpec((1, 1), lambda b, w, idx: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, D), lambda b, w, idx: (b, 0)),
-    )
-    with _enable_x64(False):
-        return pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, D), table.dtype),
-            interpret=interpret,
-        )(indices.astype(jnp.int32), table, mask, counts)
-
-
 @op("embedding_bag", "nlp")
-def embedding_bag(table, indices, mask=None, mode: str = "mean",
-                  impl: str = None):
+def embedding_bag(table, indices, mask=None, mode: str = "mean"):
     """Pooled embedding lookup: ``table [V, D]``, ``indices [B, W]``,
     optional ``mask [B, W]`` (0 = pad) → ``[B, D]`` masked mean/sum of
     the gathered rows — the CBOW window pooling and the
-    ``EmbeddingSequenceLayer``-style bag in one op.
-
-    ``impl="xla"`` (default off-TPU) is the reference lowering and is
-    BITWISE the expression the nlp rounds always computed
-    (``(table[ix] * mask).sum(1) / counts``); ``impl="pallas"`` (default
-    on TPU; ``"interpret"`` for the CPU test mesh) streams one table row
-    per grid step through a scalar-prefetch index map, so the [B, W, D]
-    gather never hits HBM — the bandwidth fix on the lookup side. The
-    pallas path is forward-only (the nlp rounds apply their updates by
-    hand); differentiate through the xla path."""
+    ``EmbeddingSequenceLayer``-style bag in one op. One XLA lowering on
+    every platform, BITWISE the expression the nlp rounds always computed
+    (``(table[ix] * mask).sum(1) / counts``)."""
     if mode not in ("mean", "sum"):
         raise ValueError(f"embedding_bag mode {mode!r}")
-    if impl is None:
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if mask is None:
         mask = jnp.ones(indices.shape, table.dtype)
     mask = mask.astype(table.dtype)
-    counts = jnp.maximum(mask.sum(axis=1, keepdims=True), 1.0)
-    if impl == "xla":
-        cvecs = table[indices]                            # [B, W, D]
-        h = (cvecs * mask[..., None]).sum(axis=1)
-        return h / counts if mode == "mean" else h
-    return _bag_pallas(table, indices, mask, counts.astype(table.dtype),
-                       mode == "mean", interpret=impl == "interpret")
+    h = (table[indices] * mask[..., None]).sum(axis=1)    # [B, D]
+    if mode == "sum":
+        return h
+    return h / jnp.maximum(mask.sum(axis=1, keepdims=True), 1.0)
 
 
 def _neg_round(h, u, labels, lr, pair_mask):
@@ -272,9 +203,7 @@ def cbow(syn0, syn1neg, contexts, ctx_mask, targets, labels, lr, pair_mask,
     contexts [B,W] int32 window word ids, ctx_mask [B,W] float (0 = pad);
     h = masked MEAN of context vectors.
     """
-    # masked-mean window pooling via the embedding_bag op (the xla impl
-    # is bitwise this round's historical inline expression; on TPU the
-    # pallas impl streams the gather row-by-row)
+    # masked-mean window pooling via the embedding_bag op
     counts = jnp.maximum(ctx_mask.sum(axis=1, keepdims=True), 1.0)
     h = embedding_bag(syn0, contexts, ctx_mask, mode="mean")
     u = syn1neg[targets]

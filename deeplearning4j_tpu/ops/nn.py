@@ -500,10 +500,11 @@ def multi_head_dot_product_attention(q, k, v, wq, wk, wv, wo, mask=None,
     out = None
     if tq == tk:
         # self-attention routes through the Pallas flash kernel on TPU
-        # (3-8x at long T, no T×T buffer — BASELINE.md); a padding mask
-        # rides as an additive logits bias streamed block-by-block, so
-        # the masked path BERT runs is the SAME fused kernel. The dense
-        # path remains the reference semantics everywhere else.
+        # (no T×T buffer; its speed against the dense op is not measured
+        # on this chip); a padding mask rides as an additive logits bias
+        # streamed block-by-block, so the masked path is the SAME fused
+        # kernel. The dense path remains the reference semantics
+        # everywhere else.
         from ..common.environment import Environment
         from .pallas_attention import flash_attention, supports_flash
 

@@ -44,7 +44,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from ..common.profiler import OpProfiler
-from .pallas_update import LANES, _enable_x64, default_mode
+from .pallas_update import LANES, default_mode
 
 BLOCK_ROWS = 256
 
@@ -97,7 +97,7 @@ def _launch(x2d, scale, shift, res2d, act, interpret):
         ins.append(res2d)
         in_specs.append(blk)
     kernel = functools.partial(_kernel, act, res2d is not None)
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             kernel,
             grid=grid,

@@ -50,11 +50,6 @@ from jax.experimental import pallas as pl
 from ..common.profiler import OpProfiler
 from ..learning.updaters import Adam, AdamW, Nesterovs, Sgd, _lr_at
 
-# jax 0.4.x spells the x64 context manager under experimental (see
-# ops/pallas_attention.py — the kernel must trace in the 32-bit world)
-_enable_x64 = getattr(jax, "enable_x64", None)
-if _enable_x64 is None:
-    from jax.experimental import enable_x64 as _enable_x64
 
 BLOCK_ROWS = 256          # f32 rows of 128 lanes per grid program (~128KB
 LANES = 128               # per buffer in VMEM; 8 buffers stay well inside)
@@ -187,7 +182,7 @@ def _launch_kernel(kind, sc, p, g, slots, bits, sr_dtype, interpret):
                   for _ in slot_names]
     kernel = functools.partial(_kernel, kind, slot_names, bits is not None,
                                sr_dtype, len(sc))
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         outs = pl.pallas_call(
             kernel,
             grid=grid,

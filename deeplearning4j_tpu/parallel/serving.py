@@ -760,6 +760,19 @@ class ServingEngine(ParallelInference):
         self._seq_out_per_timestep: Optional[bool] = None
         self._aot = (hasattr(model, "_forward")
                      and hasattr(model, "_params"))
+        # a ComputationGraph's forward takes and returns {node: array};
+        # a request is one array, so the engine serves graphs with one
+        # input and one output (the zoo's ResNet-50)
+        self._graph_io: Optional[Tuple[str, str]] = None
+        conf = getattr(model, "conf", None)
+        if self._aot and hasattr(conf, "network_inputs"):
+            ins, outs = conf.network_inputs, conf.network_outputs
+            if len(ins) != 1 or len(outs) != 1:
+                raise ValueError(
+                    "ServingEngine serves one array per request: a "
+                    f"ComputationGraph with inputs {ins} and outputs "
+                    f"{outs} needs exactly one of each")
+            self._graph_io = (ins[0], outs[0])
         self._infer_jit = None
         self._dev_params: Dict[int, Any] = {}
         pool_kwargs.setdefault("mode", "batched")
@@ -838,6 +851,7 @@ class ServingEngine(ParallelInference):
         model = self.model
         cdt = self._compute_dtype
         cell = self._trace_cell
+        graph_io = self._graph_io
 
         def infer(params, states, x, key):
             # trace-time only: the retrace ledger the serving SLO gates on
@@ -845,7 +859,12 @@ class ServingEngine(ParallelInference):
             cell[0] += 1
             if cdt is not None:
                 x = x.astype(cdt)
-            out, _ = model._forward(params, states, x, False, key, None)
+            if graph_io is not None:
+                acts, _ = model._forward(params, states, {graph_io[0]: x},
+                                         False, key)
+                out = acts[graph_io[1]]
+            else:
+                out, _ = model._forward(params, states, x, False, key, None)
             return out.astype(jnp.float32)
 
         return infer

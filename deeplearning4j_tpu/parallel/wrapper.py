@@ -212,6 +212,12 @@ class ParallelWrapper:
         def local_step(params, states, upd_state, acc_state, x, y, mask, w,
                        key, it):
             idx = jax.lax.axis_index(axis)
+            # the dense layouts apply the FULL update redundantly on every
+            # replica: their stochastic-rounding draws must be the same
+            # everywhere or the replicas' bf16 moments (and then their
+            # params) drift apart. Dropout and the ZeRO-1 slices are
+            # per-replica work and take the folded key.
+            shared_key = key
             key = jax.random.fold_in(key, idx)
             # Per-shard weighted data loss with a GLOBAL divisor: each shard
             # divides its weighted sum by global_real/num_shards, so the
@@ -291,13 +297,13 @@ class ParallelWrapper:
                 flat_grads = acc.reduce_gradients(flat_grads)
                 new_params, new_upd = _apply_fused_flat(
                     dense_fused_plan, updater, flat_grads, upd_state,
-                    params, it, key, flat_params=flat_params,
+                    params, it, shared_key, flat_params=flat_params,
                     grads_flat=True)
             else:
                 if not stateful:
                     grads = acc.reduce_gradients(grads)
                 new_params, new_upd = _prec.apply_updater(
-                    updater, grads, upd_state, params, it, key)
+                    updater, grads, upd_state, params, it, shared_key)
             if tele is None:
                 return new_params, new_states, new_upd, acc_state, loss
             if not stats:
@@ -525,6 +531,38 @@ class ParallelWrapper:
         placed = [jax.device_put(jnp.array(l), NamedSharding(self.mesh, s))
                   for l, s in zip(leaves, spec_leaves)]
         return jax.tree.unflatten(treedef, placed)
+
+    def _place_model_state(self) -> None:
+        """Params, layer states and a dense updater state onto the mesh
+        BEFORE the first dispatch. The step would re-place them itself,
+        but in jax 0.9 an array's type carries its mesh: a first call on
+        single-device arrays and a second on the step's own mesh-placed
+        outputs are two cache keys — two traces and two compiles of the
+        same step. (ZeRO-1 and accumulator state are placed where they
+        are built.)"""
+        from jax.sharding import NamedSharding
+
+        model = self.model
+
+        def placed(tree, prefix_specs):
+            leaves = jax.tree.leaves(tree)
+            if all(isinstance(l, jax.Array)
+                   and isinstance(l.sharding, NamedSharding)
+                   and l.sharding.mesh == self.mesh for l in leaves):
+                return tree
+            # broadcast the prefix spec tree (a bare P() stands for the
+            # whole tree) down to one spec per leaf
+            specs = jax.tree.map(
+                lambda s, sub: jax.tree.map(lambda _: s, sub),
+                prefix_specs, tree, is_leaf=lambda s: isinstance(s, P))
+            return self._place(tree, specs)
+
+        pspec = self._param_specs()
+        model._params = placed(model._params, pspec)
+        model._states = placed(model._states, P())
+        if not self.accumulator.zero1 and model._updater_state:
+            model._updater_state = placed(model._updater_state,
+                                          self._upd_specs(pspec))
 
     def _ensure_parallel_state(self) -> None:
         """Bring the model's updater/accumulator state into THIS wrapper's
@@ -943,6 +981,7 @@ class ParallelWrapper:
                 self._chunk_step = None
                 self._exec_cache.clear()
         self._ensure_parallel_state()
+        self._place_model_state()
         if self._step is None:
             self._step = self._build_step()
         if steps_per_dispatch > 1 and self._chunk_step is None:

@@ -4,8 +4,9 @@ Tiny-config BERT (same graph topology as base — the layer count/width are the
 only differences) built with local TF, frozen, imported, checked for forward
 parity against TF, then fine-tuned: constants promoted to variables, a
 classifier head + loss grafted on, sd.fit() with dict batches, loss falls.
-The full-size BERT-base samples/sec number comes from ``bench.py --config
-bert`` on TPU (BASELINE.md ledger).
+The full-size BERT-base step runs on the TPU in ``chip_smoke.py``; its
+samples/sec comes from ``bench.py --config bert`` there (not measured on
+this chip).
 """
 
 from __future__ import annotations

@@ -3,8 +3,9 @@
 The reference ships prebuilt libnd4j binaries, so a fresh JVM never pays
 kernel compilation; the XLA analog is jax's persistent executable cache.
 These tests pin the library-level knob: ``Environment.set_compile_cache``
-(or ``DL4J_TPU_COMPILE_CACHE=<dir>``) must make a SECOND process reuse the
-first process's executables instead of recompiling.
+must make a SECOND process reuse the first process's executables instead of
+recompiling, and the one placement rule: ``JAX_COMPILATION_CACHE_DIR`` where
+set (no directory set in code), else ``<checkout>/.jax_cache``.
 
 Cache hits are asserted structurally (no new cache entries are written by
 the second process) rather than by wall-clock, which would be flaky on a
@@ -64,6 +65,21 @@ def _cache_entries(cache_dir: str):
         for dp, _, fs in os.walk(cache_dir) for f in fs)
 
 
+def _run_script(body: str, cache_env, cwd: str = _REPO) -> None:
+    """Run ``body`` in a fresh process with JAX_COMPILATION_CACHE_DIR set to
+    ``cache_env`` (None: unset)."""
+    env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if cache_env is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = cache_env
+    env.setdefault("JAX_PLATFORMS", "cpu")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; sys.path.insert(0, %r)\n%s" % (_REPO, body)],
+        capture_output=True, text=True, env=env, cwd=cwd, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+
+
 class TestCompileCache:
     @pytest.mark.slow
     def test_second_process_hits_cache(self):
@@ -77,25 +93,55 @@ class TestCompileCache:
                 "of loading the persisted executables"
 
     def test_env_var_knob(self):
-        # DL4J_TPU_COMPILE_CACHE applies at Environment.get() with no
-        # explicit set_compile_cache call
+        # JAX_COMPILATION_CACHE_DIR places the cache with no
+        # set_compile_cache call at all: jax reads it, Environment reports it
         with tempfile.TemporaryDirectory() as cache:
-            env = dict(os.environ)
-            env["DL4J_TPU_COMPILE_CACHE"] = cache
-            env.setdefault("JAX_PLATFORMS", "cpu")
-            script = (
-                "import sys; sys.path.insert(0, %r)\n"
+            _run_script(
                 "from deeplearning4j_tpu.common.environment import "
                 "Environment\n"
                 "e = Environment.get()\n"
                 "assert e.compile_cache_dir() == %r, e.compile_cache_dir()\n"
                 "import jax\n"
                 "assert jax.config.jax_compilation_cache_dir == %r\n"
-                % (_REPO, cache, cache))
-            out = subprocess.run([sys.executable, "-c", script],
-                                 capture_output=True, text=True, env=env,
-                                 cwd=_REPO, timeout=300)
-            assert out.returncode == 0, out.stderr[-2000:]
+                % (cache, cache), cache_env=cache)
+
+    def test_env_var_set_no_directory_in_code(self):
+        # the one placement rule, first half: where the variable is set the
+        # library sets NO directory in code (it only reports jax's own)
+        with tempfile.TemporaryDirectory() as cache:
+            _run_script(
+                "import jax\n"
+                "updates = []\n"
+                "real = jax.config.update\n"
+                "def spy(name, value):\n"
+                "    updates.append(name)\n"
+                "    real(name, value)\n"
+                "jax.config.update = spy\n"
+                "from deeplearning4j_tpu.common.environment import (\n"
+                "    Environment, enable_compilation_cache)\n"
+                "assert enable_compilation_cache() == %r\n"
+                "assert Environment.get().set_compile_cache() == %r\n"
+                "assert 'jax_compilation_cache_dir' not in updates, updates\n"
+                "assert jax.config.jax_compilation_cache_dir == %r\n"
+                % (cache, cache, cache), cache_env=cache)
+
+    def test_default_dir_is_the_checkout_from_any_cwd(self):
+        # second half: without the variable the cache is
+        # <checkout>/.jax_cache whatever the working directory (the path
+        # is part of jax's cache key — a cache that moves never hits)
+        want = os.path.join(_REPO, ".jax_cache")
+        script = (
+            "from deeplearning4j_tpu.common.environment import "
+            "enable_compilation_cache\n"
+            "import jax, os\n"
+            "assert enable_compilation_cache() == %r\n"
+            "assert jax.config.jax_compilation_cache_dir == %r\n"
+            "assert not os.path.exists(os.path.join(os.getcwd(), "
+            "'.jax_cache'))\n" % (want, want))
+        with tempfile.TemporaryDirectory() as a, \
+                tempfile.TemporaryDirectory() as b:
+            for cwd in (a, b):
+                _run_script(script, cache_env=None, cwd=cwd)
 
 
 _MLN_FIT_SCRIPT = r"""

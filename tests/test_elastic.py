@@ -483,11 +483,13 @@ class TestSupervisorElastic:
         faultinject.set_plan(faultinject.FaultPlan(
             [{"site": "device/loss", "index": 2, "kind": "device_loss",
               "replica": 1}]))
+        # the probe rides the monitor's poll: poll well inside the time the
+        # shrunk run has left (its step is compiled once, so that is short)
         sup = TrainingSupervisor(pw, checkpoint_dir=str(tmp_path),
                                  grow_probe_base_s=0.0,
                                  grow_probe_max_s=0.01,
-                                 grow_failure_limit=2)
-        res = sup.fit(make_iter, epochs=6)
+                                 grow_failure_limit=2, poll_s=0.002)
+        res = sup.fit(make_iter, epochs=30)
         faultinject.clear_plan()
         assert res.status == "completed"
         assert res.restarts == 0

@@ -642,10 +642,6 @@ class TestNN:
         check("embedding_bag", pooled, table, bag, mask, mode="sum")
         # mask=None pools the whole window
         check("embedding_bag", table[bag].mean(1), table, bag)
-        # the pallas kernel (interpret mode on CPU) matches the xla
-        # reference lowering
-        check("embedding_bag", pooled / counts, table, bag, mask,
-              impl="interpret", atol=1e-6)
 
     def test_attention(self):
         q, k, v = r(2, 5, 8), r(2, 6, 8, seed=1), r(2, 6, 8, seed=2)

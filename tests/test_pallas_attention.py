@@ -1,7 +1,8 @@
 """Flash-attention Pallas kernel conformance (interpret mode on the CPU
-test mesh; the same kernel lowers through Mosaic on TPU — benched in
-BASELINE.md). Parity target: ops/nn.dot_product_attention, the dense
-reference implementation."""
+test mesh; the same kernel lowers through Mosaic on TPU —
+tests/test_tpu_compile.py compiles it for the chip, chip_smoke.py runs it
+there). Parity target: ops/nn.dot_product_attention, the dense reference
+implementation."""
 
 from __future__ import annotations
 

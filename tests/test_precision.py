@@ -396,6 +396,27 @@ def run_zero1(model, workers=4, epochs=2, resume_from=None, listeners=()):
     return [s for _, s in scores.scores], model
 
 
+class TestDenseReplicasStayIdentical:
+    @pytest.mark.parametrize("fused", [False, True])
+    def test_bf16_state_draws_are_shared_across_replicas(self, fused):
+        """Dense data-parallel applies the FULL update on every replica:
+        with bf16 state the stochastic-rounding draws must be the same on
+        all of them, or the replicas' moments and params drift apart (a
+        per-replica key did exactly that)."""
+        model = wrapper_model("bfloat16")
+        model.conf.global_conf.fused_update = fused
+        set_default_seed(99)
+        pw = ParallelWrapper.Builder(model).workers(4).build()
+        pw.fit(wrapper_iter(), epochs=2)
+        for tree in (model._params, model._updater_state):
+            for leaf in jax.tree.leaves(tree):
+                copies = [np.asarray(s.data)
+                          for s in leaf.addressable_shards]
+                assert len(copies) == 4
+                assert all(np.array_equal(copies[0], c)
+                           for c in copies[1:])
+
+
 class TestZero1Compose:
     def test_plan_reshard_preserves_bf16_state_bitwise(self):
         """The flat layout is replica-count-independent: bf16 moments

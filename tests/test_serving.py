@@ -167,6 +167,36 @@ class TestServingEngine:
         finally:
             eng.shutdown()
 
+    def test_serves_a_computation_graph(self):
+        """A ComputationGraph's forward takes and returns {node: array}:
+        the engine binds the one input / one output itself (the zoo's
+        ResNet-50 is a graph), and refuses a graph with several."""
+        from deeplearning4j_tpu.nn import (ComputationGraph,
+                                           ComputationGraphConfiguration)
+
+        def graph(outputs):
+            gb = (ComputationGraphConfiguration
+                  .graph_builder(NeuralNetConfiguration.builder().seed(3)
+                                 .updater(Adam(0.05)).activation("tanh"))
+                  .add_inputs("in"))
+            gb.add_layer("h", L.DenseLayer(n_out=16), "in")
+            for o in outputs:
+                gb.add_layer(o, L.OutputLayer(n_out=3), "h")
+            return ComputationGraph(
+                gb.set_outputs(*outputs)
+                .set_input_types(InputType.feed_forward(4)).build()).init()
+
+        model = graph(["out"])
+        eng = build_engine(model, buckets=(1, 4))
+        try:
+            x = np.linspace(-1, 1, 6 * 4, dtype=np.float32).reshape(6, 4)
+            out = eng.output(x).to_numpy()          # 6 -> chunks 4+2
+            assert np.array_equal(out, model.output(x)[0].to_numpy())
+        finally:
+            eng.shutdown()
+        with pytest.raises(ValueError, match="exactly one of each"):
+            build_engine(graph(["out", "out2"]), buckets=(1,))
+
     def test_oversize_reject_raises_synchronously(self):
         eng = build_engine(buckets=(1, 2, 4), oversize="reject")
         try:

@@ -10,7 +10,7 @@ Two halves:
 
 - **Trajectory** — :func:`load_rounds` parses every round file in a
   directory, :func:`trajectory` pivots them per metric, and
-  :func:`render_markdown` emits the r01→rNN table BASELINE.md carries.
+  :func:`render_markdown` emits the per-round trajectory table.
 - **Regression gates** — :func:`compare_records` holds a current run's
   records against a baseline round: step-time, throughput, MFU,
   compile/trace counts and updater-state bytes. Noise handling follows
@@ -129,7 +129,7 @@ def _fmt(v: Any, nd: int = 2) -> str:
 
 def render_markdown(rounds: List[Dict[str, Any]],
                     metrics: Optional[List[str]] = None) -> str:
-    """The BASELINE.md trajectory table: one section per metric, one row
+    """The trajectory table: one section per metric, one row
     per round, carrying the roofline-relevant columns."""
     traj = trajectory(rounds, metrics)
     lines: List[str] = []

@@ -10,7 +10,7 @@ two knobs the framework stack currently hard-codes:
                          per-step bf16 casts)
 
 Purpose: isolate how much of the framework's 25% MFU is layout/dtype (fixable
-in the framework) vs relay/XLA ceiling (not). Timing methodology == bench.py
+in the framework) vs the XLA ceiling (not). Timing methodology == bench.py
 (value-fenced chunks); FLOPs from XLA cost analysis of the compiled step.
 
 Also reports transpose/convert op counts in the optimized HLO so the layout
@@ -29,7 +29,7 @@ from functools import partial
 import numpy as np
 
 sys.path.insert(0, ".")
-from bench import _timed_steps, CHUNK, TPU_BF16_PEAK_TFLOPS  # noqa: E402
+from bench import _timed_steps, CHUNK  # noqa: E402
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -273,7 +273,9 @@ def main():
     }
     if flops:
         out["effective_tflops"] = round(flops / med / 1e12, 1)
-        out["mfu_vs_bf16_peak"] = round(flops / med / 1e12 / TPU_BF16_PEAK_TFLOPS, 4)
+        from deeplearning4j_tpu.common.xprof import device_peaks
+
+        out["mfu_vs_bf16_peak"] = round(flops / med / device_peaks()[0], 4)
     if hlo_stats:
         out["hlo_op_counts"] = hlo_stats
     print(json.dumps(out))
