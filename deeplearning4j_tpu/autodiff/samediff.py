@@ -38,6 +38,8 @@ import numpy as np
 
 from ..common import xprof
 from ..common.dtypes import DataType
+from ..common.profiler import OpProfiler
+from ..data.pipeline import timed_iter
 from ..ndarray.ndarray import NDArray
 from ..ndarray.rng import get_random
 from ..learning.schedules import ISchedule
@@ -257,6 +259,7 @@ class SameDiff:
         self._updater_state = None
         self._iteration = 0
         self._epoch = 0
+        self._fit_calls = 0
         self._loss_var: Optional[str] = None
         self.math = _OpNamespace(self)
         # All namespaces resolve the same registry; aliases for API parity.
@@ -748,11 +751,15 @@ class SameDiff:
         # an explicitly passed binding is never overridden; a missing label
         # placeholder stays None (unsupervised losses)
 
-        params = self._params()
-        if self._updater_state is None:
-            self._updater_state = self._training_config.updater.init(params)
-        state = self._updater_state
-        step = self._train_step_fn(loss_name, tuple(phs))
+        self._fit_calls += 1
+        with OpProfiler.get().time_section("fit/enter",
+                                           call=self._fit_calls):
+            params = self._params()     # host -> device, every variable
+            if self._updater_state is None:
+                self._updater_state = self._training_config.updater.init(
+                    params)
+            state = self._updater_state
+            step = self._train_step_fn(loss_name, tuple(phs))
         history = History()
         listeners = listeners or []
         # The jitted step donates its params/state inputs. If a step fails
@@ -779,23 +786,36 @@ class SameDiff:
                 self._updater_state = None  # momenta restart on next fit
             raise
 
+    def _bound_batches(self, data, batch_size, feature_placeholder,
+                       label_placeholder):
+        """Each batch as the step's ``{placeholder: device array}``."""
+        for ds in _iter_batches(data, batch_size):
+            if isinstance(ds, dict):
+                # multi-input binding (e.g. imported BERT: ids/types/mask
+                # + labels): batches are {placeholder_name: array}
+                ph = {k: jnp.asarray(v.value if isinstance(v, NDArray) else v)
+                      for k, v in ds.items()}
+            else:
+                ph = {feature_placeholder: jnp.asarray(ds.features.value)}
+                if label_placeholder is not None and ds.labels is not None:
+                    ph[label_placeholder] = jnp.asarray(ds.labels.value)
+            yield ph
+
     def _fit_loop(self, step, data, batch_size, epochs, feature_placeholder,
                   label_placeholder, params, state, history, listeners):
+        # the sections carry the names ComputationGraph.fit's do
+        # (data/pipeline.run_epochs), so one trace reader serves both
+        prof = OpProfiler.get()
         for epoch in range(epochs):
             loss_sum, n_batches = None, 0
-            for ds in _iter_batches(data, batch_size):
-                if isinstance(ds, dict):
-                    # multi-input binding (e.g. imported BERT: ids/types/mask
-                    # + labels): batches are {placeholder_name: array}
-                    ph = {k: jnp.asarray(v.value if isinstance(v, NDArray) else v)
-                          for k, v in ds.items()}
-                else:
-                    ph = {feature_placeholder: jnp.asarray(ds.features.value)}
-                    if label_placeholder is not None and ds.labels is not None:
-                        ph[label_placeholder] = jnp.asarray(ds.labels.value)
+            for ph in timed_iter(self._bound_batches(
+                    data, batch_size, feature_placeholder,
+                    label_placeholder), step=self._iteration):
                 key = get_random().next_key()
-                params, state, loss = step(params, state, ph, key,
-                                           jnp.asarray(self._iteration))
+                with prof.time_section("pipeline/dispatch",
+                                       step=self._iteration):
+                    params, state, loss = step(params, state, ph, key,
+                                               jnp.asarray(self._iteration))
                 self._iteration += 1
                 # device scalar all the way down: listeners receive it un-synced
                 # and decide when to read (the multilayer/ui.stats contract);
@@ -821,14 +841,18 @@ class SameDiff:
                 raise ValueError(
                     "training data yielded no batches this epoch (exhausted "
                     "iterator or empty dataset)")
-            history.add_epoch(self._epoch, float(loss_sum) / n_batches)
-            for lst in listeners:
-                if hasattr(lst, "epoch_done"):
-                    lst.epoch_done(self, self._epoch)
+            with prof.time_section("fit/epoch_end", epoch=epoch):
+                with prof.time_section("fit/sync", epoch=epoch):
+                    mean_loss = float(loss_sum) / n_batches
+                history.add_epoch(self._epoch, mean_loss)
+                for lst in listeners:
+                    if hasattr(lst, "epoch_done"):
+                        lst.epoch_done(self, self._epoch)
         # write trained values back into the graph (stateful shell)
-        for n, val in params.items():
-            self._vars[n].value = np.asarray(val)
-        self._updater_state = state
+        with prof.time_section("fit/exit", call=self._fit_calls):
+            for n, val in params.items():
+                self._vars[n].value = np.asarray(val)
+            self._updater_state = state
         return history
 
     # --- serialization ---------------------------------------------------

@@ -6,7 +6,9 @@ dimension lives inside XLA, so the device-side story is a trace: ``start()``/
 ``stop()`` (or ``with trace(logdir)``) drive ``jax.profiler`` and produce a
 TensorBoard-loadable trace of every kernel. The host-side section API
 (``time_section``) aggregates wall times by name — the analog of the
-reference's per-op counters for the Python orchestration layer.
+reference's per-op counters for the Python orchestration layer — and
+writes each section into that trace as a host span, so the trace says
+what the host was doing in every gap between the device's ops.
 
 NAN_PANIC itself is ``Environment.get().set_check_nan(True)`` →
 ``jax_debug_nans`` (§5.1's named toggle).
@@ -18,6 +20,8 @@ import contextlib
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from . import flightrec
 
@@ -104,10 +108,22 @@ class OpProfiler:
 
     # --- host-side section counters (OpProfiler counter analog) ---------
     @contextlib.contextmanager
-    def time_section(self, name: str):
+    def time_section(self, name: str, **attrs):
+        """THE span primitive: the body is one named interval of host
+        work. It is recorded three ways from this one call — the
+        (count, total, max) aggregate, a ``profiler/section`` flight-
+        recorder event, and a ``jax.profiler.TraceAnnotation``, so that
+        whenever a profiler session is on (``trace(logdir)``, a
+        benchmark's traced window) the section is a host span on the
+        profiler's clock, in the same ``.xplane.pb`` as the device's
+        ops and nested by thread. With no session the annotation is a
+        flag check. ``attrs`` (``step=``, ``epoch=``, ``call=``) go to
+        the annotation and to the event: the spans of one step share
+        ``step``."""
         t0 = time.perf_counter()
         try:
-            yield
+            with TraceAnnotation(name, **attrs):
+                yield
         finally:
             dt = time.perf_counter() - t0
             # under the lock: sections are bumped from the training
@@ -124,7 +140,8 @@ class OpProfiler:
             # the aggregate above stays the ledger source of truth.
             # Emitted OUTSIDE the profiler lock — the recorder has its
             # own, and nesting them would order the two locks.
-            flightrec.event("profiler/section", section=name, dur_s=dt)
+            flightrec.event("profiler/section", section=name, dur_s=dt,
+                            **attrs)
 
     def get_statistics(self) -> Dict[str, Dict[str, float]]:
         with self._lock:

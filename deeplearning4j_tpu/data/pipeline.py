@@ -233,20 +233,24 @@ def device_feed(batches: Iterable, place=None, depth: int = 2,
                        host_prefetch=host_prefetch)
 
 
-def timed_iter(it: Iterable, section: str = "pipeline/next_batch"):
+def timed_iter(it: Iterable, section: str = "pipeline/next_batch",
+               step: int = 0):
     """Yield from ``it`` with each blocking ``next()`` timed into the
     profiler — the host-wait half of the transfer-vs-compute overlap
     ledger (the other half is the ``pipeline/dispatch`` section the fit
-    loops record around step dispatch)."""
+    loops record around step dispatch). ``step`` numbers the first item;
+    each section carries the step its batch is for, as that step's
+    ``pipeline/dispatch`` does."""
     prof = OpProfiler.get()
     src = iter(it)
     while True:
         try:
-            with prof.time_section(section):
+            with prof.time_section(section, step=step):
                 item = next(src)
         except StopIteration:
             return
         yield item
+        step += 1
 
 
 def _poison_nan(batch):
@@ -272,7 +276,7 @@ def run_epochs(data: Any, epochs: int, batch_size: Optional[int],
                allow_multi: bool = False,
                host_prefetch: int = 0,
                skip: Optional[Tuple[int, int]] = None,
-               pre_dispatch=None) -> None:
+               pre_dispatch=None, first_step: int = 0) -> None:
     """The one training-loop skeleton shared by MultiLayerNetwork.fit,
     ComputationGraph.fit, and ParallelWrapper.fit: per epoch, stable
     batches are bound (``bind(ds, w)`` → jit argument tuple), staged
@@ -309,8 +313,13 @@ def run_epochs(data: Any, epochs: int, batch_size: Optional[int],
     generic fault points and before the dispatch — path-specific fault
     sites (the pipeline trainer's ``pipeline/stage`` stage-loss/straggler
     drills) fire here sharing the fit call's dispatch ordinal, so a drill
-    plan indexes one counter regardless of which fit path runs it."""
+    plan indexes one counter regardless of which fit path runs it.
+
+    ``first_step``: the holder's iteration as the call begins, so that a
+    batch's ``pipeline/next_batch`` section carries the same ``step`` as
+    the ``pipeline/dispatch`` section the holder records for it."""
     k = max(1, int(steps_per_dispatch))
+    prof = OpProfiler.get()
     skip_epochs, skip_steps = skip if skip is not None else (0, 0)
     n_bound = 0       # batch ordinal within this fit call (fault indexing)
     n_dispatched = 0  # dispatch ordinal within this fit call
@@ -372,7 +381,8 @@ def run_epochs(data: Any, epochs: int, batch_size: Optional[int],
             bound = (guarded_bind(ds, w) for ds, w, _n in gen)
             feed = timed_iter(device_feed(
                 bound, place=guarded_place, depth=max(0, int(prefetch)),
-                host_prefetch=max(0, int(host_prefetch))))
+                host_prefetch=max(0, int(host_prefetch))),
+                step=first_step + n_dispatched)
             if k == 1:
                 for b in feed:
                     faultinject.fault_point("train/step", n_dispatched)
@@ -411,11 +421,12 @@ def run_epochs(data: Any, epochs: int, batch_size: Optional[int],
                     else:
                         for b in group:
                             dispatch_one(b)
-            on_epoch()
-            # HBM watermark: one live-buffer census per epoch (the same
-            # walk /api/health serves) feeds the per-phase peak gauges —
-            # epoch cadence, never per dispatch
-            xprof.memory_watermark("fit")
+            with prof.time_section("fit/epoch_end", epoch=e):
+                on_epoch()
+                # HBM watermark: one live-buffer census per epoch (the
+                # same walk /api/health serves) feeds the per-phase peak
+                # gauges — epoch cadence, never per dispatch
+                xprof.memory_watermark("fit")
 
 
 def note_steps(holder: Any, listeners: Iterable, losses,

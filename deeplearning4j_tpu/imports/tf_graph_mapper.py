@@ -54,6 +54,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..autodiff.samediff import SameDiff, SDVariable
+from ..common.profiler import OpProfiler
 
 _TF_OPS: Dict[str, Callable] = {}
 
@@ -1105,9 +1106,11 @@ class TFGraphMapper:
     @staticmethod
     def import_graph(graph, input_shapes: Optional[Dict[str, Sequence[int]]] = None
                      ) -> SameDiff:
-        gd = _as_graph_def(graph)
-        imp = _Importer(gd, input_shapes)
-        sd = imp.run()
+        # the whole mapping of a GraphDef to a SameDiff, parsing included
+        with OpProfiler.get().time_section("build/import_graph"):
+            gd = _as_graph_def(graph)
+            imp = _Importer(gd, input_shapes)
+            sd = imp.run()
         sd.tf_placeholders = list(imp.placeholders)
         sd.tf_outputs = list(imp.outputs)
         return sd

@@ -396,6 +396,7 @@ class ComputationGraph:
         self._initialized = False
         self._iteration = 0
         self._epoch = 0
+        self._fit_calls = 0
         self._listeners: List[Any] = []
         self._telemetry = None
         self._fit_step = None
@@ -415,15 +416,17 @@ class ComputationGraph:
     def init(self, seed: Optional[int] = None) -> "ComputationGraph":
         if not self.conf.node_output_types:
             raise ValueError("configuration needs set_input_types(...) before init()")
-        key = jax.random.PRNGKey(seed if seed is not None else self.conf.global_conf.seed)
-        dtype = jnp.dtype(self.conf.global_conf.dtype)
-        for name in self.conf.order:
-            node = self.conf.nodes[name]
-            if node.kind == "layer":
-                key, sub = jax.random.split(key)
-                self._params[name] = (node.layer.init_params(sub, dtype)
-                                      if node.layer.has_params else {})
-                self._states[name] = node.layer.init_state()
+        with OpProfiler.get().time_section("build/init"):
+            key = jax.random.PRNGKey(
+                seed if seed is not None else self.conf.global_conf.seed)
+            dtype = jnp.dtype(self.conf.global_conf.dtype)
+            for name in self.conf.order:
+                node = self.conf.nodes[name]
+                if node.kind == "layer":
+                    key, sub = jax.random.split(key)
+                    self._params[name] = (node.layer.init_params(sub, dtype)
+                                          if node.layer.has_params else {})
+                    self._states[name] = node.layer.init_state()
         self._initialized = True
         return self
 
@@ -862,20 +865,23 @@ class ComputationGraph:
         lax.scan device loop. See MultiLayerNetwork.fit for knob docs,
         including ``resume_from`` (exact checkpoint resume)."""
         self._check_init()
-        skip = self._begin_fit(resume_from)
-        if self._updater_state is None:
-            self._updater_state = self.conf.global_conf.updater.init(self._params)
         from ..learning.precision import note_state_bytes
 
-        note_state_bytes(self._updater_state)
-        if self._fit_step is None:
-            self._fit_step = self._build_fit_step()
+        prof = OpProfiler.get()
+        self._fit_calls += 1
+        with prof.time_section("fit/enter", call=self._fit_calls):
+            skip = self._begin_fit(resume_from)
+            if self._updater_state is None:
+                self._updater_state = self.conf.global_conf.updater.init(
+                    self._params)
+            note_state_bytes(self._updater_state)
+            if self._fit_step is None:
+                self._fit_step = self._build_fit_step()
         if isinstance(data, (DataSet, MultiDataSet)) and batch_size is None:
             self._fit_serial(data, epochs, skip=skip)
             return
         if steps_per_dispatch > 1 and self._chunk_step is None:
             self._chunk_step = self._build_chunk_step()
-        prof = OpProfiler.get()
 
         def on_epoch():
             self._epoch += 1
@@ -894,7 +900,8 @@ class ComputationGraph:
             dispatch_one=lambda b: self._dispatch_one(b, prof),
             dispatch_chunk=lambda g: self._dispatch_chunk(g, prof),
             stackable=_chunk_stackable, on_epoch=on_epoch,
-            allow_multi=True, host_prefetch=host_prefetch, skip=skip)
+            allow_multi=True, host_prefetch=host_prefetch, skip=skip,
+            first_step=self._iteration)
 
     def _begin_fit(self, resume_from: Optional[str]):
         from ..util.checkpoint import begin_fit_cursor
@@ -905,7 +912,7 @@ class ComputationGraph:
     def _dispatch_one(self, b, prof) -> None:
         inputs, labels, masks, w = b
         key = get_random().next_key()
-        with prof.time_section("pipeline/dispatch"):
+        with prof.time_section("pipeline/dispatch", step=self._iteration):
             out = self._fit_step(self._params, self._states,
                                  self._updater_state, inputs, labels, masks,
                                  key, jnp.asarray(self._iteration), w)
@@ -918,7 +925,8 @@ class ComputationGraph:
         inputs, labels, masks = stack(0), stack(1), stack(2)
         ws = jnp.stack([b[3] for b in group])
         keys = jnp.stack([get_random().next_key() for _ in group])
-        with prof.time_section("pipeline/dispatch"):
+        with prof.time_section("pipeline/dispatch", step=self._iteration,
+                               steps=len(group)):
             out = self._chunk_step(self._params, self._states,
                                    self._updater_state, inputs, labels, masks,
                                    keys, jnp.asarray(self._iteration), ws)

@@ -39,6 +39,7 @@ class MultiLayerNetwork:
         self._initialized = False
         self._iteration = 0
         self._epoch = 0
+        self._fit_calls = 0
         self._listeners: List[Any] = []
         self._telemetry = None
         self._fit_step = None
@@ -60,14 +61,17 @@ class MultiLayerNetwork:
     def init(self, seed: Optional[int] = None) -> "MultiLayerNetwork":
         if self.conf.input_type is None:
             raise ValueError("configuration needs set_input_type(...) before init()")
-        key = jax.random.PRNGKey(seed if seed is not None else self.conf.global_conf.seed)
-        dtype = jnp.dtype(self.conf.global_conf.dtype)
-        self._params = []
-        self._states = []
-        for layer in self.layers:
-            key, sub = jax.random.split(key)
-            self._params.append(layer.init_params(sub, dtype) if layer.has_params else {})
-            self._states.append(layer.init_state())
+        with OpProfiler.get().time_section("build/init"):
+            key = jax.random.PRNGKey(
+                seed if seed is not None else self.conf.global_conf.seed)
+            dtype = jnp.dtype(self.conf.global_conf.dtype)
+            self._params = []
+            self._states = []
+            for layer in self.layers:
+                key, sub = jax.random.split(key)
+                self._params.append(layer.init_params(sub, dtype)
+                                    if layer.has_params else {})
+                self._states.append(layer.init_state())
         self._initialized = True
         return self
 
@@ -642,14 +646,18 @@ class MultiLayerNetwork:
         where the uninterrupted run would have gone.
         """
         self._check_init()
-        skip = self._begin_fit(resume_from)
-        if self._updater_state is None:
-            self._updater_state = self.conf.global_conf.updater.init(self._params)
         from ..learning.precision import note_state_bytes
 
-        note_state_bytes(self._updater_state)
-        if self._fit_step is None:
-            self._fit_step = self._build_fit_step()
+        prof = OpProfiler.get()
+        self._fit_calls += 1
+        with prof.time_section("fit/enter", call=self._fit_calls):
+            skip = self._begin_fit(resume_from)
+            if self._updater_state is None:
+                self._updater_state = self.conf.global_conf.updater.init(
+                    self._params)
+            note_state_bytes(self._updater_state)
+            if self._fit_step is None:
+                self._fit_step = self._build_fit_step()
 
         tbptt = self.conf.backprop_type == "TruncatedBPTT"
         # Single-DataSet/tuple calls with no batch size have one stable
@@ -661,7 +669,6 @@ class MultiLayerNetwork:
             return
         if steps_per_dispatch > 1 and self._chunk_step is None:
             self._chunk_step = self._build_chunk_step()
-        prof = OpProfiler.get()
 
         def on_epoch():
             self._epoch += 1
@@ -679,7 +686,8 @@ class MultiLayerNetwork:
             dispatch_one=lambda b: self._dispatch_one(b, prof),
             dispatch_chunk=lambda g: self._dispatch_chunk(g, prof),
             stackable=_same_shapes, on_epoch=on_epoch,
-            host_prefetch=host_prefetch, skip=skip)
+            host_prefetch=host_prefetch, skip=skip,
+            first_step=self._iteration)
 
     def _begin_fit(self, resume_from: Optional[str]):
         from ..util.checkpoint import begin_fit_cursor
@@ -702,7 +710,7 @@ class MultiLayerNetwork:
     def _dispatch_one(self, b, prof) -> None:
         x, y, mask, fmask, w = b
         key = get_random().next_key()
-        with prof.time_section("pipeline/dispatch"):
+        with prof.time_section("pipeline/dispatch", step=self._iteration):
             out = self._fit_step(self._params, self._states,
                                  self._updater_state, x, y, mask, key,
                                  jnp.asarray(self._iteration), fmask, w)
@@ -714,7 +722,8 @@ class MultiLayerNetwork:
         # keys drawn in batch order — the chunked loop consumes the SAME
         # rng stream the per-step loop would
         keys = jnp.stack([get_random().next_key() for _ in group])
-        with prof.time_section("pipeline/dispatch"):
+        with prof.time_section("pipeline/dispatch", step=self._iteration,
+                               steps=len(group)):
             out = self._chunk_step(self._params, self._states,
                                    self._updater_state, xs, ys, masks,
                                    keys, jnp.asarray(self._iteration),

@@ -980,13 +980,15 @@ class ParallelWrapper:
                 self._step = None
                 self._chunk_step = None
                 self._exec_cache.clear()
-        self._ensure_parallel_state()
-        self._place_model_state()
-        if self._step is None:
-            self._step = self._build_step()
-        if steps_per_dispatch > 1 and self._chunk_step is None:
-            self._chunk_step = self._build_chunk_step()
         prof = OpProfiler.get()
+        model._fit_calls += 1
+        with prof.time_section("fit/enter", call=model._fit_calls):
+            self._ensure_parallel_state()
+            self._place_model_state()
+            if self._step is None:
+                self._step = self._build_step()
+            if steps_per_dispatch > 1 and self._chunk_step is None:
+                self._chunk_step = self._build_chunk_step()
 
         def on_epoch():
             model._epoch += 1
@@ -1008,7 +1010,8 @@ class ParallelWrapper:
             dispatch_chunk=lambda g: self._dispatch_chunk(g, prof),
             stackable=_same_shapes, on_epoch=on_epoch,
             round_to_multiple_of=self.workers_count,
-            host_prefetch=host_prefetch, skip=skip)
+            host_prefetch=host_prefetch, skip=skip,
+            first_step=model._iteration)
 
     def _bind_batch(self, ds: DataSet, w):
         """DataSet → (x, y, mask, w) as HOST arrays. The mask is the RAW
@@ -1046,7 +1049,7 @@ class ParallelWrapper:
         xs, ys, ms, ws = b
         self._inject_faults(model)
         key = get_random().next_key()
-        with prof.time_section("pipeline/dispatch"):
+        with prof.time_section("pipeline/dispatch", step=model._iteration):
             out = self._step(model._params, model._states,
                              model._updater_state, model._acc_state, xs,
                              ys, ms, ws, key, jnp.asarray(model._iteration))
@@ -1067,7 +1070,8 @@ class ParallelWrapper:
         stack = lambda i: jnp.stack([b[i] for b in group])  # noqa: E731
         self._inject_faults(model)
         keys = jnp.stack([get_random().next_key() for _ in group])
-        with prof.time_section("pipeline/dispatch"):
+        with prof.time_section("pipeline/dispatch", step=model._iteration,
+                               steps=len(group)):
             out = self._chunk_step(model._params, model._states,
                                    model._updater_state, model._acc_state,
                                    stack(0), stack(1), stack(2), stack(3),
