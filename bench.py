@@ -206,10 +206,11 @@ def _resnet50_model(image_size: int = 224):
 
 
 def _resnet50_train_model(image_size: int = 224):
-    """The flagship training cell's model: :func:`_resnet50_model` with the
-    in-graph MFU tier on (ISSUE 8) — flat-bucket fused weight update + bf16
-    updater state w/ stochastic rounding (mfu-smoke A/B-gates the tier).
-    Shared by bench_resnet50 and chip_smoke.py."""
+    """The flagship training cell's model: :func:`_resnet50_model` with
+    bf16 updater state w/ stochastic rounding. ``fused_update`` is set as
+    the benchmark's configuration sets it and selects nothing since PR 27
+    (the unsharded step updates leaf by leaf; ZeRO-1 takes the bucket
+    kernel unasked). Shared by bench_resnet50 and chip_smoke.py."""
     model = _resnet50_model(image_size)
     model.conf.global_conf.fused_update = True
     model.conf.global_conf.updater.state_dtype = "bfloat16"
@@ -362,8 +363,7 @@ def bench_resnet50(steps: int, batch: int = 64, image_size: int = 224,
         "resnet50_imagenet_train", times, batch, flops,
         jax.devices()[0].platform,
         {"image_size": image_size,
-         "dtype": "bf16 compute / fp32 params / bf16 updater state "
-                  "(fused flat-bucket update)",
+         "dtype": "bf16 compute / fp32 params / bf16 updater state",
          # the BENCH_r* trajectory captures the footprint win, not just
          # img/s: state bytes by dtype + the fused-kernel hit ledger
          "updater_state_bytes": state_bytes,
@@ -1428,10 +1428,6 @@ def bench_mfu_smoke(steps: int, batch: int = 64) -> dict:
       (|Δ| <= 1e-3 + 0.05*|ref| per step loss and final params);
     - updater-state footprint above 0.55x fp32 (the halving is the
       point: moments are the whole Adam state);
-    - a fused fit that compiled WITHOUT the flat-backward epilogue
-      (precision/grads_flat_in_step gauge must read 1 — the grads are
-      born in bucket layout and the updater folds into the same
-      dispatch; remat-smoke A/Bs the knob itself);
     - any retrace delta between configs, or any retrace inside a timed
       window;
     - step-time regression (ratio of min-over-interleaved-rounds — the
@@ -1575,14 +1571,9 @@ def bench_mfu_smoke(steps: int, batch: int = 64) -> dict:
     if bytes_c["total"] > 0.55 * bytes_a["total"]:
         fail("bf16 updater-state footprint above 0.55x fp32",
              fp32_bytes=bytes_a["total"], bf16_bytes=bytes_c["total"])
-    # the fused configs must have taken the flat-backward epilogue —
-    # grads born in bucket layout, optimizer folded into the same
-    # compiled dispatch, no dense grad tree materialized (the trace-time
-    # gauge records which path the fused step compiled with; remat-smoke
-    # A/Bs the knob itself)
-    if fit_ledger.get("grads_flat_in_step") != 1:
-        fail("fused fit did not compile the flat-backward epilogue "
-             "(precision/grads_flat_in_step != 1)", ledger=fit_ledger)
+    # (the flat-backward gauge gate is gone with the single-device bucket
+    # path, PR 27: `fused` now compiles the same tree step as `base`;
+    # ROADMAP.md D5 deletes this smoke)
 
     # --- gate 4: interleaved A/B step time -----------------------------
     # Two budgets: the FUSION must be free (fused fp32 vs base ≤5% —
@@ -1704,9 +1695,6 @@ def bench_remat_smoke(steps: int, batch: int = 64) -> dict:
       flat cotangent is the EXACT concatenation of the dense leaf
       cotangents via Zero1Plan.unflatten_diff — drift means the adjoint
       is wrong);
-    - a flat-backward leg that compiled without the epilogue
-      (precision/grads_flat_in_step must read 1) or a legacy leg that
-      claims it (must read 0);
     - any retrace delta between configs, a policy flip that costs more
       than exactly ONE retrace, or any retrace inside the timed
       steady-state windows;
@@ -1806,10 +1794,8 @@ def bench_remat_smoke(steps: int, batch: int = 64) -> dict:
     if not bitwise(models["legacy"]._updater_state,
                    models["none"]._updater_state):
         fail("flat-backward updater state drifted from the legacy step")
-    for name, want in (("none", 1), ("legacy", 0)):
-        if ledger[name].get("grads_flat_in_step") != want:
-            fail(f"config {name!r}: precision/grads_flat_in_step != "
-                 f"{want}", ledger=ledger[name])
+    # (no gauge gate: since PR 27 both legs compile the tree step — the
+    # flat_backward axis lives under ZeRO-1 only, tests/test_remat_policies)
 
     # --- gate 3: retrace accounting ------------------------------------
     if len({tuple(sorted(w.items())) for w in warm.values()}) != 1:

@@ -257,7 +257,7 @@ def phase_device() -> dict:
             "peak_flops": peak_flops, "peak_bytes_per_s": peak_bytes}
 
 
-def phase_train(cfg: Sizes, kernels: bool, holder: dict) -> dict:
+def phase_train(cfg: Sizes, holder: dict) -> dict:
     """ResNet-50 exactly as the flagship cell builds it, through
     ComputationGraph.fit on one fixed synthetic batch."""
     import jax
@@ -291,10 +291,10 @@ def phase_train(cfg: Sizes, kernels: bool, holder: dict) -> dict:
           traces=traces)
     pallas = prof.counter_value("precision/fused_buckets_pallas")
     xla = prof.counter_value("precision/fused_buckets_xla")
-    if kernels:
-        check(pallas > 0 and xla == 0,
-              "the fused update did not run as the Pallas kernel",
-              fused_buckets_pallas=pallas, fused_buckets_xla=xla)
+    check(pallas == 0 and xla == 0,
+          "the unsharded step built a flat bucket (the update runs leaf "
+          "by leaf, in the layout the state lives in)",
+          fused_buckets_pallas=pallas, fused_buckets_xla=xla)
     holder["model"] = model      # the serve phase serves this model
     return {"model": "ResNet-50", "image": cfg.image, "batch": cfg.batch,
             "steps": cfg.steps, "losses": [round(l, 4) for l in losses],
@@ -516,8 +516,9 @@ def _mesh_devices(pw, n: int, platform: str) -> List[int]:
 
 
 def phase_pw_resnet50(cfg: Sizes, kernels: bool, n: int) -> dict:
-    """ParallelWrapper over the flagship ResNet-50 — dense all-reduce and
-    ZeRO-1, both with the fused update — against single-chip fit on the
+    """ParallelWrapper over the flagship ResNet-50 — dense all-reduce
+    (trees, updated leaf by leaf) and ZeRO-1 (flat buckets, the fused
+    Pallas update) — against single-chip fit on the
     same global batch and seed. Per-shard BatchNorm statistics (batch/n
     rows) differ from whole-batch ones, so this is not the strict check
     (that is phase_pw_lenet): the FIRST loss — same parameters, forward
@@ -573,9 +574,14 @@ def phase_pw_resnet50(cfg: Sizes, kernels: bool, n: int) -> dict:
               traces=traces)
         pallas = prof.counter_value("precision/fused_buckets_pallas")
         xla = prof.counter_value("precision/fused_buckets_xla")
-        if kernels:
+        if acc is None:
+            # dense all-reduce keeps trees: no bucket in the step
+            check(pallas == 0 and xla == 0,
+                  "dense: the step built a flat bucket",
+                  fused_buckets_pallas=pallas, fused_buckets_xla=xla)
+        elif kernels:
             check(pallas > 0 and xla == 0,
-                  f"{name}: fused update did not run as the Pallas kernel",
+                  "zero1: fused update did not run as the Pallas kernel",
                   fused_buckets_pallas=pallas, fused_buckets_xla=xla)
         exact[name] = losses
         row = {"losses": [round(l, 4) for l in losses],
@@ -757,7 +763,7 @@ def main() -> int:
             ("device", phase_device),
             ("attention", lambda: phase_attention(cfg, kernels)),
             ("embeddings", lambda: phase_embeddings(cfg)),
-            ("train", lambda: phase_train(cfg, kernels, holder)),
+            ("train", lambda: phase_train(cfg, holder)),
             ("serve", lambda: phase_serve(cfg, holder)),
             ("samediff", lambda: phase_samediff(cfg)),
         ]

@@ -67,30 +67,25 @@ class GlobalConf:
     # loss sequences are bit-identical across policies on a fixed
     # platform (pinned by tests/test_remat_policies.py).
     remat_policy: Any = None
-    # Fused weight update: flatten params/grads(/updater state) into
-    # Zero1Plan per-dtype buckets INSIDE the compiled step and apply the
-    # updater through ops/pallas_update — one fused kernel launch per
-    # bucket (a Pallas kernel on TPU, one flat XLA elementwise kernel
-    # elsewhere) instead of a handful of ops per parameter leaf. fp32
-    # results are bit-identical to the per-leaf path at the kernel level
-    # (pinned by tests/test_precision.py); inside a full compiled step
-    # XLA may fma-contract the mul-add chains differently for the flat
-    # shape — Sgd stays bitwise end-to-end, the momentum/Adam family can
-    # drift ≤ a few ulp (measured ≤3e-8 after 2 epochs). Composes with
-    # ``updater.state_dtype`` (bf16 moments + stochastic rounding).
-    # Requires an elementwise updater (falls back, warned, otherwise).
+    # Accepted, and selects nothing: where params and updater state are
+    # whole on the device as trees (ComputationGraph.fit,
+    # MultiLayerNetwork.fit, ParallelWrapper's dense all-reduce) the step
+    # updates them leaf by leaf in the layout they live in, whatever this
+    # says. It used to flatten params/grads/state into Zero1Plan buckets
+    # inside the step for one ops/pallas_update kernel per bucket; on a
+    # TPU that round trip is a physical relayout of every leaf and cost
+    # 46 ms of a 93 ms ResNet-50 step (PERF.md, PR 27). The bucket kernel
+    # is ZeRO-1's (ReduceScatterAccumulator), which needs no switch. Kept
+    # because configurations assign it; ROADMAP.md D3 removes it.
     fused_update: bool = False
-    # Backward-epilogue fusion (rides on fused_update): differentiate
+    # Backward-epilogue fusion under ZeRO-1 (ParallelWrapper with
+    # ReduceScatterAccumulator; nothing else reads it): differentiate
     # w.r.t. the plan's FLAT buckets so the cotangents accumulate
-    # directly into flat layout and the dense grad pytree never
-    # materializes between the backward and the updater — the
-    # 2-copy→1-copy grad-epilogue fix for the HBM roofline. Bitwise
-    # identical to the dense-then-flatten path (the unflatten in the
-    # forward is a pure permutation, so leaf cotangents are computed by
-    # the exact same ops). On by default; set False to force the legacy
-    # dense-grads-then-flatten step (the bench A/B axis). Auto-disabled
-    # when telemetry or a dense-tree grad-normalization mode needs the
-    # dense grads.
+    # directly into the layout the reduce-scatter takes and the dense
+    # grad pytree never materializes. Bitwise identical to the
+    # dense-then-flatten step (Zero1Plan.unflatten_diff spells out the
+    # adjoint). On by default; False forces dense-grads-then-flatten.
+    # Auto-disabled when telemetry stats need the dense grads.
     flat_backward: bool = True
     # Fused inference epilogue (ops/pallas_epilogue): inference-mode
     # BatchNormalization + relu/identity collapse into one kernel, and
@@ -221,9 +216,9 @@ class Builder:
         return self
 
     def fused_update(self, v: bool = True) -> "Builder":
-        """Apply the updater over flat per-dtype buckets in fused kernels
-        (ops/pallas_update) instead of leaf-by-leaf. fp32-bitwise; see
-        GlobalConf.fused_update."""
+        """Accepted for configurations that set it; selects nothing (the
+        step updates each leaf in its own layout, and ZeRO-1 takes the
+        bucket kernel unasked). See GlobalConf.fused_update."""
         self._conf.fused_update = bool(v)
         return self
 

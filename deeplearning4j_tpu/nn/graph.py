@@ -742,25 +742,15 @@ class ComputationGraph:
         return inputs, labels, masks
 
     # --- training --------------------------------------------------------
-    def _fused_flat_plan(self):
-        from .multilayer import _fused_flat_plan
-
-        return _fused_flat_plan(self.conf, self._params)
-
     def _step_core(self):
         """Single train-step computation, shared by the per-step jit and
         the multi-step lax.scan dispatch (see multilayer._step_core)."""
         gc = self.conf.global_conf
         updater = gc.updater
         tele = self._telemetry
-        fused_plan = self._fused_flat_plan()
-        # backward-epilogue fusion gate — see multilayer._step_core
-        flat_bwd = (fused_plan is not None and tele is None
-                    and not gc.grad_normalization
-                    and getattr(gc, "flat_backward", True))
         from ..learning import precision as _prec
         from ..optimize import telemetry as _tel
-        from .multilayer import _apply_fused_flat
+        from .multilayer import _normalize_gradients
 
         def core(params, states, upd_state, inputs, labels, masks, key,
                  iteration, w):
@@ -769,37 +759,17 @@ class ComputationGraph:
                                               True, key, w=w)
                 return loss, new_states
 
-            if flat_bwd:
-                flat_params = fused_plan.flatten(params)
-                (loss, new_states), flat_grads = jax.value_and_grad(
-                    lambda fp: loss_fn(fused_plan.unflatten_diff(fp)),
-                    has_aux=True)(flat_params)
-                new_params, new_upd = _apply_fused_flat(
-                    fused_plan, updater, flat_grads, upd_state, params,
-                    iteration, key, flat_params=flat_params,
-                    grads_flat=True)
-            else:
-                (loss, new_states), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(params)
-                if gc.grad_normalization:
-                    from .multilayer import _normalize_gradients
-
-                    grads = _normalize_gradients(
-                        grads, gc.grad_normalization,
-                        gc.grad_norm_threshold)
-                if fused_plan is not None:
-                    new_params, new_upd = _apply_fused_flat(
-                        fused_plan, updater, grads, upd_state, params,
-                        iteration, key)
-                else:
-                    new_params, new_upd = _prec.apply_updater(
-                        updater, grads, upd_state, params, iteration, key)
+            (loss, new_states), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params)
+            if gc.grad_normalization:
+                grads = _normalize_gradients(
+                    grads, gc.grad_normalization, gc.grad_norm_threshold)
+            OpProfiler.get().gauge("precision/grads_flat_in_step", 0)
+            new_params, new_upd = _prec.apply_updater(
+                updater, grads, upd_state, params, iteration, key)
             if tele is None:
                 return new_params, new_states, new_upd, loss
             # per-node stats in sorted node-name order (telemetry.groups)
-            # graftlint: disable=donated-grad-escape -- in-graph read: the
-            # telemetry path runs with grads_flat=False, so _apply_fused_flat
-            # flattened a COPY and XLA keeps the traced dense tree alive
             aux = _tel.layer_stats(params, new_params, grads, loss)
             if tele.nan_guard:
                 aux, new_params, new_states, new_upd = _tel.apply_nan_guard(

@@ -384,9 +384,6 @@ class MultiLayerNetwork:
         return [i for i, l in enumerate(self.layers)
                 if isinstance(l, L.FrozenLayer)]
 
-    def _fused_flat_plan(self):
-        return _fused_flat_plan(self.conf, self._params)
-
     def _step_core(self):
         """The single train-step computation, shared verbatim by the
         per-step jit and the multi-step ``lax.scan`` dispatch so the two
@@ -409,17 +406,6 @@ class MultiLayerNetwork:
         updater = gc.updater
         frozen = self._frozen_indices()
         tele = self._telemetry
-        fused_plan = self._fused_flat_plan()
-        # Backward-epilogue fusion: differentiate w.r.t. the plan's FLAT
-        # buckets (the forward unflattens them — a pure permutation, so
-        # the cotangents accumulate directly into flat layout and the
-        # dense grad pytree never materializes between the backward and
-        # the updater). Gated off when telemetry wants per-layer dense
-        # grads or a grad-normalization mode defined on the dense tree is
-        # configured — those keep the dense-then-flatten path.
-        flat_bwd = (fused_plan is not None and tele is None
-                    and not gc.grad_normalization
-                    and getattr(gc, "flat_backward", True))
         from ..learning import precision as _prec
         from ..optimize import telemetry as _tel
 
@@ -443,29 +429,14 @@ class MultiLayerNetwork:
                                             hp["l2"])
                 return loss, new_states
 
-            if flat_bwd:
-                flat_params = fused_plan.flatten(params)
-                (loss, new_states), flat_grads = jax.value_and_grad(
-                    lambda fp: loss_fn(fused_plan.unflatten_diff(fp)),
-                    has_aux=True)(flat_params)
-                new_params, new_upd = _apply_fused_flat(
-                    fused_plan, up, flat_grads, upd_state, params,
-                    iteration, key, flat_params=flat_params,
-                    grads_flat=True)
-            else:
-                (loss, new_states), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(params)
-                if gc.grad_normalization:
-                    grads = _normalize_gradients(
-                        grads, gc.grad_normalization,
-                        gc.grad_norm_threshold)
-                if fused_plan is not None:
-                    new_params, new_upd = _apply_fused_flat(
-                        fused_plan, up, grads, upd_state, params,
-                        iteration, key)
-                else:
-                    new_params, new_upd = _prec.apply_updater(
-                        up, grads, upd_state, params, iteration, key)
+            (loss, new_states), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params)
+            if gc.grad_normalization:
+                grads = _normalize_gradients(
+                    grads, gc.grad_normalization, gc.grad_norm_threshold)
+            OpProfiler.get().gauge("precision/grads_flat_in_step", 0)
+            new_params, new_upd = _prec.apply_updater(
+                up, grads, upd_state, params, iteration, key)
             for i in frozen:
                 # stop_gradient already zeroes their grads; restoring the
                 # original tensors also shields them from stateful-updater
@@ -474,10 +445,6 @@ class MultiLayerNetwork:
             new_params = self._apply_constraints(new_params)
             if tele is None:
                 return new_params, new_states, new_upd, loss
-            # graftlint: disable=donated-grad-escape -- in-graph read: the
-            # telemetry path runs with grads_flat=False, so _apply_fused_flat
-            # flattened a COPY and XLA keeps the traced dense tree alive;
-            # donation frees only jit-boundary buffers, never mid-graph values
             aux = _tel.layer_stats(params, new_params, grads, loss)
             if tele.nan_guard:
                 aux, new_params, new_states, new_upd = _tel.apply_nan_guard(
@@ -978,66 +945,6 @@ class MultiLayerNetwork:
         net._params = jax.tree.map(jnp.array, self._params)
         net._states = jax.tree.map(jnp.array, self._states)
         return net
-
-
-def _fused_flat_plan(conf, params):
-    """The ``Zero1Plan(params, 1)`` behind ``fused_update`` — the
-    single-device flat path shared by MultiLayerNetwork and
-    ComputationGraph (both flatten params the same way: a pytree-keyed
-    pure permutation): params/grads/updater state flatten into per-dtype
-    buckets inside the step and the update runs as ONE fused kernel per
-    bucket (ops/pallas_update) instead of per-leaf ops. None when the
-    knob is off or the updater is not elementwise (flat application of a
-    coupled updater would change the math — refuse and fall back,
-    ledgered + warned)."""
-    if not getattr(conf.global_conf, "fused_update", False):
-        return None
-    updater = conf.global_conf.updater
-    if not getattr(updater, "elementwise", False):
-        OpProfiler.get().count("precision/fused_fallbacks")
-        import logging
-
-        logging.getLogger("deeplearning4j_tpu").warning(
-            "fused_update requested but %s does not declare "
-            "elementwise=True; using the per-leaf updater path",
-            type(updater).__name__)
-        return None
-    from ..parallel.sharding import Zero1Plan
-
-    return Zero1Plan(params, 1)
-
-
-def _apply_fused_flat(plan, updater, grads, upd_state, params, iteration,
-                      key, flat_params=None, grads_flat=False):
-    """The single-device fused-update body (traced into the step):
-    flatten params/grads/state through ``plan``'s pure-permutation bucket
-    layout, run one fused kernel per bucket, unflatten back. The model
-    keeps its DENSE layouts between steps — checkpointing, listeners and
-    the serializers see exactly what they always saw.
-
-    ``grads_flat=True`` (the backward-epilogue path): ``grads`` is
-    ALREADY the plan's flat-bucket dict — the backward differentiated
-    w.r.t. the flat params, so no dense grad tree ever existed and no
-    flatten copy is paid here. ``flat_params`` lets the caller reuse the
-    flat view it already built for that backward. The trace-time
-    ``precision/grads_flat_in_step`` gauge records which path the
-    compiled step took (1 = grads born flat, single fused grad+update
-    epilogue; 0 = legacy dense-grads-then-flatten) — the
-    2-dispatch→1-dispatch claim, observable on /api/metrics."""
-    from ..ops.pallas_update import apply_flat_updater
-
-    OpProfiler.get().gauge("precision/grads_flat_in_step",
-                           1 if grads_flat else 0)
-    flat_p = plan.flatten(params) if flat_params is None else flat_params
-    flat_g = grads if grads_flat else plan.flatten(grads)
-    flat_s = (plan.flatten_state(upd_state, xp=jnp)
-              if isinstance(upd_state, dict) else upd_state)
-    new_flat, new_flat_s = apply_flat_updater(updater, flat_p, flat_g,
-                                              flat_s, iteration, key)
-    new_params = plan.unflatten(new_flat)
-    new_upd = (plan.unflatten_state_inplan(new_flat_s)
-               if isinstance(new_flat_s, dict) else new_flat_s)
-    return new_params, new_upd
 
 
 def _weak_scalar(v):

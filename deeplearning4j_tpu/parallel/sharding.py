@@ -98,7 +98,14 @@ class Zero1Plan:
     split + reshape — no arithmetic), so running an ELEMENTWISE updater on
     the flat buffers is bit-identical to running it leaf-by-leaf; the
     in-graph versions trace into the compiled step, and ``xp=np`` gives
-    the host-side versions checkpointing uses."""
+    the host-side versions checkpointing uses.
+
+    No arithmetic is not no cost: on a TPU a rank-4 leaf lives tiled, and
+    ravel/reshape to and from rank 1 is a physical relayout (a
+    ``f32[512,512,3,3]`` leaf: 1.46 ms, ≈13 GB/s against 819 GB/s of
+    HBM). A plan therefore belongs in a step only where a collective
+    wants the contiguous buffer (``n_shards > 1``); with one shard the
+    round trip was half of a ResNet-50 step (PERF.md, PR 27)."""
 
     def __init__(self, params, n_shards: int):
         from ..optimize.telemetry import groups
@@ -228,23 +235,6 @@ class Zero1Plan:
         for k, v in state.items():
             if jax.tree.structure(v) == self.treedef:
                 out[k] = self.flatten(v, xp=xp)
-            else:
-                out[k] = v
-        return out
-
-    def unflatten_state_inplan(self, state, xp=jnp):
-        """Flat updater state already in THIS plan's exact padded layout →
-        dense tree. Unlike :meth:`unflatten_state` it never touches numpy
-        (no repad/validation), so it is safe to TRACE into a compiled
-        step — the single-device fused-update path densifies the state it
-        just updated with this."""
-        out = {}
-        for k, v in state.items():
-            if isinstance(v, dict) and v and all(
-                    str(kk).startswith(FLAT_PREFIX) for kk in v):
-                out[k] = self.unflatten(
-                    {b.key: v[b.key][:b.total] for b in self.buckets},
-                    xp=xp)
             else:
                 out[k] = v
         return out

@@ -36,7 +36,7 @@ from ..common.profiler import OpProfiler
 from ..data import pipeline as _pipe
 from ..data.dataset import DataSet
 from ..ndarray.rng import get_random
-from ..nn.multilayer import _apply_fused_flat, _fused_flat_plan, _same_shapes
+from ..nn.multilayer import _same_shapes
 from .accumulator import DenseAllReduceAccumulator, GradientsAccumulator
 from .mesh import elastic_pool, make_mesh, probe_device, shard_batch
 from .sharding import Zero1Plan, is_flat_state
@@ -191,23 +191,19 @@ class ParallelWrapper:
                     "invariant — model-sharded params have no replica "
                     "copies to compare")
 
-        # Backward-epilogue fusion (mirrors the solo _step_core): when the
-        # updater consumes FLAT buckets anyway (ZeRO-1 always; dense when
-        # `fused_update` is on), differentiate w.r.t. the flat params — the
-        # forward unflattens them (a pure permutation), so the cotangents
-        # accumulate directly into flat layout and the dense grad pytree
-        # never materializes between the backward and the exchange. Gated
-        # off when telemetry stats need the raw dense per-shard grads
-        # (nonfinite_counts / layer_stats walk the layer tree) and for
-        # stateful accumulators (residual carry is a dense-tree pytree) —
-        # a stats-off aux (integrity fingerprints only) keeps it on.
-        dense_fused_plan = (None if (zero1 or stateful or stats)
-                            else _fused_flat_plan(model.conf, model._params))
-        flat_bwd = (not stats and not stateful
+        # Backward-epilogue fusion, ZeRO-1's alone: its updater consumes
+        # FLAT buckets (the unit of the reduce-scatter and the all-gather),
+        # so differentiate w.r.t. the flat params — the forward unflattens
+        # them and the cotangents accumulate directly into flat layout for
+        # the exchange. The dense layouts keep params, grads and state as
+        # trees: on a TPU a bucket round trip is a physical relayout of
+        # every leaf (PERF.md, PR 27), which only a collective pays for.
+        # Gated off when telemetry stats need the raw dense per-shard grads
+        # (nonfinite_counts / layer_stats walk the layer tree) — a
+        # stats-off aux (integrity fingerprints only) keeps it on.
+        flat_bwd = (zero1 and not stats
                     and getattr(model.conf.global_conf, "flat_backward",
-                                True)
-                    and (zero1 or dense_fused_plan is not None))
-        bwd_plan = plan if zero1 else dense_fused_plan
+                                True))
 
         def local_step(params, states, upd_state, acc_state, x, y, mask, w,
                        key, it):
@@ -244,15 +240,16 @@ class ParallelWrapper:
                 return loss, new_states
 
             if flat_bwd:
-                flat_params = bwd_plan.flatten(params)
+                flat_params = plan.flatten(params)
                 (loss, new_states), flat_grads = jax.value_and_grad(
-                    lambda fp: loss_fn(bwd_plan.unflatten_diff(fp)),
+                    lambda fp: loss_fn(plan.unflatten_diff(fp)),
                     has_aux=True)(flat_params)
                 OpProfiler.get().gauge("precision/grads_flat_in_step", 1)
                 grads = None
             else:
                 (loss, new_states), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params)
+                OpProfiler.get().gauge("precision/grads_flat_in_step", 0)
             if stats:
                 # non-finite counts are taken on the RAW per-shard grads
                 # (reduction would smear one shard's NaN across all of
@@ -289,16 +286,6 @@ class ParallelWrapper:
                 gathered = {k: jax.lax.all_gather(v, axis, tiled=True)
                             for k, v in new_p_sh.items()}
                 new_params = plan.unflatten(gathered)
-            elif flat_bwd:
-                # dense data-parallel fused epilogue: pmean the FLAT buckets
-                # (elementwise — bitwise-equal to flattening the pmean'd
-                # dense tree) and run the fused grad+update in the same
-                # compiled step, full-width on every replica
-                flat_grads = acc.reduce_gradients(flat_grads)
-                new_params, new_upd = _apply_fused_flat(
-                    dense_fused_plan, updater, flat_grads, upd_state,
-                    params, it, shared_key, flat_params=flat_params,
-                    grads_flat=True)
             else:
                 if not stateful:
                     grads = acc.reduce_gradients(grads)

@@ -1,6 +1,6 @@
 """Seeded donated-grad-escape regressions: grads read after the fused
 epilogue consumed them inside the step. Four sins."""
-from somewhere import apply_flat_updater, _apply_fused_flat, log_norm
+from somewhere import apply_flat_updater, fused_apply, log_norm
 
 
 def plain_read_after_consume(up, flat_p, flat_g, st, it, key):
@@ -15,9 +15,9 @@ def subscript_read_after_consume(up, flat_p, g_sh, st, it, key, buckets):
     return new_p_sh, new_s, parts
 
 
-def keyword_consume_then_read(plan, up, grads, st, params, it, key):
-    new_p, new_s = _apply_fused_flat(plan, up, st, params, it, key,
-                                     flat_grads=grads, grads_flat=True)
+def keyword_consume_then_read(up, flat_p, grads, st, it, key):
+    new_p, new_s = fused_apply(up, flat_p, state=st, iteration=it, key=key,
+                               flat_grads=grads)
     tail = grads                                  # sin 3: kw-arg consume
     return new_p, new_s, tail
 

@@ -302,7 +302,12 @@ class TestFitIntegration:
         m.fit(fit_data(), epochs=3, batch_size=16)
         assert prof.trace_counts() == {"trace/mln_fit_step": 1}
 
-    def test_non_elementwise_updater_warns_and_falls_back(self, caplog):
+    def test_non_elementwise_updater_has_nothing_to_fall_back_from(
+            self, caplog):
+        """On unsharded state ``fused_update`` selects nothing (PR 27):
+        a coupled updater is applied leaf by leaf like any other — no
+        warning, no ledgered fallback, the same bits. (ZeRO-1 refuses
+        it at build.)"""
         import logging
 
         class Coupled(GradientUpdater):
@@ -322,7 +327,9 @@ class TestFitIntegration:
         with caplog.at_level(logging.WARNING, "deeplearning4j_tpu"):
             m = mln(Coupled(), fused=True)
             m.fit(fit_data(), epochs=1, batch_size=16)
-        assert any("elementwise" in r.message for r in caplog.records)
+        assert not any("elementwise" in r.message for r in caplog.records)
+        assert OpProfiler.get().counter_value(
+            "precision/fused_fallbacks") == 0
         ref = mln(Coupled())
         ref.fit(fit_data(), epochs=1, batch_size=16)
         assert tree_bitwise(ref._params, m._params)
@@ -769,11 +776,9 @@ class TestEpilogueGraphFusion:
 
 class TestLedger:
     def test_precision_stats_populated(self):
+        """The bucket kernel's ledger, where it still runs: ZeRO-1."""
         prof = OpProfiler.get()
-        u = Adam(1e-3)
-        u.state_dtype = "bfloat16"
-        m = mln(u, fused=True)
-        m.fit(fit_data(), epochs=1, batch_size=16)
+        run_zero1(wrapper_model("bfloat16"), workers=2, epochs=1)
         stats = prof.precision_stats()
         assert stats["fused_hits"] >= 1
         assert stats["sr_draws"] > 0
@@ -784,10 +789,7 @@ class TestLedger:
     def test_health_endpoint_has_precision_section(self):
         from deeplearning4j_tpu.ui.server import UIServer
 
-        u = Adam(1e-3)
-        u.state_dtype = "bfloat16"
-        m = mln(u, fused=True)
-        m.fit(fit_data(), epochs=1, batch_size=16)
+        run_zero1(wrapper_model("bfloat16"), workers=2, epochs=1)
         ui = UIServer()
         h = ui.health()
         assert "precision" in h and h["precision"]["fused_hits"] >= 1

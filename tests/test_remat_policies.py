@@ -3,8 +3,9 @@ named remat policies are numerically free (bitwise loss/param parity vs
 "none" on CPU), a policy flip costs exactly one recompile, the remat
 primitive really lands in the jaxpr, dots_only's memory win is asserted
 on hardware (TPU-gated like test_l6_features — the CPU scheduler shows
-the inverse), and the flat-backward fused epilogue is ledgered and
-bitwise against the legacy dense-grads-then-flatten step."""
+the inverse), and the flat-backward fused epilogue — ZeRO-1's alone since
+the unsharded step updates trees (PR 27) — is ledgered and bitwise
+against the dense-grads-then-flatten step on a 2-device ZeRO-1 wrapper."""
 
 import numpy as np
 import pytest
@@ -22,6 +23,8 @@ from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.conf.builder import (REMAT_POLICIES,
                                                 effective_remat_policy,
                                                 remat_wrap)
+from deeplearning4j_tpu.parallel import (ParallelWrapper,
+                                         ReduceScatterAccumulator)
 
 f32 = jnp.float32
 
@@ -39,12 +42,10 @@ def tree_bitwise(a, b):
         np.array_equal(np.asarray(x), np.asarray(y)) for x, y in zip(la, lb))
 
 
-def stack(policy=None, updater=None, fused=False, depth=3, width=32,
-          flat_backward=True, seed=11):
+def stack(policy=None, updater=None, depth=3, width=32, flat_backward=True,
+          seed=11):
     b = NeuralNetConfiguration.builder().seed(seed)
     b = b.updater(updater if updater is not None else Sgd(0.05))
-    if fused:
-        b = b.fused_update()
     if policy is not None:
         b = b.remat_policy(policy)
     lb = b.list()
@@ -61,6 +62,15 @@ def fit_data(n=64):
     rng = np.random.default_rng(3)
     return DataSet(rng.normal(size=(n, 16)).astype(np.float32),
                    np.eye(5, dtype=np.float32)[rng.integers(0, 5, n)])
+
+
+def zero1_fit(model, epochs=1):
+    """Where the flat path lives: the state sharded as flat buckets over
+    two (virtual CPU) devices."""
+    pw = (ParallelWrapper.Builder(model).workers(2)
+          .gradients_accumulator(ReduceScatterAccumulator()).build())
+    pw.fit(fit_data(), epochs=epochs, batch_size=32)
+    return model
 
 
 # ---------------------------------------------------------------------------
@@ -91,12 +101,9 @@ class TestPolicyParity:
         assert tree_bitwise(base._params, rem._params)
 
     def test_parity_holds_with_fused_epilogue(self):
-        """Policy × fused flat-backward compose: still bitwise."""
-        ds = fit_data()
-        base = stack(policy=None, fused=True)
-        rem = stack(policy="dots_only", fused=True)
-        base.fit(ds, epochs=3, batch_size=32)
-        rem.fit(ds, epochs=3, batch_size=32)
+        """Policy × fused flat-backward (ZeRO-1) compose: still bitwise."""
+        base = zero1_fit(stack(policy=None), epochs=3)
+        rem = zero1_fit(stack(policy="dots_only"), epochs=3)
         assert tree_bitwise(base._params, rem._params)
 
     def test_unknown_policy_rejected_at_build(self):
@@ -230,14 +237,12 @@ class TestWatermark:
 
 class TestFusedEpilogue:
     def test_fused_fit_sets_grads_flat_gauge(self):
-        m = stack(updater=Sgd(0.05), fused=True)
-        m.fit(fit_data(), epochs=1, batch_size=32)
+        zero1_fit(stack(updater=Sgd(0.05)))
         stats = OpProfiler.get().precision_stats()
         assert stats.get("grads_flat_in_step") == 1
 
     def test_legacy_path_reports_dense_grads(self):
-        m = stack(updater=Sgd(0.05), fused=True, flat_backward=False)
-        m.fit(fit_data(), epochs=1, batch_size=32)
+        zero1_fit(stack(updater=Sgd(0.05), flat_backward=False))
         stats = OpProfiler.get().precision_stats()
         assert stats.get("grads_flat_in_step") == 0
 
@@ -249,11 +254,10 @@ class TestFusedEpilogue:
         leaf cotangents (Zero1Plan.unflatten_diff spells out the
         adjoint), so flat-backward vs legacy dense-then-flatten is
         bitwise — for Adam too, not just ulp-bounded."""
-        ds = fit_data()
-        a = stack(updater=updater(), fused=True, flat_backward=False)
-        b = stack(updater=updater(), fused=True, flat_backward=True)
-        a.fit(ds, epochs=3, batch_size=32)
-        b.fit(ds, epochs=3, batch_size=32)
+        a = zero1_fit(stack(updater=updater(), flat_backward=False),
+                      epochs=3)
+        b = zero1_fit(stack(updater=updater(), flat_backward=True),
+                      epochs=3)
         assert tree_bitwise(a._params, b._params)
         assert tree_bitwise(a._updater_state, b._updater_state)
 

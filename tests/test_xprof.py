@@ -316,15 +316,23 @@ class TestTrainerFamilies:
             eng.shutdown()
 
     def test_fused_pallas_counted_subexec(self, fresh_census):
+        """The bucket kernel runs where the state is sharded as flat
+        buckets (ZeRO-1), over this replica's slice."""
+        from deeplearning4j_tpu.parallel import (ParallelWrapper,
+                                                 ReduceScatterAccumulator)
+        from deeplearning4j_tpu.parallel.sharding import Zero1Plan
+
         model = _mlp(updater=Adam(1e-3))
-        model.conf.global_conf.fused_update = True
         _, _, it = _batches()
-        model.fit(it, epochs=1)
+        pw = (ParallelWrapper.Builder(model).workers(2)
+              .gradients_accumulator(ReduceScatterAccumulator()).build())
+        pw.fit(it, epochs=1)
         e = xprof.census()["pallas/update_bucket"]
         assert e["subexec"] is True and e["cost_source"] == "counted"
-        n_params = model.num_params()
+        shard = sum(b.shard for b in Zero1Plan(model._params, 2).buckets)
+        assert shard * 2 >= model.num_params()
         # adam: 12 flops/elem analytic; one trace -> one bump
-        assert e["cost"]["flops"] == pytest.approx(12 * n_params)
+        assert e["cost"]["flops"] == pytest.approx(12 * shard)
         assert e["cost"]["bytes_accessed"] > 0
 
     def test_exec_events_emitted(self, fresh_census):

@@ -1,10 +1,10 @@
 """donated-grad-escape: grads consumed by the fused epilogue stay consumed.
 
 The backward-epilogue fusion (PR-16) hands the flat grad buckets to
-``apply_flat_updater`` / ``fused_apply`` / ``_apply_fused_flat`` INSIDE
-the jitted step, with params and updater state donated at the jit
-boundary. On TPU the fused kernel is free to update in place — a grad
-leaf read *after* the consuming call is a use-after-donate hazard: it
+``apply_flat_updater`` / ``fused_apply`` INSIDE the jitted step, with
+params and updater state donated at the jit boundary. On TPU the fused
+kernel is free to update in place — a grad leaf read *after* the
+consuming call is a use-after-donate hazard: it
 compiles clean on CPU, then reads freed (or already-overwritten) HBM
 the first time the real donation kicks in. The shipped near-miss is the
 ZeRO-1 telemetry block in parallel/wrapper.py, which reads the reduced
@@ -35,8 +35,7 @@ from ..engine import Finding, ModuleContext, Project, Rule, call_name
 
 # dotted-name tails that consume flat grads inside a step; the value is
 # the positional index of the grads argument
-_CONSUMERS = {"apply_flat_updater": 2, "fused_apply": 2,
-              "_apply_fused_flat": 2}
+_CONSUMERS = {"apply_flat_updater": 2, "fused_apply": 2}
 _GRADS_KW = ("flat_grads", "grads")
 
 # statement fields holding nested blocks (walked separately, in source
