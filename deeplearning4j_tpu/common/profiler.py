@@ -46,6 +46,7 @@ class OpProfiler:
         ("autoscale", "autoscale_stats"),
         ("fleet", "fleet_stats"),
         ("precision", "precision_stats"),
+        ("sequence", "sequence_stats"),
         ("xla", "xla_stats"),
         ("tracecheck", "tracecheck_stats"),
         ("faults", "fault_stats"),
@@ -386,6 +387,19 @@ class OpProfiler:
         Empty until a fit or fused inference runs."""
         return {k.split("/", 1)[1]: v for k, v in self._counters.items()
                 if k.startswith("precision/")}
+
+    def sequence_stats(self) -> Dict[str, float]:
+        """Sequence-op ledger (``seq/*`` counters, ``ops/ssm.py`` and
+        ``ops/pallas_attention.causal_attention``): call sites that took
+        the Pallas kernel or the plain XLA path (``scan_kernel`` /
+        ``scan_fallback``, ``attn_kernel`` / ``attn_fallback``) and the
+        (query block, key block) pairs the attention band computes and
+        leaves out of the square (``attn_key_blocks_run`` /
+        ``attn_key_blocks_skipped``, per head). Trace-time counters: one
+        bump per call site per compiled program, not per execution. Empty
+        until a sequence layer is traced."""
+        return {k.split("/", 1)[1]: v for k, v in self._counters.items()
+                if k.startswith("seq/")}
 
     def xla_stats(self) -> Dict[str, float]:
         """XLA performance-observatory ledger (``common.xprof``): the

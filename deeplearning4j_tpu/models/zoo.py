@@ -15,10 +15,11 @@ ComputationGraph flagship (north-star config 2).
 
 from __future__ import annotations
 
+import math
 import os
 from typing import Optional, Sequence, Tuple
 
-from ..learning.updaters import Adam, Nesterovs
+from ..learning.updaters import Adam, AdamW, Nesterovs
 from ..nn.conf import layers as L
 from ..nn.conf.builder import NeuralNetConfiguration
 from ..nn.conf.inputs import InputType
@@ -968,4 +969,110 @@ class NASNet(ZooModel):
                 .set_input_types(InputType.convolutional(
                     self.image_size, self.image_size, 3))
                 .build())
+        return ComputationGraph(conf).init()
+
+
+class Phi4MiniFlash(ZooModel):
+    """Phi-4-mini-flash-reasoning ("SambaY": Ren et al., arXiv:2507.06607;
+    huggingface.co/microsoft/Phi-4-mini-flash-reasoning, ``config.json``): a
+    decoder whose first half alternates Mamba-1 mixers with window-512
+    differential attention, whose middle pair is a Mamba layer that exposes
+    its scan output (the memory) and a full-attention layer that exposes its
+    keys and values, and whose second half alternates gated memory units
+    reading that memory with cross-attention over those keys and values.
+    Every block is pre-norm (LayerNorm) with a gated MLP; no positional
+    encoding; the head is the embedding table itself.
+
+    ``layers``: the published layer indices to build, in order (all
+    ``num_hidden_layers`` when None) — a pipeline stage, or one period of
+    each half. ``vocab_rows``: rows of the tied embedding/head held here (a
+    vocabulary-parallel shard; ids and the loss range over the rows).
+    Defaults are the published sizes; sizes the published config does not
+    carry (``d_state``, ``d_conv``, ``expand``, ``dt_rank = ceil(d/16)``)
+    are the ``Phi4FlashConfig`` defaults. Trained through
+    ``ComputationGraph.fit`` on ``[B, T]`` integer ids with ``[B, T]``
+    integer next-token labels."""
+
+    def __init__(self, layers: Optional[Sequence[int]] = None,
+                 vocab_rows: int = 200064, hidden_size: int = 2560,
+                 intermediate_size: int = 10240,
+                 num_attention_heads: int = 40, num_key_value_heads: int = 20,
+                 sliding_window: int = 512, mb_per_layer: int = 2,
+                 num_hidden_layers: int = 32, layer_norm_eps: float = 1e-5,
+                 d_state: int = 16, d_conv: int = 4, expand: int = 2,
+                 dt_rank: Optional[int] = None, seq_len: Optional[int] = None,
+                 compute_dtype: Optional[str] = "bfloat16",
+                 state_dtype: Optional[str] = "bfloat16",
+                 remat_policy="full", learning_rate: float = 1e-4,
+                 weight_decay: float = 0.1, seed: int = 123):
+        self.layers = list(range(num_hidden_layers) if layers is None
+                           else layers)
+        self.vocab_rows = vocab_rows
+        self.d, self.ff = hidden_size, intermediate_size
+        self.heads, self.kv_heads = num_attention_heads, num_key_value_heads
+        self.window, self.period = sliding_window, mb_per_layer
+        self.boundary = num_hidden_layers // 2   # the layer that emits memory
+        self.eps = layer_norm_eps
+        self.d_state, self.d_conv = d_state, d_conv
+        self.d_inner = expand * hidden_size
+        self.dt_rank = dt_rank or 0     # 0: the layer's own ceil(d / 16)
+        self.seq_len = seq_len
+        self.compute_dtype, self.state_dtype = compute_dtype, state_dtype
+        self.remat_policy = remat_policy
+        self.learning_rate, self.weight_decay = learning_rate, weight_decay
+        self.seed = seed
+
+    def _mixer(self, l: int):
+        """(layer, further inputs) of published layer ``l``."""
+        if l % self.period == 0:
+            if l > self.boundary:
+                return L.GatedMemoryUnit(), [f"l{self.boundary}_mix.memory"]
+            return L.MambaLayer(
+                d_inner=self.d_inner, d_state=self.d_state,
+                d_conv=self.d_conv, dt_rank=self.dt_rank,
+                emit_memory=l == self.boundary), []
+        full, cross = l == self.boundary + 1, l > self.boundary + 1
+        kv = f"l{self.boundary + 1}_mix"
+        return L.DifferentialAttentionLayer(
+            n_heads=self.heads, n_kv_heads=self.kv_heads,
+            head_dim=self.d // self.heads,
+            window=None if full or cross else self.window,
+            cross=cross, emit_kv=full, eps=self.eps,
+            lambda_init=0.8 - 0.6 * math.exp(-0.3 * l)), (
+                [kv + ".k", kv + ".v"] if cross else [])
+
+    def init(self) -> ComputationGraph:
+        updater = AdamW(learning_rate=self.learning_rate, beta1=0.9,
+                        beta2=0.95, epsilon=1e-8,
+                        weight_decay=self.weight_decay)
+        updater.state_dtype = self.state_dtype
+        gb = (ComputationGraphConfiguration
+              .graph_builder(NeuralNetConfiguration.builder()
+                             .seed(self.seed).updater(updater))
+              .add_inputs("ids"))
+        gb.add_layer("embed", L.EmbeddingSequenceLayer(
+            n_out=self.d, weight_init="normal"), "ids")
+        norm = lambda: L.LayerNormalization(eps=self.eps)   # noqa: E731
+        prev = "embed"
+        for l in self.layers:
+            mixer, more = self._mixer(l)
+            gb.add_layer(f"l{l}_ln1", norm(), prev)
+            gb.add_layer(f"l{l}_mix", mixer, f"l{l}_ln1", *more)
+            gb.add_vertex(f"l{l}_add1", ElementWiseVertex(op="add"),
+                          prev, f"l{l}_mix")
+            gb.add_layer(f"l{l}_ln2", norm(), f"l{l}_add1")
+            gb.add_layer(f"l{l}_mlp", L.GatedMLPLayer(n_ff=self.ff),
+                         f"l{l}_ln2")
+            gb.add_vertex(f"l{l}_add2", ElementWiseVertex(op="add"),
+                          f"l{l}_add1", f"l{l}_mlp")
+            prev = f"l{l}_add2"
+        gb.add_layer("final_ln", norm(), prev)
+        gb.add_layer("head", L.TiedOutputLayer(tied_to="embed"), "final_ln")
+        conf = (gb.set_outputs("head")
+                .set_input_types(InputType.recurrent(self.vocab_rows,
+                                                     self.seq_len))
+                .build())
+        gc = conf.global_conf
+        gc.compute_dtype = self.compute_dtype
+        gc.remat_policy = self.remat_policy
         return ComputationGraph(conf).init()

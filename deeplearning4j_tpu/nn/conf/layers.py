@@ -111,6 +111,26 @@ class Layer:
     def has_params(self) -> bool:
         return True
 
+    # -- what ComputationGraph reads of a layer beyond apply() (the
+    # sequence layers of layers_seq.py use them; every default is "no") --
+    #: apply()'s ``x`` is the tuple of the node's inputs, not the first
+    multi_input = False
+    #: leaves that stay in the storage dtype under ``compute_dtype``
+    full_precision_params = ()
+
+    def extra_outputs(self) -> Tuple[str, ...]:
+        """Names of the tensors apply() returns after ``y``; another node
+        reads one as ``"<node>.<name>"``."""
+        return ()
+
+    def extra_output_types(self, input_type) -> Tuple:
+        return ()
+
+    def borrowed_params(self) -> Dict[str, Tuple[str, str]]:
+        """{own name: (node, leaf)} of leaves that belong to another
+        node and are handed to apply() beside this layer's own."""
+        return {}
+
     # -- per-timestep feature masking (reference: Layer.setMaskArray /
     # feedForwardMaskArray; SURVEY §5.7 masking row) --------------------
     def apply_masked(self, params, x, state, training, rng, fmask):
@@ -1183,3 +1203,5 @@ class LossLayer(Layer):
 
 # extended families (1D/3D convs, capsules, VAE, YOLO, constraints, ...)
 from .layers_ext import *  # noqa: E402,F401,F403
+# sequence-model layers (state-space scan, differential attention, tied head)
+from .layers_seq import *  # noqa: E402,F401,F403

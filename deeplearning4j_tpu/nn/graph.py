@@ -271,7 +271,13 @@ class ComputationGraphConfiguration:
                     and not isinstance(node.layer, L.RnnOutputLayer):
                 node.preprocessors[0] = cnn_to_ff(t)
                 t = node.preprocessors[0].out_type
-            self.node_output_types[name] = node.layer.set_input_type(t)
+            if node.layer.multi_input:
+                t = tuple([t] + in_types[1:])
+            out = node.layer.set_input_type(t)
+            self.node_output_types[name] = out
+            for extra, et in zip(node.layer.extra_outputs(),
+                                 node.layer.extra_output_types(out)):
+                self.node_output_types[f"{name}.{extra}"] = et
 
     # --- serde -----------------------------------------------------------
     def to_json(self) -> str:
@@ -375,7 +381,13 @@ class GraphBuilder:
         if not inputs:
             raise ValueError(f"node {name!r} needs at least one input")
         for i in inputs:
-            if i not in self._conf.nodes:
+            if i in self._conf.nodes:
+                continue
+            # a further output of a layer node: "<node>.<name>"
+            node, _, extra = i.rpartition(".")
+            owner = self._conf.nodes.get(node)
+            if owner is None or owner.layer is None \
+                    or extra not in owner.layer.extra_outputs():
                 raise ValueError(f"node {name!r}: unknown input {i!r} "
                                  f"(declare nodes in topological order)")
 
@@ -538,7 +550,11 @@ class ComputationGraph:
             ct = jnp.dtype(cd)
             cast = lambda a: (a.astype(ct)
                               if jnp.issubdtype(a.dtype, jnp.floating) else a)
-            params = jax.tree.map(cast, params)
+            keep = {n: self.conf.nodes[n].layer.full_precision_params
+                    for n in params}
+            params = {n: {k: (v if k in keep[n] else jax.tree.map(cast, v))
+                          for k, v in lp.items()}
+                      for n, lp in params.items()}
             inputs = {k: cast(v) for k, v in inputs.items()}
         acts: Dict[str, jnp.ndarray] = {}
         new_states = dict(states)
@@ -588,6 +604,13 @@ class ComputationGraph:
             x = ins[0]
             if 0 in node.preprocessors:
                 x = node.preprocessors[0](x)
+            if node.layer.multi_input:
+                x = (x, *ins[1:])
+            lp = params.get(name, {})
+            borrowed = node.layer.borrowed_params()
+            if borrowed:    # leaves of another node, read here too
+                lp = {**lp, **{k: params[n][leaf]
+                               for k, (n, leaf) in borrowed.items()}}
             rng, sub = jax.random.split(rng)
             if plan is not None and name in plan["bn"]:
                 # head of a fused chain: stash the raw input for the relu
@@ -596,7 +619,12 @@ class ComputationGraph:
                 continue
             if to_preout and name in out_set and isinstance(node.layer, (L.OutputLayer, L.LossLayer)):
                 x = node.layer._maybe_dropout(x, training, sub)
-                head_params = params.get(name, {})
+                head_params = lp
+                if hasattr(node.layer, "fused_score"):
+                    # the head computes its own loss from its input, in
+                    # blocks, and keeps its own precision rule
+                    acts[name] = L.HeadInput(x, head_params)
+                    continue
                 if cd:
                     # run the head matmul + downstream loss in fp32 (matches
                     # the MultiLayerNetwork mixed-precision policy)
@@ -616,8 +644,12 @@ class ComputationGraph:
                     # match on the vertex NAME here
                     run = remat_wrap(self.conf.global_conf, run,
                                      block=name)
-                y, st = run(params.get(name, {}), x,
-                            states.get(name, {}), sub)
+                y, st = run(lp, x, states.get(name, {}), sub)
+                extras = node.layer.extra_outputs()
+                if extras:
+                    y, *more = y
+                    for extra, tensor in zip(extras, more):
+                        acts[f"{name}.{extra}"] = tensor
                 acts[name] = y
                 if st:
                     new_states[name] = st
@@ -659,6 +691,11 @@ class ComputationGraph:
             if not isinstance(node.layer, (L.OutputLayer, L.LossLayer)):
                 continue
             pre = acts[out_name]
+            if isinstance(pre, L.HeadInput):
+                total = total + _fused_head_score(
+                    node.layer, pre, labels[out_name],
+                    masks.get(out_name) if masks else None, w, w_denom)
+                continue
             # under reduced-precision compute, reduce the loss in fp32; leave
             # fp64 runs (gradient checks) untouched
             if self.conf.global_conf.compute_dtype and \
@@ -972,6 +1009,23 @@ class ComputationGraph:
     def _check_init(self):
         if not self._initialized:
             raise ValueError("call init() first")
+
+
+def _fused_head_score(layer, head, labels, mask, w, w_denom):
+    """The score of a head that computes its loss from its own input
+    (``TiedOutputLayer``): each sequence's mean over its (unmasked)
+    positions, then the mean over sequences, or their ``w``-weighted mean as
+    every other head under shape-stable batching."""
+    per_token = (jnp.ones(labels.shape, jnp.float32) if mask is None
+                 else mask.astype(jnp.float32))
+    per_token = per_token / jnp.maximum(
+        jnp.sum(per_token, axis=-1, keepdims=True), 1.0)
+    if w is None:
+        denom = labels.shape[0]
+    else:
+        per_token = per_token * w.astype(jnp.float32)[:, None]
+        denom = w_denom if w_denom is not None else jnp.maximum(jnp.sum(w), 1.0)
+    return layer.fused_score(head.params, head.x, labels, per_token) / denom
 
 
 def _chunk_stackable(group) -> bool:

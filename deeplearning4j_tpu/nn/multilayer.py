@@ -958,7 +958,7 @@ def _weak_scalar(v):
     try:
         from jax._src.lax.lax import _convert_element_type
 
-        return _convert_element_type(v, jnp.float64, weak_type=True)
+        return _convert_element_type(v, jnp.dtype(jnp.float64), weak_type=True)
     except (ImportError, TypeError):    # pragma: no cover - jax internals
         return v
 

@@ -1,23 +1,28 @@
-"""Flash attention: a hand-written Pallas TPU kernel for the hot op.
+"""Flash attention: hand-written Pallas TPU kernels for the hot op.
 
 Reference: the reference's attention is a dense libnd4j kernel
 (``generic/nn/multi_head_dot_product_attention.cpp``) materializing the
 full [T, T] score matrix. On TPU the memory-bound way to run long-sequence
 attention is the blockwise online-softmax construction (Flash Attention /
-Rabe-Staats), tiled for VMEM with Pallas/Mosaic — this module implements
-it natively (forward kernel + memory-efficient blockwise backward), the
-"pallas kernels for the hot ops" role in this framework's layer map.
+Rabe-Staats), tiled for VMEM with Pallas/Mosaic. Two kernels, one for each
+shape of the mask:
 
-Shapes: q, k, v ``[B, H, T, D]``. The kernel grid is (B·H, T/block_q);
-each program holds one q block in VMEM and streams k/v blocks with an
-online max/denominator, so nothing of size T×T ever materializes. The
-backward pass is the standard FA recipe (recompute p per block from the
-saved row max/denominator) expressed as an XLA ``lax.scan`` over k blocks
-— also free of T×T buffers.
+- ``flash_attention`` (``flash_attention_dense_fwd``): every key block of the
+  square, with an optional additive bias (a padding mask, a relative-position
+  bias) streamed tile by tile. q, k, v ``[B, H, T, D]``, float32 in the
+  kernel. Grid (B·H, T/block_q, T/block_k); the backward is the standard FA
+  recipe (recompute p per block from the row max/denominator) as an XLA
+  ``lax.scan`` over key blocks. A causal mask with a bias rides in the bias;
+  a causal mask without one is ``causal_attention``;
+- ``causal_attention`` (``flash_attention_fwd``): causal, optionally banded
+  by a window, grouped-query heads, a value wider than the head, operands in
+  the inputs' dtype with float32 accumulation. Only the key blocks inside the
+  band are fetched or computed, forward and backward.
 
-``interpret=True`` runs the kernel in Pallas interpret mode (used by the
-CPU test mesh); on the TPU the same kernel lowers through Mosaic
-(``chip_smoke.py`` runs it there). Sequence lengths must divide the block
+Nothing of size T×T ever materializes in either. ``interpret=True`` runs a
+kernel in Pallas interpret mode (the CPU tests); on the TPU the same kernel
+lowers through Mosaic (``tests/test_tpu_compile*.py`` compile them for the
+chip). ``flash_attention`` needs sequence lengths that divide the block
 sizes — callers fall back to the dense op otherwise
 (``ops/nn.dot_product_attention``).
 """
@@ -34,6 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from ..common.profiler import OpProfiler
 from .registry import op
 
 # Chosen at T=4096 on a v5e set-up that is gone (r05-era, 2026-07); not
@@ -42,8 +48,7 @@ DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 1024
 
 
-def _fa_kernel(*refs, scale: float, causal: bool, block_q: int,
-               block_k: int, n_k: int, has_bias: bool = False):
+def _fa_kernel(*refs, scale: float, n_k: int, has_bias: bool = False):
     # NOTE (Mosaic, this jax version — pinned empirically on the real
     # chip): the kernel must trace in the 32-bit world. This framework
     # enables jax_enable_x64 globally (NDArray fp64 parity), under which
@@ -62,7 +67,6 @@ def _fa_kernel(*refs, scale: float, causal: bool, block_q: int,
     else:
         q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
         b_ref = None
-    qi = pl.program_id(1)
     kj = pl.program_id(2)
 
     @pl.when(kj == jnp.int32(0))
@@ -71,48 +75,31 @@ def _fa_kernel(*refs, scale: float, causal: bool, block_q: int,
         l_scr[...] = jnp.zeros_like(l_scr[...])
         acc_scr[...] = jnp.zeros_like(acc_scr[...])
 
-    def _compute():
-        q = q_ref[0] * scale                              # [bq, d]
-        k = k_ref[0]                                      # [bk, d]
-        v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
-        if has_bias:
-            # additive logits bias (BERT attention mask / relative-pos
-            # bias), streamed block-by-block like k/v — the [T, T] bias
-            # never resides whole in VMEM
-            s = s + b_ref[0]
-        if causal:
-            qpos = (qi * jnp.int32(block_q)
-                    + lax.broadcasted_iota(jnp.int32,
-                                           (block_q, block_k), 0))
-            kpos = (kj * jnp.int32(block_k)
-                    + lax.broadcasted_iota(jnp.int32,
-                                           (block_q, block_k), 1))
-            s = jnp.where(qpos >= kpos, s, jnp.float32(-jnp.inf))
-        m_prev = jnp.max(m_scr[...], axis=1, keepdims=True)   # [bq, 1]
-        l_prev = jnp.max(l_scr[...], axis=1, keepdims=True)
-        m_blk = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_blk)
-        f0 = jnp.float32(0.0)
-        safe = jnp.where(jnp.isfinite(m_new), m_new, f0)
-        p = jnp.exp(s - safe)
-        p = jnp.where(jnp.isfinite(s), p, f0)
-        alpha = jnp.where(jnp.isfinite(m_prev),
-                          jnp.exp(m_prev - safe), f0)        # [bq, 1]
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        ones = jnp.ones((1, m_scr.shape[1]), jnp.float32)
-        m_scr[...] = m_new * ones
-        l_scr[...] = l_new * ones
-
-    if causal:
-        # whole k block above the diagonal → nothing to do
-        pl.when(kj * jnp.int32(block_k)
-                <= qi * jnp.int32(block_q)
-                + jnp.int32(block_q - 1))(_compute)
-    else:
-        _compute()
+    q = q_ref[0] * scale                              # [bq, d]
+    k = k_ref[0]                                      # [bk, d]
+    v = v_ref[0]
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
+    if has_bias:
+        # additive logits bias (BERT attention mask / relative-pos bias, a
+        # causal mask as -inf), streamed block-by-block like k/v — the
+        # [T, T] bias never resides whole in VMEM
+        s = s + b_ref[0]
+    m_prev = jnp.max(m_scr[...], axis=1, keepdims=True)   # [bq, 1]
+    l_prev = jnp.max(l_scr[...], axis=1, keepdims=True)
+    m_blk = jnp.max(s, axis=1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_blk)
+    f0 = jnp.float32(0.0)
+    safe = jnp.where(jnp.isfinite(m_new), m_new, f0)
+    p = jnp.exp(s - safe)
+    p = jnp.where(jnp.isfinite(s), p, f0)
+    alpha = jnp.where(jnp.isfinite(m_prev),
+                      jnp.exp(m_prev - safe), f0)        # [bq, 1]
+    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())))
+    ones = jnp.ones((1, m_scr.shape[1]), jnp.float32)
+    m_scr[...] = m_new * ones
+    l_scr[...] = l_new * ones
 
     @pl.when(kj == jnp.int32(n_k - 1))
     def _finalize():
@@ -120,15 +107,13 @@ def _fa_kernel(*refs, scale: float, causal: bool, block_q: int,
         o_ref[0] = acc_scr[...] / jnp.maximum(l, jnp.float32(1e-30))
 
 
-def _fa_forward(q, k, v, scale, causal, block_q, block_k, interpret,
-                bias=None):
+def _fa_forward(q, k, v, scale, block_q, block_k, interpret, bias=None):
     from jax.experimental.pallas import tpu as pltpu
 
     bh, T, d = q.shape
     n_q = T // block_q
     n_k = T // block_k
-    kernel = functools.partial(_fa_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k, n_k=n_k,
+    kernel = functools.partial(_fa_kernel, scale=scale, n_k=n_k,
                                has_bias=bias is not None)
     scratch = [
         pltpu.VMEM((block_q, 128), jnp.float32),   # running row max
@@ -155,17 +140,17 @@ def _fa_forward(q, k, v, scale, causal, block_q, block_k, interpret,
             out_shape=jax.ShapeDtypeStruct((bh, T, d), q.dtype),
             scratch_shapes=scratch,
             interpret=interpret,
+            name="flash_attention_dense_fwd",
         )(*args)
     return o
 
 
-def _row_stats(q, k, scale, causal, block_k, bias=None):
+def _row_stats(q, k, scale, block_k, bias=None):
     """Blockwise recomputation of the softmax row max/denominator
     (the stats the kernel keeps in registers), as an XLA scan."""
     bh, T, d = q.shape
     n_k = T // block_k
     qf = q.astype(jnp.float32)
-    qpos = jnp.arange(T)
 
     def blk(carry, i):
         m, l = carry
@@ -175,9 +160,6 @@ def _row_stats(q, k, scale, causal, block_k, bias=None):
         if bias is not None:
             s = s + lax.dynamic_slice_in_dim(bias, i * block_k, block_k,
                                              2).astype(jnp.float32)
-        if causal:
-            kpos = i * block_k + jnp.arange(block_k)
-            s = jnp.where(qpos[:, None] >= kpos[None, :], s, -jnp.inf)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
         p = jnp.where(jnp.isfinite(s), jnp.exp(s - safe[..., None]), 0.0)
@@ -190,7 +172,7 @@ def _row_stats(q, k, scale, causal, block_k, bias=None):
     return jnp.where(jnp.isfinite(m), m, 0.0), l
 
 
-def _fa_backward(q, k, v, o, do, scale, causal, block_k, bias=None,
+def _fa_backward(q, k, v, o, do, scale, block_k, bias=None,
                  need_dbias=False):
     """Blockwise FA backward (XLA scan over k blocks, no T×T buffers).
 
@@ -203,12 +185,11 @@ def _fa_backward(q, k, v, o, do, scale, causal, block_k, bias=None,
     through the broadcast's own VJP).
     """
     bh, T, d = q.shape
-    m, l = _row_stats(q, k, scale, causal, block_k, bias=bias)
+    m, l = _row_stats(q, k, scale, block_k, bias=bias)
     n_k = T // block_k
     qf = q.astype(jnp.float32)
     dof = do.astype(jnp.float32)
     D = jnp.sum(dof * o.astype(jnp.float32), axis=-1)       # [bh, T]
-    qpos = jnp.arange(T)
 
     def blk(carry, i):
         dq_acc = carry
@@ -220,9 +201,6 @@ def _fa_backward(q, k, v, o, do, scale, causal, block_k, bias=None,
         if bias is not None:
             s = s + lax.dynamic_slice_in_dim(bias, i * block_k, block_k,
                                              2).astype(jnp.float32)
-        if causal:
-            kpos = i * block_k + jnp.arange(block_k)
-            s = jnp.where(qpos[:, None] >= kpos[None, :], s, -jnp.inf)
         p = jnp.exp(s - m[..., None])
         p = jnp.where(jnp.isfinite(s), p, 0.0) \
             / jnp.maximum(l, 1e-30)[..., None]               # [bh, T, bk]
@@ -250,41 +228,39 @@ def _fa_backward(q, k, v, o, do, scale, causal, block_k, bias=None,
     return grads
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash3(q, k, v, scale, causal, block_q, block_k, interpret):
-    return _fa_forward(q, k, v, scale, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash3(q, k, v, scale, block_q, block_k, interpret):
+    return _fa_forward(q, k, v, scale, block_q, block_k, interpret)
 
 
-def _flash3_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
-    o = _fa_forward(q, k, v, scale, causal, block_q, block_k, interpret)
+def _flash3_fwd(q, k, v, scale, block_q, block_k, interpret):
+    o = _fa_forward(q, k, v, scale, block_q, block_k, interpret)
     return o, (q, k, v, o)
 
 
-def _flash3_bwd(scale, causal, block_q, block_k, interpret, res, do):
+def _flash3_bwd(scale, block_q, block_k, interpret, res, do):
     q, k, v, o = res
-    return _fa_backward(q, k, v, o, do, scale, causal, block_k)
+    return _fa_backward(q, k, v, o, do, scale, block_k)
 
 
 _flash3.defvjp(_flash3_fwd, _flash3_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash3b(q, k, v, bias, scale, causal, block_q, block_k, interpret):
-    return _fa_forward(q, k, v, scale, causal, block_q, block_k,
-                       interpret, bias=bias)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash3b(q, k, v, bias, scale, block_q, block_k, interpret):
+    return _fa_forward(q, k, v, scale, block_q, block_k, interpret,
+                       bias=bias)
 
 
-def _flash3b_fwd(q, k, v, bias, scale, causal, block_q, block_k,
-                 interpret):
-    o = _fa_forward(q, k, v, scale, causal, block_q, block_k, interpret,
-                    bias=bias)
+def _flash3b_fwd(q, k, v, bias, scale, block_q, block_k, interpret):
+    o = _fa_forward(q, k, v, scale, block_q, block_k, interpret, bias=bias)
     return o, (q, k, v, bias, o)
 
 
-def _flash3b_bwd(scale, causal, block_q, block_k, interpret, res, do):
+def _flash3b_bwd(scale, block_q, block_k, interpret, res, do):
     q, k, v, bias, o = res
-    return _fa_backward(q, k, v, o, do, scale, causal, block_k,
-                        bias=bias, need_dbias=True)
+    return _fa_backward(q, k, v, o, do, scale, block_k, bias=bias,
+                        need_dbias=True)
 
 
 _flash3b.defvjp(_flash3b_fwd, _flash3b_bwd)
@@ -322,7 +298,12 @@ def flash_attention(q, k, v, causal: bool = False,
     ``where(mask, 0, -1e9)``, or a learned relative-position bias: it is
     differentiated, with the cotangent summed back through the broadcast).
     The bias streams through VMEM one [block_q, block_k] tile at a time,
-    same as k/v — no [T, T] residency."""
+    same as k/v — no [T, T] residency.
+
+    ``causal`` without a bias is ``causal_attention`` (one block size,
+    ``block_q``; the key blocks above the diagonal are neither fetched nor
+    computed, forward or backward); with a bias the mask joins the bias and
+    every block is computed."""
     squeeze = q.ndim == 3
     if squeeze:
         q, k, v = q[:, None], k[:, None], v[:, None]
@@ -332,6 +313,12 @@ def flash_attention(q, k, v, causal: bool = False,
         raise ValueError(
             f"flash_attention needs T % block == 0 (T={T}, blocks "
             f"{block_q}/{block_k}); fall back to dot_product_attention")
+    if causal and bias is None:
+        # False asks for the compiled kernel here and for the XLA loops
+        # there: None lets the backend decide
+        o = causal_attention(q, k, v, sm_scale=sm_scale, block=block_q,
+                             interpret=interpret or None)
+        return o[:, 0] if squeeze else o
     if interpret is None:
         interpret = jax.default_backend() not in ("tpu",)
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(d)
@@ -342,14 +329,293 @@ def flash_attention(q, k, v, causal: bool = False,
     if bias is not None:
         if squeeze and bias.ndim == 3:
             bias = bias[:, None]
+        if causal:
+            bias = bias + jnp.where(jnp.tril(jnp.ones((T, T), bool)),
+                                    jnp.float32(0.0), -jnp.inf)
         # broadcast OUTSIDE the custom_vjp: dbias sums back to the
         # caller's small shape through the broadcast's own VJP
         bf = jnp.broadcast_to(bias.astype(jnp.float32),
                               (b, h, T, T)).reshape(b * h, T, T)
-        o = _flash3b(qf, kf, vf, bf, float(scale), bool(causal),
-                     int(block_q), int(block_k), bool(interpret))
+        o = _flash3b(qf, kf, vf, bf, float(scale), int(block_q),
+                     int(block_k), bool(interpret))
     else:
-        o = _flash3(qf, kf, vf, float(scale), bool(causal), int(block_q),
-                    int(block_k), bool(interpret))
+        o = _flash3(qf, kf, vf, float(scale), int(block_q), int(block_k),
+                    bool(interpret))
     o = o.reshape(b, h, T, d).astype(in_dtype)
     return o[:, 0] if squeeze else o
+
+
+# --- causal attention over a band of key blocks ------------------------------
+#
+# Grouped-query heads, an optional sliding window, operands in the inputs'
+# dtype with float32 accumulation. Query block i meets key blocks
+# lo(i)..i only (lo = 0 without a window), forward and backward: what the
+# mask empties is neither fetched nor computed, and no temporary is larger
+# than one [heads, block, block] tile. The forward is a Pallas kernel on
+# the TPU (``flash_attention_fwd``, which also hands back each row's
+# log-sum-exp) or the XLA loop below; the backward is the XLA loop both
+# times, from the saved log-sum-exp.
+
+BAND_BLOCK = 512
+_MASKED = -1e30     # finite: a row whose first block is all masked stays finite
+
+
+def _band_lo(i, bs: int, window: Optional[int]):
+    """First key block that query block ``i`` can see."""
+    if not window:
+        return i * 0
+    return jnp.maximum(i * bs - (window - 1), 0) // bs
+
+
+def _band_width(n: int, bs: int, window: Optional[int]) -> int:
+    """Most key blocks any query block sees."""
+    if not window:
+        return n
+    return max(i - max(i * bs - (window - 1), 0) // bs + 1 for i in range(n))
+
+
+def band_blocks(T: int, bs: int, window: Optional[int]) -> tuple:
+    """(block pairs computed, block pairs of the square left out)."""
+    n = T // bs
+    run = sum(i - (max(i * bs - (window - 1), 0) // bs if window else 0) + 1
+              for i in range(n))
+    return run, n * n - run
+
+
+def _band_mask(i, j, bs: int, window: Optional[int]):
+    qpos = i * bs + lax.broadcasted_iota(jnp.int32, (bs, bs), 0)
+    kpos = j * bs + lax.broadcasted_iota(jnp.int32, (bs, bs), 1)
+    ok = kpos <= qpos
+    if window:
+        ok = ok & (qpos - kpos < window)
+    return ok
+
+
+def _blk(a, i, bs, axis):
+    return lax.dynamic_slice_in_dim(a, i * bs, bs, axis)
+
+
+def _band_fwd_xla(q, k, v, scale, window, bs):
+    """q [B, Hk, G, T, D]; k [B, Hk, T, D]; v [B, Hk, T, Dv] ->
+    (o [B, Hk, G, T, Dv] float32, lse [B, Hk, G, T])."""
+    f32 = jnp.float32
+    b, hk, g, T, d = q.shape
+    n = T // bs
+
+    def q_block(i):
+        qi = _blk(q, i, bs, 3)
+
+        def k_block(j, carry):
+            m, l, acc = carry
+            s = jnp.einsum("bhgqd,bhkd->bhgqk", qi, _blk(k, j, bs, 2),
+                           preferred_element_type=f32) * scale
+            s = jnp.where(_band_mask(i, j, bs, window), s, _MASKED)
+            m_new = jnp.maximum(m, s.max(-1))
+            p = jnp.exp(s - m_new[..., None])
+            alpha = jnp.exp(m - m_new)
+            vj = _blk(v, j, bs, 2)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "bhgqk,bhkd->bhgqd", p.astype(vj.dtype), vj,
+                preferred_element_type=f32)
+            return m_new, l * alpha + p.sum(-1), acc
+
+        m, l, acc = lax.fori_loop(
+            _band_lo(i, bs, window), i + 1, k_block,
+            (jnp.full((b, hk, g, bs), _MASKED, f32),
+             jnp.zeros((b, hk, g, bs), f32),
+             jnp.zeros((b, hk, g, bs, v.shape[-1]), f32)))
+        return acc / l[..., None], m + jnp.log(l)
+
+    o, lse = lax.map(q_block, jnp.arange(n, dtype=jnp.int32))
+    o = jnp.moveaxis(o, 0, 3).reshape(b, hk, g, T, v.shape[-1])
+    lse = jnp.moveaxis(lse, 0, 3).reshape(b, hk, g, T)
+    return o, lse
+
+
+def _band_bwd_xla(q, k, v, o, lse, do, scale, window, bs):
+    """The backward over the same band: one pass over (query block, key
+    block) pairs; dk and dv are accumulated in place, block by block."""
+    f32 = jnp.float32
+    b, hk, g, T, d = q.shape
+    n = T // bs
+    delta = jnp.sum(do.astype(f32) * o.astype(f32), axis=-1)   # [B,Hk,G,T]
+
+    def q_block(carry, i):
+        qi, doi = _blk(q, i, bs, 3), _blk(do, i, bs, 3)
+        lse_i, delta_i = _blk(lse, i, bs, 3), _blk(delta, i, bs, 3)
+
+        def k_block(j, carry):
+            dq, dk, dv = carry
+            kj, vj = _blk(k, j, bs, 2), _blk(v, j, bs, 2)
+            s = jnp.einsum("bhgqd,bhkd->bhgqk", qi, kj,
+                           preferred_element_type=f32) * scale
+            s = jnp.where(_band_mask(i, j, bs, window), s, _MASKED)
+            p = jnp.exp(s - lse_i[..., None])
+            dp = jnp.einsum("bhgqd,bhkd->bhgqk", doi, vj,
+                            preferred_element_type=f32)
+            ds = (p * (dp - delta_i[..., None]) * scale).astype(q.dtype)
+            dq = dq + jnp.einsum("bhgqk,bhkd->bhgqd", ds, kj,
+                                 preferred_element_type=f32)
+            dk_j = jnp.einsum("bhgqk,bhgqd->bhkd", ds, qi,
+                              preferred_element_type=f32)
+            dv_j = jnp.einsum("bhgqk,bhgqd->bhkd", p.astype(do.dtype), doi,
+                              preferred_element_type=f32)
+            dk = lax.dynamic_update_slice_in_dim(
+                dk, _blk(dk, j, bs, 2) + dk_j, j * bs, 2)
+            dv = lax.dynamic_update_slice_in_dim(
+                dv, _blk(dv, j, bs, 2) + dv_j, j * bs, 2)
+            return dq, dk, dv
+
+        dq, dk, dv = lax.fori_loop(
+            _band_lo(i, bs, window), i + 1, k_block,
+            (jnp.zeros((b, hk, g, bs, d), f32),) + carry)
+        return (dk, dv), dq
+
+    (dk, dv), dq = lax.scan(
+        q_block, (jnp.zeros(k.shape, f32), jnp.zeros(v.shape, f32)),
+        jnp.arange(n, dtype=jnp.int32))
+    dq = jnp.moveaxis(dq, 0, 3).reshape(q.shape)
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+def _band_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+                 *, scale: float, bs: int, nb: int, window: Optional[int]):
+    # traced in the 32-bit world, like _fa_kernel above
+    i = pl.program_id(1)
+    jj = pl.program_id(2)
+    kb = _band_lo(i, bs, window) + jj
+
+    @pl.when(jj == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _MASKED)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(kb <= i)
+    def _compute():
+        v = v_ref[0]
+        s = lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(_band_mask(i, kb, bs, window), s, _MASKED)
+        m_prev = jnp.max(m_scr[...], axis=1, keepdims=True)      # [bs, 1]
+        l_prev = jnp.max(l_scr[...], axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        acc_scr[...] = acc_scr[...] * alpha + lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ones = jnp.ones((1, m_scr.shape[1]), jnp.float32)
+        m_scr[...] = m_new * ones
+        l_scr[...] = (l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)) * ones
+
+    @pl.when(jj == nb - 1)
+    def _finalize():
+        l = jnp.max(l_scr[...], axis=1, keepdims=True)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[0] = m_scr[...] + jnp.log(l_scr[...])
+
+
+def _band_fwd_pallas(q, k, v, scale, window, bs, interpret):
+    """Same contract as ``_band_fwd_xla``; the group's heads index their
+    key/value head in the block maps, nothing is repeated."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, hk, g, T, d = q.shape
+    dv = v.shape[-1]
+    n = T // bs
+    nb = _band_width(n, bs, window)
+    q3 = q.reshape(b * hk * g, T, d)
+    k3, v3 = k.reshape(b * hk, T, d), v.reshape(b * hk, T, dv)
+
+    def kv_map(h, i, j):
+        return (h // g, jnp.minimum(_band_lo(i, bs, window) + j, i), 0)
+
+    with jax.enable_x64(False):
+        o, lse = pl.pallas_call(
+            functools.partial(_band_kernel, scale=scale, bs=bs, nb=nb,
+                              window=window),
+            grid=(b * hk * g, n, nb),
+            in_specs=[pl.BlockSpec((1, bs, d), lambda h, i, j: (h, i, 0)),
+                      pl.BlockSpec((1, bs, d), kv_map),
+                      pl.BlockSpec((1, bs, dv), kv_map)],
+            out_specs=[pl.BlockSpec((1, bs, dv), lambda h, i, j: (h, i, 0)),
+                       pl.BlockSpec((1, bs, 128), lambda h, i, j: (h, i, 0))],
+            out_shape=[jax.ShapeDtypeStruct((b * hk * g, T, dv), q.dtype),
+                       jax.ShapeDtypeStruct((b * hk * g, T, 128),
+                                            jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((bs, 128), jnp.float32),
+                            pltpu.VMEM((bs, 128), jnp.float32),
+                            pltpu.VMEM((bs, dv), jnp.float32)],
+            interpret=interpret, name="flash_attention_fwd",
+        )(q3, k3, v3)
+    return (o.reshape(b, hk, g, T, dv), lse[..., 0].reshape(b, hk, g, T))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _band(q, k, v, scale, window, bs, kernel, interpret):
+    return _band_fwd(q, k, v, scale, window, bs, kernel, interpret)[0]
+
+
+def _band_fwd(q, k, v, scale, window, bs, kernel, interpret):
+    if kernel:
+        o, lse = _band_fwd_pallas(q, k, v, scale, window, bs, interpret)
+    else:
+        o, lse = _band_fwd_xla(q, k, v, scale, window, bs)
+        o = o.astype(q.dtype)
+    return o, (q, k, v, o, lse)
+
+
+def _band_bwd(scale, window, bs, kernel, interpret, res, do):
+    return _band_bwd_xla(*res, do, scale, window, bs)
+
+
+_band.defvjp(_band_fwd, _band_bwd)
+
+
+def supports_band_kernel(T: int, d: int, dv: int, bs: int) -> bool:
+    # the [bs, bs] score tile's lane dim % 128; head and value widths as
+    # compiled for the chip (tests/test_tpu_compile_seq.py)
+    return (T % bs == 0 and bs % 128 == 0 and d % 32 == 0 and dv % 32 == 0)
+
+
+@op("causal_attention", "nn")
+def causal_attention(q, k, v, window: Optional[int] = None,
+                     sm_scale: Optional[float] = None,
+                     block: Optional[int] = None,
+                     interpret: Optional[bool] = None):
+    """Causal softmax attention with grouped-query heads: q ``[B, Hq, T,
+    D]``, k ``[B, Hk, T, D]``, v ``[B, Hk, T, Dv]`` with ``Hq`` a multiple of
+    ``Hk`` (query head ``h`` reads key/value head ``h // (Hq/Hk)``); returns
+    ``[B, Hq, T, Dv]`` in q's dtype. ``window``: query i sees keys j with
+    ``i - window < j <= i``; None sees every ``j <= i``. Products take the
+    operands as they come (bfloat16 stays bfloat16) and accumulate in
+    float32. ``block``: query and key block (``BAND_BLOCK``, shrunk to T); a
+    ``T`` it does not divide is padded at the end, where causality hides the
+    padding. ``interpret`` as in ``ops.ssm.selective_scan``."""
+    from ..common.environment import Environment
+
+    b, hq, T, d = q.shape
+    hk, dv = k.shape[1], v.shape[-1]
+    g = hq // hk
+    bs = min(int(block or BAND_BLOCK), T)
+    pad = -T % bs
+    if pad:
+        q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                   for a in (q, k, v))
+    Tp = T + pad
+    fits = supports_band_kernel(Tp, d, dv, bs)
+    if interpret is None:
+        kernel = (Environment.get().allow_pallas()
+                  and jax.default_backend() == "tpu" and fits)
+    else:
+        kernel = bool(interpret) and fits
+    prof = OpProfiler.get()
+    prof.count("seq/attn_kernel" if kernel else "seq/attn_fallback")
+    run, skipped = band_blocks(Tp, bs, window)
+    prof.count("seq/attn_key_blocks_run", run * b * hq)
+    prof.count("seq/attn_key_blocks_skipped", skipped * b * hq)
+    scale = float(sm_scale if sm_scale is not None else 1.0 / np.sqrt(d))
+    o = _band(q.reshape(b, hk, g, Tp, d), k, v, scale,
+              int(window) if window else None, bs, kernel, bool(interpret))
+    return o.reshape(b, hq, Tp, dv)[:, :, :T]

@@ -119,6 +119,7 @@ def _ensure_loaded() -> None:
         loss,
         image,
         pallas_attention,
+        ssm,
         bitwise,
         embeddings,
     )

@@ -1288,6 +1288,43 @@ class TestPallasOps:
                                    atol=2e-5)
 
 
+    def test_causal_attention_matches_dense(self):
+        """Grouped heads and a window, XLA loops and the Pallas forward
+        (interpret mode) vs the dense masked softmax; the layers' cases are
+        in tests/test_seq_layers.py."""
+        rng = np.random.RandomState(6)
+        q = rng.randn(1, 4, 256, 64).astype(np.float32) * 0.4
+        k = rng.randn(1, 2, 256, 64).astype(np.float32) * 0.4
+        v = rng.randn(1, 2, 256, 128).astype(np.float32) * 0.4
+        i, j = np.arange(256)[:, None], np.arange(256)[None, :]
+        ok = (j <= i) & (i - j < 100)
+        s = np.einsum("bhqd,bhkd->bhqk", q, np.repeat(k, 2, 1)) / 8.0
+        s = np.where(ok, s, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        ref = np.einsum("bhqk,bhkd->bhqd", p / p.sum(-1, keepdims=True),
+                        np.repeat(v, 2, 1))
+        for interpret in (None, True):
+            got = exec_op("causal_attention", q, k, v, window=100, block=128,
+                          interpret=interpret)
+            np.testing.assert_allclose(np.asarray(got), ref, atol=2e-5)
+
+    def test_selective_scan_matches_single_steps(self):
+        rng = np.random.RandomState(7)
+        u = rng.randn(1, 20, 128).astype(np.float32)
+        dt = np.log1p(np.exp(rng.randn(1, 20, 128).astype(np.float32) - 2))
+        A = -np.exp(rng.randn(128, 4).astype(np.float32))
+        Bm = rng.randn(1, 20, 4).astype(np.float32)
+        Cm = rng.randn(1, 20, 4).astype(np.float32)
+        h, ref = np.zeros((128, 4), np.float64), []
+        for t in range(20):
+            h = np.exp(dt[0, t, :, None] * A) * h \
+                + (dt[0, t] * u[0, t])[:, None] * Bm[0, t][None, :]
+            ref.append(h @ Cm[0, t])
+        got = exec_op("selective_scan", u, dt, A, Bm, Cm, chunk=8)
+        np.testing.assert_allclose(np.asarray(got)[0], np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+
+
 class TestCoverageLedger:
     """The reference's coverage-ledger gate: every registered op must be
     exercised by this suite or explicitly listed as pending with a reason."""

@@ -50,6 +50,17 @@ class TestFlashForward:
         ref = np.asarray(_dense(q, k, v, causal=True))
         np.testing.assert_allclose(got, ref, atol=2e-5)
 
+    def test_causal_is_the_band_kernel(self):
+        """No second causal kernel: the call is ``causal_attention``'s, and
+        counted as one."""
+        from deeplearning4j_tpu.common.profiler import OpProfiler
+
+        prof = OpProfiler.get()
+        before = prof.counter_value("seq/attn_kernel")
+        q, k, v = _qkv(b=1, h=2, t=256)
+        flash_attention(q, k, v, causal=True, block_q=128, interpret=True)
+        assert prof.counter_value("seq/attn_kernel") == before + 1
+
     def test_multiple_k_blocks(self):
         q, k, v = _qkv(b=1, h=1, t=512, d=32)
         got = np.asarray(flash_attention(q, k, v, block_q=128, block_k=128,
@@ -87,6 +98,32 @@ class TestFlashBackward:
         gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
         gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
         for a, b, name in zip(gf, gd, "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=3e-5, err_msg=f"d{name}")
+
+    def test_causal_with_bias_matches_dense(self):
+        """The mask joins the bias; the bias is differentiated."""
+        q, k, v = _qkv(b=1, h=2, t=256, d=32)
+        bias = rng.randn(1, 2, 256, 256).astype(np.float32) * 0.5
+        tril = np.tril(np.ones((256, 256), bool))
+
+        def dense(q, k, v, bias):
+            s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(32.0) + bias
+            w = jax.nn.softmax(jnp.where(tril, s, -jnp.inf), axis=-1)
+            return jnp.einsum("bhqk,bhkd->bhqd", w, v)
+
+        def flash(q, k, v, bias):
+            return flash_attention(q, k, v, causal=True, bias=bias,
+                                   block_q=128, block_k=128, interpret=True)
+
+        np.testing.assert_allclose(np.asarray(flash(q, k, v, bias)),
+                                   np.asarray(dense(q, k, v, bias)),
+                                   atol=2e-5)
+        tgt = rng.randn(1, 2, 256, 32).astype(np.float32)
+        g = lambda f: jax.grad(                              # noqa: E731
+            lambda *a: jnp.mean((f(*a) - tgt) ** 2), (0, 1, 2, 3))(q, k, v,
+                                                                   bias)
+        for a, b, name in zip(g(flash), g(dense), ["q", "k", "v", "bias"]):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=3e-5, err_msg=f"d{name}")
 

@@ -1,0 +1,463 @@
+"""The sequence layers (``nn/conf/layers_seq.py``), their ops (``ops/ssm.py``,
+``ops/pallas_attention.causal_attention``) and ``models.Phi4MiniFlash``
+against the plain reference of ``benchmarks/configs/phi4_mini_flash.py``,
+which imports nothing of the package.
+
+Tolerances. float32 against float32 at ``highest``: both sides compute the
+same sums in another order (chunked scan against single steps, online
+softmax against one softmax, one fused call against two), so they differ by
+rounding alone: 1e-5 of the largest magnitude of what is compared, which is
+about a hundred float32 ulps (2e-5 for the layers' gradients: the reason is at
+the line). The three-step ``fit`` comparison uses the
+benchmark's own gaps (``benchmarks/compare.py``): 1e-4 in float32 (three
+Adam steps amplify a rounding of the gradient where ``v`` is tiny), and in
+bfloat16 limits that a float8 cast of the reference's operands fails.
+"""
+
+import importlib.util
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu.common.profiler import OpProfiler
+from deeplearning4j_tpu.data import DataSet
+from deeplearning4j_tpu.models import Phi4MiniFlash
+from deeplearning4j_tpu.nn.conf import layers as L
+from deeplearning4j_tpu.nn.conf.inputs import RNNInput
+from deeplearning4j_tpu.ops.pallas_attention import causal_attention
+from deeplearning4j_tpu.ops.ssm import selective_scan
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks")
+for p in (BENCH, os.path.join(BENCH, "tests")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+import compare  # noqa: E402  (benchmarks/compare.py)
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+CONF = _load(os.path.join(BENCH, "configs", "phi4_mini_flash.py"),
+             "bench_conf_phi4")
+CFG = __import__("json").load(
+    open(os.path.join(BENCH, "configs", "phi4_mini_flash.json")))
+SIZES = CONF.sizes_of(CFG, True)     # d=64, heads 4/2 of 16, d_inner 128, ...
+REF = CONF.ref_ops(SIZES, compare.EXACT)
+D, T, B = SIZES["hidden_size"], 32, 2
+F32 = jnp.float32
+
+
+def _close(a, b, tol=1e-5):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    assert a.shape == b.shape
+    assert np.max(np.abs(a - b)) <= tol * max(np.max(np.abs(b)), 1e-30), \
+        np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30)
+
+
+def _tree_close(a, b, tol=1e-5):
+    """Leaf by leaf. A leaf that is analytically zero (a key's bias under
+    softmax: under 1e-5 of the largest leaf) reads the rounding of terms that
+    cancel, whose scale is the other leaves': it is held to theirs."""
+    assert jax.tree.structure(a) == jax.tree.structure(b)
+    top = max(float(jnp.max(jnp.abs(y))) for y in jax.tree.leaves(b))
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        if float(jnp.max(jnp.abs(y))) < 1e-5 * top:
+            assert float(jnp.max(jnp.abs(x - y))) <= tol * top
+        else:
+            _close(x, y, tol)
+
+
+def _layer(layer, *in_sizes):
+    """The layer with its input types set and parameters drawn wide enough
+    (std 0.3) that every term of its output matters."""
+    types = [RNNInput(s, T) for s in in_sizes]
+    layer.set_input_type(types[0] if len(types) == 1 else tuple(types))
+    params = layer.init_params(jax.random.PRNGKey(3))
+    return layer, jax.tree.map(
+        lambda a: a * 15.0 if a.ndim == 2 and a.shape[0] > 8 else a, params)
+
+
+def _inputs(*sizes, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), len(sizes))
+    return [jax.random.normal(k, (B, T, s), F32) for k, s in zip(ks, sizes)]
+
+
+def _mamba(emit=False):
+    return _layer(L.MambaLayer(d_inner=SIZES["d_inner"],
+                               d_state=SIZES["d_state"],
+                               dt_rank=SIZES["dt_rank"], emit_memory=emit),
+                  D)
+
+
+def _attention(l, **kw):
+    return _layer(L.DifferentialAttentionLayer(
+        n_heads=SIZES["num_attention_heads"],
+        n_kv_heads=SIZES["num_key_value_heads"], head_dim=SIZES["head_dim"],
+        lambda_init=CONF.lambda_init(l), **kw), D)
+
+
+KV = SIZES["num_key_value_heads"] * SIZES["head_dim"]
+
+
+def _case(name):
+    """(layer, params, inputs, the reference as f(params, *inputs))."""
+    if name == "gated_mlp":
+        layer, p = _layer(L.GatedMLPLayer(n_ff=SIZES["intermediate_size"]), D)
+        return layer, p, _inputs(D), REF.mlp
+    if name == "mamba":
+        layer, p = _mamba(emit=True)
+        return layer, p, _inputs(D), REF.mamba
+    if name == "attn_window":
+        layer, p = _attention(1, window=SIZES["sliding_window"])
+        return layer, p, _inputs(D), lambda p, x: REF.diff_attention(
+            p, x, 1, window=SIZES["sliding_window"])[0]
+    if name == "attn_full":
+        layer, p = _attention(17, emit_kv=True)
+        return layer, p, _inputs(D), lambda p, x: REF.diff_attention(p, x, 17)
+    if name == "attn_cross":
+        layer, p = _attention(19, cross=True)
+        return (layer, p, _inputs(D, KV, KV),
+                lambda p, x, k, v: REF.diff_attention(p, x, 19, k=k, v=v)[0])
+    layer, p = _layer(L.GatedMemoryUnit(), D, SIZES["d_inner"])
+    return layer, p, _inputs(D, SIZES["d_inner"]), REF.gmu
+
+
+@pytest.mark.parametrize("name", ["gated_mlp", "mamba", "attn_window",
+                                  "attn_full", "attn_cross", "gmu"])
+def test_layer_matches_reference(name):
+    """Forward, and the gradients of a random projection of every output
+    with respect to every parameter and every input."""
+    layer, params, xs, ref = _case(name)
+
+    def prog(p, *xs):
+        y, _ = layer.apply(p, xs[0] if len(xs) == 1 else tuple(xs), {},
+                           True, None)
+        return y
+
+    y_p, y_r = jax.jit(prog)(params, *xs), jax.jit(ref)(params, *xs)
+    _tree_close(y_p, y_r)
+    ws = [jax.random.normal(jax.random.PRNGKey(9 + i), a.shape, F32)
+          for i, a in enumerate(jax.tree.leaves(y_r))]
+
+    def scalar(f):
+        return lambda p, *xs: sum(
+            jnp.sum(a * w) for a, w in zip(jax.tree.leaves(f(p, *xs)), ws))
+
+    argnums = tuple(range(len(xs) + 1))
+    # 2e-5: a lambda vector's gradient is one scalar, summed over every head
+    # and position of terms that cancel, times the other vector (read here:
+    # 1.004e-5 on the full layer's, everything else under 5e-6)
+    _tree_close(jax.jit(jax.grad(scalar(prog), argnums))(params, *xs),
+                jax.jit(jax.grad(scalar(ref), argnums))(params, *xs), 2e-5)
+
+
+def _scan_args(d, n, t, seed=0, dtype=F32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    return (jax.random.normal(ks[0], (B, t, d), dtype),
+            jax.nn.softplus(jax.random.normal(ks[1], (B, t, d), dtype) - 2),
+            -jnp.exp(jax.random.normal(ks[2], (d, n), dtype)),
+            jax.random.normal(ks[3], (B, t, n), dtype),
+            jax.random.normal(ks[4], (B, t, n), dtype))
+
+
+@pytest.mark.parametrize("d,t,chunk,interpret", [
+    (128, 32, 8, None), (128, 37, 8, None), (128, 32, 64, None),
+    (1024, 24, 8, True), (1024, 21, 8, True)])
+def test_selective_scan_chunked_equals_sequential(d, t, chunk, interpret):
+    """Chunk lengths that do and do not divide T (and one longer than T), on
+    the XLA path and in the Pallas kernels under interpret mode, against the
+    reference's scan over single steps; gradients of all five arguments."""
+    args = _scan_args(d, SIZES["d_state"], t)
+    w = jax.random.normal(jax.random.PRNGKey(5), (B, t, d), F32)
+    before = OpProfiler.get().counter_value(
+        "seq/scan_kernel" if interpret else "seq/scan_fallback")
+    prog = lambda *a: selective_scan(*a, chunk=chunk, interpret=interpret)  # noqa: E731
+    _close(prog(*args), REF.scan(*args))
+    assert OpProfiler.get().counter_value(
+        "seq/scan_kernel" if interpret else "seq/scan_fallback") > before
+    g = lambda f: jax.grad(lambda *a: jnp.sum(f(*a) * w),   # noqa: E731
+                           (0, 1, 2, 3, 4))(*args)
+    _tree_close(g(prog), g(REF.scan))
+
+
+def _qkv(t, hq=4, hk=2, d=16, dv=32, seed=1):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (B, hq, t, d), F32),
+            jax.random.normal(ks[1], (B, hk, t, d), F32),
+            jax.random.normal(ks[2], (B, hk, t, dv), F32))
+
+
+def _plain_gqa(q, k, v, window=None):
+    g = q.shape[1] // k.shape[1]
+    return REF.softmax_rows(q, jnp.repeat(k, g, 1), jnp.repeat(v, g, 1),
+                            window)
+
+
+@pytest.mark.parametrize("t,block,window,interpret,d,dv", [
+    (48, 16, None, None, 16, 32), (48, 16, 8, None, 16, 32),
+    (40, 16, 20, None, 16, 32), (48, 16, 48, None, 16, 32),
+    (48, 16, 100, None, 16, 32),
+    (256, 128, None, True, 64, 128), (384, 128, 130, True, 64, 128)])
+def test_causal_attention_band(t, block, window, interpret, d, dv):
+    """Window attention = full attention under the band mask (query i sees
+    i-window < j <= i), = plain causal attention when window >= T; grouped
+    heads; a T the block does not divide; XLA loops and the Pallas forward
+    under interpret mode. Forward and the gradients of q, k, v."""
+    q, k, v = _qkv(t, d=d, dv=dv)
+    w = jax.random.normal(jax.random.PRNGKey(7), (B, 4, t, dv), F32)
+    prog = lambda *a: causal_attention(*a, window=window, block=block,  # noqa: E731
+                                       interpret=interpret)
+    ref = lambda *a: _plain_gqa(*a, window=window)      # noqa: E731
+    _close(prog(q, k, v), ref(q, k, v))
+    if window and window >= t:
+        _close(prog(q, k, v), _plain_gqa(q, k, v))
+    g = lambda f: jax.grad(lambda *a: jnp.sum(f(*a) * w),   # noqa: E731
+                           (0, 1, 2))(q, k, v)
+    _tree_close(g(prog), g(ref))
+
+
+def test_band_skips_key_blocks():
+    """The counters say how much of the square the band leaves out."""
+    prof = OpProfiler.get()
+    run0 = prof.counter_value("seq/attn_key_blocks_run")
+    skip0 = prof.counter_value("seq/attn_key_blocks_skipped")
+    q, k, v = _qkv(64)
+    causal_attention(q, k, v, window=8, block=8)
+    # 8 query blocks: the first sees 1 key block, the others 2
+    assert prof.counter_value("seq/attn_key_blocks_run") - run0 == 15 * B * 4
+    assert (prof.counter_value("seq/attn_key_blocks_skipped") - skip0
+            == (64 - 15) * B * 4)
+
+
+def test_differential_attention_with_lambda_zero_is_plain_gqa():
+    """lambda forced to 0 (lambda_init 0, zero lambda vectors): the layer is
+    plain grouped-query softmax attention on the q1/k1 half, normalised."""
+    layer, p = _layer(L.DifferentialAttentionLayer(
+        n_heads=4, n_kv_heads=2, head_dim=16, lambda_init=0.0), D)
+    p = {k: (jnp.zeros_like(a) if k.startswith("lambda_") else a)
+         for k, a in p.items()}
+    (x,) = _inputs(D)
+    y, _ = layer.apply(p, x, {}, True, None)
+    q1 = (x @ p["Wq"] + p["bq"]).reshape(B, T, 2, 2, 16)[:, :, :, 0]
+    k1 = (x @ p["Wk"] + p["bk"]).reshape(B, T, 1, 2, 16)[:, :, :, 0]
+    v = (x @ p["Wv"] + p["bv"]).reshape(B, T, 1, 32)
+    a = _plain_gqa(q1.transpose(0, 2, 1, 3), k1.transpose(0, 2, 1, 3),
+                   v.transpose(0, 2, 1, 3))
+    a = a * jax.lax.rsqrt(jnp.mean(a * a, -1, keepdims=True) + layer.eps)
+    a = (a * p["subln"]).transpose(0, 2, 1, 3).reshape(B, T, 64)
+    _close(y, a @ p["Wo"] + p["bo"])
+
+
+@pytest.mark.parametrize("name", ["mamba", "gmu"])
+def test_gradcheck_float64(name):
+    """Central differences in float64 against autodiff through the layer
+    (Mamba: through the scan's custom VJP)."""
+    layer, params, xs, _ = _case(name)
+    to64 = lambda t: jax.tree.map(lambda a: a.astype(jnp.float64)[..., :8, :]  # noqa: E731
+                                  if a.ndim == 3 else a.astype(jnp.float64), t)
+    params, xs = to64(params), to64(xs)
+
+    def f(p, *xs):
+        y, _ = layer.apply(p, xs[0] if len(xs) == 1 else tuple(xs), {},
+                           True, None)
+        return sum(jnp.sum(jnp.sin(a)) for a in jax.tree.leaves(y))
+
+    grads = jax.grad(f, tuple(range(len(xs) + 1)))(params, *xs)
+    flat, tree = jax.tree.flatten((params, *xs))
+    gflat = jax.tree.leaves(grads)
+    rng = np.random.default_rng(0)
+    for leaf, g in zip(range(len(flat)), gflat):
+        for _ in range(2):
+            idx = tuple(rng.integers(0, s) for s in flat[leaf].shape)
+            h = 1e-6
+            bump = lambda s: f(*jax.tree.unflatten(tree, [      # noqa: E731
+                a.at[idx].add(s * h) if i == leaf else a
+                for i, a in enumerate(flat)]))
+            numeric = (bump(1) - bump(-1)) / (2 * h)
+            assert abs(numeric - g[idx]) <= 1e-6 * max(1.0, abs(g[idx])), \
+                (leaf, idx, float(numeric), float(g[idx]))
+
+
+# --- the six-layer model through ComputationGraph.fit -------------------------
+
+SEQ = 32
+MIX = {"batch": 1, "seq": SEQ, "batches": 3, "first_steps": 3}
+SEED = 11
+
+
+def _model(layers=None, compute_dtype=None, state_dtype=None, remat="full"):
+    return Phi4MiniFlash(
+        layers=layers or SIZES["layers_kept"], vocab_rows=SIZES["vocab_size"],
+        hidden_size=D, intermediate_size=SIZES["intermediate_size"],
+        num_attention_heads=SIZES["num_attention_heads"],
+        num_key_value_heads=SIZES["num_key_value_heads"],
+        sliding_window=SIZES["sliding_window"], d_state=SIZES["d_state"],
+        dt_rank=SIZES["dt_rank"], seq_len=SEQ, compute_dtype=compute_dtype,
+        state_dtype=state_dtype, remat_policy=remat).init()
+
+
+def _batches():
+    gen = _load(os.path.join(BENCH, "traffic", "token_stream.py"), "bench_gen")
+    return gen.make(MIX, SIZES, SEED, 3)
+
+
+def _drive(cfg):
+    """The benchmark's own comparison at this file's sizes: the program's
+    three steps, one ``fit`` call each, against the reference's. Also the
+    job, and how often its step was traced."""
+    job = CONF.build(cfg, SIZES, 1, MIX)
+    batches = _batches()
+    w0 = CONF.make_weights(cfg, SIZES, SEED)
+    w0_host = jax.device_get(w0)
+    job.reset(w0)
+    traced = OpProfiler.get().counter_value("trace/graph_fit_step")
+    prog = compare.drive_first_steps(job, batches, w0_host)
+    traced = OpProfiler.get().counter_value("trace/graph_fit_step") - traced
+    ref = compare.reference_norms(CONF.reference(cfg, SIZES, SEED, batches))
+    return {"job": job, "prog": prog, "ref": ref, "batches": batches,
+            "traced": traced}
+
+
+@pytest.fixture(scope="module")
+def float32_run():
+    return _drive({**CFG, "compute_dtype": "", "updater_state_dtype": ""})
+
+
+@pytest.fixture(scope="module")
+def bfloat16_run():
+    return _drive(CFG)
+
+
+def test_fit_three_steps_float32_matches_reference(float32_run):
+    """Losses, the first gradient per leaf and the parameters' change per
+    leaf, by the benchmark's gaps."""
+    found = compare.gaps(float32_run["prog"], float32_run["ref"])
+    assert set(found) >= {"loss_step3", "first_gradient", "param_change"}
+    for name, (gap, where) in found.items():
+        assert gap <= 1e-4, (name, gap, where)
+
+
+def test_fit_three_steps_bfloat16_inside_limits_that_float8_fails(bfloat16_run):
+    import precisions
+
+    r = bfloat16_run
+    found = compare.gaps(r["prog"], r["ref"])
+    low = compare.gaps(compare.reference_norms(CONF.reference(
+        CFG, SIZES, SEED, r["batches"],
+        lower=precisions.get(CFG["control_precision"]))), r["ref"])
+    # read here: bfloat16 1.0e-3 and 1.1e-3, float8 0.21 and 0.65; the losses
+    # of three steps from random weights barely tell the two apart (1.2e-4
+    # against 5.4e-4), so they carry no limit
+    limits = CFG["limits_tiny"]
+    assert set(limits) == {"first_gradient_median_leaf",
+                           "param_change_median_leaf"}
+    ok, rows = compare.judge(found, limits)
+    assert ok, rows
+    ok8, rows8 = compare.judge(low, limits)
+    assert not ok8, rows8
+    assert all(low[k][0] > 3 * v for k, v in limits.items()), rows8
+
+
+def test_fit_compiles_once(bfloat16_run):
+    """Three one-step calls and then three epochs over three sequences: one
+    trace of the step in all."""
+    r = bfloat16_run
+    assert r["traced"] == 1
+    prof = OpProfiler.get()
+    before = prof.counter_value("trace/graph_fit_step")
+    m = r["job"].model
+    it = m._iteration
+    r["job"].fit(r["job"].feed(r["batches"]), epochs=3)
+    assert prof.counter_value("trace/graph_fit_step") == before
+    assert m._iteration == it + 9
+    assert np.isfinite(m.score_value)
+
+
+def test_tied_head_is_one_leaf_and_sums_both_gradients():
+    """The head owns nothing; the table's gradient is the embedding's part
+    plus the head's part, as an untied twin (the reference with the head's
+    table passed apart) gives them."""
+    layers = [0, 1]
+    m = _model(layers)
+    assert m._params["head"] == {}
+    assert [n for n, p in m._params.items() if "W" in p and p["W"].shape
+            == (SIZES["vocab_size"], D)] == ["embed"]
+    b = _batches()[0]
+    ids, labels = jnp.asarray(b["ids"]), jnp.asarray(b["labels"])
+    key = jax.random.PRNGKey(0)
+    grads = jax.jit(jax.grad(lambda p: m._loss(
+        p, m._states, {"ids": ids}, {"head": labels}, {}, True, key)[0]))(
+            m._params)
+    p = m._params
+
+    def twin(embed, head):
+        x = embed[ids]
+        for l in layers:
+            h = REF.ln(p[f"l{l}_ln1"], x)
+            x = x + (REF.mamba(p[f"l{l}_mix"], h)[0] if l == 0 else
+                     REF.diff_attention(p[f"l{l}_mix"], h, l,
+                                        window=SIZES["sliding_window"])[0])
+            x = x + REF.mlp(p[f"l{l}_mlp"], REF.ln(p[f"l{l}_ln2"], x))
+        return REF.head_loss(head, REF.ln(p["final_ln"], x), labels,
+                             jnp.full((1, SEQ), 1.0 / SEQ, F32))
+
+    E = p["embed"]["W"]
+    g_embed, g_head = jax.jit(jax.grad(twin, (0, 1)))(E, E)
+    assert float(jnp.linalg.norm(g_embed)) > 0 < float(jnp.linalg.norm(g_head))
+    _close(grads["embed"]["W"], g_embed + g_head, 2e-5)
+
+
+def test_head_loss_runs_in_token_blocks():
+    """More positions than the head holds at once (three blocks, the last
+    padded): the loss and its gradients with respect to the table and the
+    input are those of the reference's head."""
+    from deeplearning4j_tpu.nn.conf.layers_seq import HEAD_TOKEN_BLOCK
+
+    n, vocab = 2 * HEAD_TOKEN_BLOCK + 452, SIZES["vocab_size"]
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    x = jax.random.normal(ks[0], (1, n, D), F32)
+    E = jax.random.normal(ks[1], (vocab, D), F32) * 0.3
+    labels = jax.random.randint(ks[2], (1, n), 0, vocab)
+    w = jnp.full((1, n), 1.0 / n, F32)
+    head = L.TiedOutputLayer(tied_to="embed")
+    prog = lambda E, x: head.fused_score({"W": E}, x, labels, w)  # noqa: E731
+    ref = lambda E, x: REF.head_loss(E, x, labels, w)             # noqa: E731
+    _close(jax.jit(prog)(E, x), jax.jit(ref)(E, x))
+    _tree_close(jax.jit(jax.grad(prog, (0, 1)))(E, x),
+                jax.jit(jax.grad(ref, (0, 1)))(E, x))
+
+
+def test_remat_none_and_full_give_the_same_losses():
+    """The policy changes which residuals are kept, never the math. Bit for
+    bit at the first step (the forward is the same program); later steps may
+    differ in the last place, because XLA fuses a recomputed forward into the
+    backward's loops and rounds its sums in another order (read here: step 2
+    differs by one float32 ulp of the loss, step 3 by none)."""
+    losses = {}
+    for remat in ("none", "full"):
+        m = _model([16, 17, 18], remat=remat)
+        out = []
+        for x in _batches():
+            m.fit(DataSet(x["ids"], x["labels"]), epochs=1, batch_size=1)
+            out.append(m.score_value)
+        losses[remat] = out
+    assert losses["none"][0] == losses["full"][0], losses
+    np.testing.assert_allclose(losses["none"], losses["full"], rtol=3e-7)
+
+
+def test_sequence_stats_ledger():
+    q, k, v = _qkv(16)
+    causal_attention(q, k, v, block=8)
+    stats = OpProfiler.get().sequence_stats()
+    assert stats["attn_fallback"] >= 1 and stats["attn_key_blocks_run"] >= 3
+    assert ("sequence", "sequence_stats") in OpProfiler.LEDGERS

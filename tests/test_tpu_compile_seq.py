@@ -1,0 +1,113 @@
+"""The sequence kernels compile for the chip at the shapes of the cell
+``phi4_mini_flash.train_s8k``: the selective scan forward and backward
+(``ops/ssm.py``) and the banded attention forward with grouped heads, a
+window and bfloat16 operands (``ops/pallas_attention.causal_attention``), each
+with its backward. As ``tests/test_tpu_compile.py``: the compiler is installed
+with jax and compiles for a chip that is DESCRIBED, not attached; a compile
+that passes is not a chip run.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deeplearning4j_tpu.ops import pallas_attention as pa
+from deeplearning4j_tpu.ops import ssm
+
+T, D_INNER, D_STATE = 8192, 5120, 16        # the cell's sequence and widths
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — no libtpu: nothing to compile for
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _scan(grad):
+    f32 = jnp.float32
+    wide, narrow = ((1, T, D_INNER), f32), ((1, T, D_STATE), f32)
+
+    def fwd(u, dt, A, Bm, Cm):
+        return ssm._scan(u, dt, A, Bm, Cm, ssm.DEFAULT_CHUNK, True, False)
+
+    def both(*a):
+        return jax.grad(lambda *a: fwd(*a).sum(), argnums=(0, 1, 2, 3, 4))(*a)
+
+    return (both if grad else fwd), [wide, wide, ((D_INNER, D_STATE), f32),
+                                     narrow, narrow]
+
+
+def _attention(window, grad, plain=None):
+    # the two score maps on the batch axis: 2 x 20 query heads of 64 over
+    # 2 x 10 key heads, the pair's 128-wide value; ``plain``: float32, equal
+    # head counts and a value as wide as the head (what
+    # ``flash_attention(causal=True)`` hands over), at that width
+    bf16 = jnp.bfloat16
+    shapes = [((2, 10, 2, T, 64), bf16), ((2, 10, T, 64), bf16),
+              ((2, 10, T, 128), bf16)]
+    if plain:
+        shapes = [((2, 4, 1, 2048, plain), jnp.float32)] + [
+            ((2, 4, 2048, plain), jnp.float32)] * 2
+
+    def fwd(q, k, v):
+        return pa._band(q, k, v, 0.125, window, pa.BAND_BLOCK, True, False)
+
+    def both(*a):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(*a)
+
+    return (both if grad else fwd), shapes
+
+
+CASES = {
+    "selective_scan_fwd": (lambda: _scan(False), 1),
+    "selective_scan_fwd_bwd": (lambda: _scan(True), 2),
+    "attention_full_fwd": (lambda: _attention(None, False), 1),
+    "attention_window_fwd": (lambda: _attention(512, False), 1),
+    "attention_window_fwd_bwd": (lambda: _attention(512, True), 1),
+    "attention_plain_f32_d64_fwd_bwd": (lambda: _attention(None, True, 64), 1),
+    "attention_plain_f32_d32_fwd_bwd": (lambda: _attention(None, True, 32), 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_sequence_kernel_compiles_for_v5e(case, v5e):
+    build, kernels = CASES[case]
+    fn, shapes = build()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    # the Mosaic kernels are in the executable, not an XLA stand-in
+    assert compiled.as_text().count("tpu_custom_call") >= kernels
+    assert supports(case)
+
+
+def supports(case):
+    if case.startswith("selective_scan"):
+        return ssm.supports_scan_kernel(D_INNER, D_STATE)
+    if "plain" in case:
+        d = int(case.split("_d")[1].split("_")[0])
+        return pa.supports_band_kernel(2048, d, d, pa.BAND_BLOCK)
+    return pa.supports_band_kernel(T, 64, 128, pa.BAND_BLOCK)
