@@ -63,7 +63,10 @@ class _Var:
     vtype: str
     shape: Optional[Tuple[Optional[int], ...]] = None
     dtype: str = "float32"
-    value: Optional[np.ndarray] = None      # materialized for VARIABLE/CONSTANT
+    # materialized for VARIABLE/CONSTANT. A VARIABLE's value is a host array
+    # until a call first needs it on the device and a jax.Array from then on
+    # (SameDiff._params); assigning either kind is how a caller replaces it.
+    value: Any = None
     producer: Optional[int] = None           # node id for ARRAY vars
     out_index: int = 0
 
@@ -109,8 +112,11 @@ class SDVariable:
         return self.sd.output(placeholders or {}, [self.name])[self.name]
 
     def arr(self) -> Optional[NDArray]:
+        """The current value, as a copy of the caller's own: a variable's
+        device buffer is donated to the next ``fit`` step, which would
+        delete a handle that shared it."""
         v = self.sd._vars[self.name]
-        return NDArray(jnp.asarray(v.value)) if v.value is not None else None
+        return NDArray(jnp.array(v.value)) if v.value is not None else None
 
     # --- graph-building operators --------------------------------------
     def _bin(self, op: str, other, reverse: bool = False):
@@ -581,8 +587,29 @@ class SameDiff:
         return fn
 
     def _params(self) -> Dict[str, jnp.ndarray]:
-        return {n: jnp.asarray(v.value) for n, v in self._vars.items()
-                if v.vtype == VariableType.VARIABLE}
+        """The trainable variables as the device arrays a compiled module
+        takes. A value that is already a ``jax.Array`` is handed over as it
+        is; a host array (a fresh ``var()``, an import, a ``load()``, a
+        caller's assignment to ``.value``) is uploaded and the device array
+        kept in ``.value``, so a variable is uploaded once in its life and
+        not once a call. Counted in ``samediff/vars_resident`` and
+        ``samediff/vars_uploaded``.
+
+        The returned arrays ARE the variables' buffers, and ``fit``'s step
+        donates them: hold them no longer than the call they were taken
+        for (``SDVariable.arr`` hands out a copy)."""
+        params, uploaded = {}, 0
+        for n, v in self._vars.items():
+            if v.vtype != VariableType.VARIABLE:
+                continue
+            if not isinstance(v.value, jax.Array):
+                v.value = jnp.asarray(v.value)
+                uploaded += 1
+            params[n] = v.value
+        prof = OpProfiler.get()
+        prof.count("samediff/vars_resident", len(params) - uploaded)
+        prof.count("samediff/vars_uploaded", uploaded)
+        return params
 
     def _jitted(self, outputs: Tuple[str, ...], training: bool) -> Callable:
         cache_key = (outputs, training)
@@ -722,6 +749,16 @@ class SameDiff:
         Placeholder binding follows the reference TrainingConfig data-layout
         contract: with exactly two placeholders, first=features, second=labels
         unless explicitly named.
+
+        ``fit`` brings nothing to the host that nobody asked for. The
+        trained values and the updater state stay on the device, as the
+        variables' ``.value`` and ``_updater_state``, and the next ``fit``,
+        ``output`` or ``calculate_gradients`` takes them from there; a host
+        copy is made when a reader asks (``save``, ``np.asarray(v.value)``,
+        ``SDVariable.arr().to_numpy()``). The epoch losses of the returned
+        ``History`` are device scalars until they are read, so ``fit``
+        returns once the last step is dispatched, not once it has run:
+        reading a loss or a value is what waits for the device.
         """
         from ..data.dataset import DataSet
         from .history import History
@@ -754,37 +791,24 @@ class SameDiff:
         self._fit_calls += 1
         with OpProfiler.get().time_section("fit/enter",
                                            call=self._fit_calls):
-            params = self._params()     # host -> device, every variable
+            # host -> device only for what is not there yet (first call
+            # after a build, an import, a load or a caller's assignment)
+            params = self._params()
             if self._updater_state is None:
                 self._updater_state = self._training_config.updater.init(
                     params)
             state = self._updater_state
             step = self._train_step_fn(loss_name, tuple(phs))
-        history = History()
-        listeners = listeners or []
-        # The jitted step donates its params/state inputs. If a step fails
-        # after dispatch (OOM, NaN panic, Ctrl-C), whatever self._vars /
-        # self._updater_state reference may already be deleted; the finally
-        # block below restores the entry values so the model object stays
-        # usable for recovery save/inspection (training progress since the
-        # last successful fit/checkpoint is lost — same semantic as the
-        # reference crashing mid-fit).
-        entry_vals = {n: self._vars[n].value for n in params}
-        try:
-            return self._fit_loop(step, data, batch_size, epochs,
-                                  feature_placeholder, label_placeholder,
-                                  params, state, history, listeners)
-        except BaseException:
-            def _dead(a):
-                return hasattr(a, "is_deleted") and a.is_deleted()
+        return self._fit_loop(step, data, batch_size, epochs,
+                              feature_placeholder, label_placeholder,
+                              params, state, History(), listeners or [])
 
-            for n, v0 in entry_vals.items():
-                if _dead(self._vars[n].value):
-                    self._vars[n].value = v0
-            if self._updater_state is not None and any(
-                    _dead(l) for l in jax.tree.leaves(self._updater_state)):
-                self._updater_state = None  # momenta restart on next fit
-            raise
+    def _hold(self, params, state) -> None:
+        """Make ``params``/``state`` the model's own. Reference assignment
+        only: nothing is copied and nothing is brought to the host."""
+        for n, v in params.items():
+            self._vars[n].value = v
+        self._updater_state = state
 
     def _bound_batches(self, data, batch_size, feature_placeholder,
                        label_placeholder):
@@ -806,53 +830,72 @@ class SameDiff:
         # the sections carry the names ComputationGraph.fit's do
         # (data/pipeline.run_epochs), so one trace reader serves both
         prof = OpProfiler.get()
-        for epoch in range(epochs):
-            loss_sum, n_batches = None, 0
-            for ph in timed_iter(self._bound_batches(
-                    data, batch_size, feature_placeholder,
-                    label_placeholder), step=self._iteration):
-                key = get_random().next_key()
-                with prof.time_section("pipeline/dispatch",
-                                       step=self._iteration):
-                    params, state, loss = step(params, state, ph, key,
-                                               jnp.asarray(self._iteration))
-                self._iteration += 1
-                # device scalar all the way down: listeners receive it un-synced
-                # and decide when to read (the multilayer/ui.stats contract);
-                # fit itself syncs ONCE per epoch below via a running on-device
-                # sum (O(1) memory, no variadic stack). The reference's
-                # TrainingSession also floats per step — that cost is invisible
-                # over JNI but a per-step readback stalls dispatch here.
-                loss_sum = loss if loss_sum is None else loss_sum + loss
-                n_batches += 1
-                if listeners:
-                    # a listener may checkpoint THIS model mid-fit (e.g.
-                    # CheckpointListener): expose the live post-step buffers.
-                    # Reference assignment only — no host sync; the returned
-                    # arrays are fresh (the donated ones were the inputs), so
-                    # a save here serializes valid, current state.
-                    for n, v in params.items():
-                        self._vars[n].value = v
-                    self._updater_state = state
-                for lst in listeners:
-                    lst.iteration_done(self, self._iteration, loss)
-            self._epoch += 1
-            if loss_sum is None:
-                raise ValueError(
-                    "training data yielded no batches this epoch (exhausted "
-                    "iterator or empty dataset)")
-            with prof.time_section("fit/epoch_end", epoch=epoch):
-                with prof.time_section("fit/sync", epoch=epoch):
-                    mean_loss = float(loss_sum) / n_batches
-                history.add_epoch(self._epoch, mean_loss)
-                for lst in listeners:
-                    if hasattr(lst, "epoch_done"):
-                        lst.epoch_done(self, self._epoch)
-        # write trained values back into the graph (stateful shell)
+        try:
+            for epoch in range(epochs):
+                loss_sum, n_batches = None, 0
+                for ph in timed_iter(self._bound_batches(
+                        data, batch_size, feature_placeholder,
+                        label_placeholder), step=self._iteration):
+                    key = get_random().next_key()
+                    with prof.time_section("pipeline/dispatch",
+                                           step=self._iteration):
+                        params, state, loss = step(
+                            params, state, ph, key,
+                            jnp.asarray(self._iteration))
+                    self._iteration += 1
+                    # device scalar all the way down: listeners receive it
+                    # un-synced and decide when to read (the multilayer/
+                    # ui.stats contract), and fit itself reads none: the
+                    # epoch's mean is a running on-device sum (O(1) memory,
+                    # no variadic stack) that History turns into a float
+                    # when somebody asks. The reference's TrainingSession
+                    # floats per step — that cost is invisible over JNI but
+                    # a readback stalls dispatch here.
+                    loss_sum = loss if loss_sum is None else loss_sum + loss
+                    n_batches += 1
+                    if listeners:
+                        # a listener may checkpoint THIS model mid-fit (e.g.
+                        # CheckpointListener): expose the live post-step
+                        # buffers. The returned arrays are fresh (the donated
+                        # ones were the inputs), so a save here serializes
+                        # valid, current state.
+                        self._hold(params, state)
+                    for lst in listeners:
+                        lst.iteration_done(self, self._iteration, loss)
+                self._epoch += 1
+                if loss_sum is None:
+                    raise ValueError(
+                        "training data yielded no batches this epoch "
+                        "(exhausted iterator or empty dataset)")
+                with prof.time_section("fit/epoch_end", epoch=epoch):
+                    history.add_epoch(self._epoch, loss_sum / n_batches)
+                    for lst in listeners:
+                        if hasattr(lst, "epoch_done"):
+                            lst.epoch_done(self, self._epoch)
+        except BaseException:
+            # The step donates its params/state inputs, so what the model
+            # held at entry is deleted after the first step and there is no
+            # host copy to go back to. What is live is the newest output of
+            # the loop: a failure between steps (a listener, the data, a
+            # Ctrl-C) or before a launch (a batch of the wrong shape) leaves
+            # it intact, and the model goes on from the last finished step,
+            # usable for a recovery save. A step that failed AFTER consuming
+            # its inputs (OOM at run time, NaN panic) returned nothing:
+            # those values exist nowhere any more, the variables stay
+            # deleted (reading them raises) until the caller assigns or
+            # loads new ones, and the momenta restart on the next fit.
+            def _dead(a):
+                return isinstance(a, jax.Array) and a.is_deleted()
+
+            self._hold(
+                {n: v for n, v in params.items() if not _dead(v)},
+                None if any(_dead(l) for l in jax.tree.leaves(state))
+                else state)
+            raise
+        # the trained values become the graph's (stateful shell), where
+        # they are: on the device
         with prof.time_section("fit/exit", call=self._fit_calls):
-            for n, val in params.items():
-                self._vars[n].value = np.asarray(val)
-            self._updater_state = state
+            self._hold(params, state)
         return history
 
     # --- serialization ---------------------------------------------------
@@ -1068,7 +1111,12 @@ def _lower_control(node: "_Node", env: Dict[str, Any], training: bool, key):
         names = node.sub_inputs[tag]
 
         def run(args, k):
-            return fn(sub._params(), dict(zip(names, args)), k)
+            # a body's own variables are constants of the enclosing trace
+            # (never trained, never donated): not _params(), which keeps
+            # what it uploads, and here that would be a tracer
+            held = {n: jnp.asarray(v.value) for n, v in sub._vars.items()
+                    if v.vtype == VariableType.VARIABLE}
+            return fn(held, dict(zip(names, args)), k)
 
         return run
 

@@ -11,7 +11,10 @@ TPU-shaped differences (documented): the reference applies Hogwild-ish
 gradient updates under its AsyncGlobal lock; here the whole update is one
 jitted SameDiff step, serialized by the same kind of lock — worker
 parallelism buys overlapped ENVIRONMENT stepping (the host-bound part,
-SURVEY §7.3.6), while the math stays in single compiled modules.
+SURVEY §7.3.6), while the math stays in single compiled modules. A worker
+reads the shared networks under that lock too: their parameters live on the
+device and the update step donates them, so a read that overlapped an
+update could be handed a buffer the step has just consumed.
 """
 
 from __future__ import annotations
@@ -131,7 +134,8 @@ class A3CDiscreteDense(_AsyncBase):
             frag_obs, frag_act, frag_rew = [], [], []
             done = False
             for _ in range(c.nstep):
-                a = policy.next_action(obs)
+                with self._lock:
+                    a = policy.next_action(obs)
                 nxt, r, done, _ = mdp.step(a)
                 frag_obs.append(obs)
                 frag_act.append(a)
@@ -147,8 +151,9 @@ class A3CDiscreteDense(_AsyncBase):
             if done or ep_steps >= c.max_epoch_step:
                 boot = 0.0
             else:
-                _, v = self.net.policy_and_value(
-                    np.asarray(obs, np.float32)[None])
+                with self._lock:
+                    _, v = self.net.policy_and_value(
+                        np.asarray(obs, np.float32)[None])
                 boot = float(v[0])
             R = boot
             returns = np.zeros(len(frag_rew), np.float32)
@@ -156,7 +161,8 @@ class A3CDiscreteDense(_AsyncBase):
                 R = frag_rew[i] + c.gamma * R
                 returns[i] = R
             ob = np.asarray(frag_obs, np.float32)
-            _, values = self.net.policy_and_value(ob)
+            with self._lock:
+                _, values = self.net.policy_and_value(ob)
             adv = returns - values
             onehot = np.eye(nA, dtype=np.float32)[np.asarray(frag_act)]
             with self._lock:
@@ -218,8 +224,9 @@ class AsyncNStepQLearningDiscreteDense(_AsyncBase):
                 if rng.random() < self._epsilon(tid):
                     a = int(rng.integers(0, nA))
                 else:
-                    q = self.net.output(
-                        np.asarray(obs, np.float32)[None]).to_numpy()[0]
+                    with self._lock:
+                        q = self.net.output(
+                            np.asarray(obs, np.float32)[None]).to_numpy()[0]
                     a = int(np.argmax(q))
                 nxt, r, done, _ = mdp.step(a)
                 frag_obs.append(obs)
@@ -235,8 +242,9 @@ class AsyncNStepQLearningDiscreteDense(_AsyncBase):
             if done or ep_steps >= c.max_epoch_step:
                 boot = 0.0
             else:
-                qn = self.target.output(
-                    np.asarray(obs, np.float32)[None]).to_numpy()[0]
+                with self._lock:
+                    qn = self.target.output(
+                        np.asarray(obs, np.float32)[None]).to_numpy()[0]
                 boot = float(qn.max())
             R = boot
             returns = np.zeros(len(frag_rew), np.float32)

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
+import jax.numpy as jnp
 import numpy as np
 
 from ..autodiff.samediff import SameDiff, TrainingConfig
@@ -90,9 +91,11 @@ class SameDiffQNetwork:
         return new
 
     def copy_params_from(self, other: "SameDiffQNetwork") -> None:
+        # a copy of this network's own, made where the value lies: the
+        # other's buffers are donated to its next fit step
         for n, v in other.sd._vars.items():
             if v.vtype == "VARIABLE":
-                self.sd._vars[n].value = np.asarray(v.value)
+                self.sd._vars[n].value = jnp.array(v.value)
 
 
 def DuelingQNetwork(obs_dim: int, n_actions: int,
@@ -173,5 +176,5 @@ class ActorCriticNetwork:
                                  self.value_coeff, self.seed)
         for n, v in self.sd._vars.items():
             if v.vtype == "VARIABLE":
-                new.sd._vars[n].value = np.asarray(v.value)
+                new.sd._vars[n].value = jnp.array(v.value)
         return new
