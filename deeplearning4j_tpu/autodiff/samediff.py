@@ -42,6 +42,7 @@ from ..common.profiler import OpProfiler
 from ..data.pipeline import timed_iter
 from ..ndarray.ndarray import NDArray
 from ..ndarray.rng import get_random
+from ..learning.precision import apply_updater
 from ..learning.schedules import ISchedule
 from ..learning.updaters import Adam, GradientUpdater
 from ..ops.registry import all_ops, get_op
@@ -731,7 +732,8 @@ class SameDiff:
             if tc.grad_clip_value:
                 grads = jax.tree.map(
                     lambda g: jnp.clip(g, -tc.grad_clip_value, tc.grad_clip_value), grads)
-            new_params, new_state = updater.apply(grads, upd_state, params, iteration)
+            new_params, new_state = apply_updater(
+                updater, grads, upd_state, params, iteration, key)
             return new_params, new_state, loss
 
         jitted = xprof.register_jit(

@@ -17,7 +17,7 @@ minibatch", with the host side around it made shape-stable and overlapped):
   ``common.background.staged_iter``): batch placement (``jax.device_put``
   or a sharded put) is issued ``depth`` batches ahead of the consumer, so
   the H2D transfer of batch *n+1* overlaps the device compute of batch
-  *n*; host-side assembly can additionally run on a prefetch thread.
+  *n*.
 - **multi-step dispatch** (:func:`chunked`): group K stable batches per
   Python dispatch; the networks stack them and run a ``lax.scan`` device
   loop, amortizing Python/dispatch overhead over K steps (the same lever
@@ -222,15 +222,13 @@ def stable_batches(data: Any, batch_size: Optional[int] = None,
             yield padded, w, n
 
 
-def device_feed(batches: Iterable, place=None, depth: int = 2,
-                host_prefetch: int = 0) -> Iterator:
+def device_feed(batches: Iterable, place=None, depth: int = 2) -> Iterator:
     """Stage ``place(batch)`` (device placement) ``depth`` batches ahead of
     the consumer — see ``common.background.staged_iter`` for the threading
     contract. ``depth=0`` disables lookahead (fully serial feed)."""
     if place is None:
         place = lambda b: b  # noqa: E731
-    return staged_iter(batches, stage=place, depth=depth,
-                       host_prefetch=host_prefetch)
+    return staged_iter(batches, stage=place, depth=depth)
 
 
 def timed_iter(it: Iterable, section: str = "pipeline/next_batch",
@@ -274,7 +272,6 @@ def run_epochs(data: Any, epochs: int, batch_size: Optional[int],
                dispatch_chunk, stackable, on_epoch,
                round_to_multiple_of: int = 1,
                allow_multi: bool = False,
-               host_prefetch: int = 0,
                skip: Optional[Tuple[int, int]] = None,
                pre_dispatch=None, first_step: int = 0) -> None:
     """The one training-loop skeleton shared by MultiLayerNetwork.fit,
@@ -380,8 +377,7 @@ def run_epochs(data: Any, epochs: int, batch_size: Optional[int],
                         "change since the checkpoint?", skip_steps, skipped)
             bound = (guarded_bind(ds, w) for ds, w, _n in gen)
             feed = timed_iter(device_feed(
-                bound, place=guarded_place, depth=max(0, int(prefetch)),
-                host_prefetch=max(0, int(host_prefetch))),
+                bound, place=guarded_place, depth=max(0, int(prefetch))),
                 step=first_step + n_dispatched)
             if k == 1:
                 for b in feed:

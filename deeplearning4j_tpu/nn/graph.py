@@ -22,7 +22,6 @@ import numpy as np
 
 from ..common import xprof
 from ..common.profiler import OpProfiler
-from ..data import pipeline as _pipe
 from ..data.dataset import DataSet, MultiDataSet
 from ..ndarray.ndarray import NDArray
 from ..ndarray.rng import get_random
@@ -30,6 +29,8 @@ from .conf import layers as L
 from .conf.builder import (GlobalConf, MultiLayerConfiguration, _deser_obj,
                            _ser_obj, remat_wrap)
 from .conf.inputs import CNNFlatInput, CNNInput, FFInput, InputType, RNNInput, cnn_to_ff, flat_to_cnn
+from .train_step import (FitLoop, _fold_weights, chunk_program, make_core,
+                         step_program)
 
 
 # --- graph vertices (reference conf/graph/*) ---------------------------------
@@ -397,32 +398,16 @@ class GraphBuilder:
         apply_layer_defaults(l, self._conf.global_conf)
 
 
-class ComputationGraph:
+class ComputationGraph(FitLoop):
     """Runtime twin of the configuration (reference ComputationGraph)."""
 
+    _one_batch = (DataSet, MultiDataSet)
+    _allow_multi = True
+
     def __init__(self, conf: ComputationGraphConfiguration):
-        self.conf = conf
+        super().__init__(conf)
         self._params: Dict[str, Dict[str, jnp.ndarray]] = {}
         self._states: Dict[str, Dict[str, jnp.ndarray]] = {}
-        self._updater_state = None
-        self._initialized = False
-        self._iteration = 0
-        self._epoch = 0
-        self._fit_calls = 0
-        self._listeners: List[Any] = []
-        self._telemetry = None
-        self._fit_step = None
-        self._chunk_step = None
-        self._infer_fn = None
-        self._score_dev = None
-
-    @property
-    def score_value(self) -> float:
-        return float(self._score_dev) if self._score_dev is not None else float("nan")
-
-    @score_value.setter
-    def score_value(self, v) -> None:
-        self._score_dev = v
 
     # ------------------------------------------------------------------
     def init(self, seed: Optional[int] = None) -> "ComputationGraph":
@@ -441,43 +426,6 @@ class ComputationGraph:
                     self._states[name] = node.layer.init_state()
         self._initialized = True
         return self
-
-    def set_listeners(self, *listeners) -> None:
-        self._listeners = list(listeners)
-        for lst in self._listeners:
-            # checkpoint-style listeners snapshot their peers' state for
-            # exact resume (see MultiLayerNetwork.set_listeners)
-            bind = getattr(lst, "bind_group", None)
-            if callable(bind):
-                bind(self._listeners)
-        from ..optimize.telemetry import config_for
-
-        cfg = config_for(self._listeners)
-        if cfg != self._telemetry:
-            # in-graph telemetry is a build-time property of the jitted
-            # step (see MultiLayerNetwork.set_listeners)
-            self._telemetry = cfg
-            self._fit_step = None
-            self._chunk_step = None
-
-    def set_remat_policy(self, policy) -> None:
-        """Switch the rematerialization policy in place — a build-time
-        property of the jitted step (see MultiLayerNetwork
-        .set_remat_policy): exactly one rebuild on the next fit."""
-        if policy == self.conf.global_conf.remat_policy:
-            return
-        self.conf.global_conf.remat_policy = policy
-        self._fit_step = None
-        self._chunk_step = None
-
-    def num_params(self) -> int:
-        return sum(int(np.prod(l.shape)) for l in jax.tree.leaves(self._params))
-
-    def params(self) -> NDArray:
-        leaves = jax.tree.leaves(self._params)
-        if not leaves:
-            return NDArray(jnp.zeros((0,)))
-        return NDArray(jnp.concatenate([l.ravel() for l in leaves]))
 
     # --- forward ---------------------------------------------------------
     def _epilogue_fusion_plan(self):
@@ -707,11 +655,8 @@ class ComputationGraph:
                     labels[out_name], pre, node.layer.activation, mask,
                     average=True)
             else:
-                # example-weighted mean (shape-stable batching, see
-                # multilayer._loss): pad rows carry w=0 and the divisor is
-                # the real example count
-                from .multilayer import _fold_weights
-
+                # example-weighted mean (shape-stable batching): pad rows
+                # carry w=0 and the divisor is the real example count
                 s = node.layer.loss.compute_score(
                     labels[out_name], pre, node.layer.activation,
                     _fold_weights(mask, w), average=False)
@@ -732,34 +677,7 @@ class ComputationGraph:
                     reg = reg + l1 * jnp.sum(jnp.abs(w))
         return total + reg, new_states
 
-    def score(self, ds: Union[DataSet, MultiDataSet], training: bool = False) -> float:
-        self._check_init()
-        inputs, labels, masks = self._bind_dataset(ds)
-        loss, _ = self._loss(self._params, self._states, inputs, labels, masks,
-                             training, get_random().next_key())
-        return float(loss)
-
-    def compute_gradient_and_score(self, ds):
-        self._check_init()
-        inputs, labels, masks = self._bind_dataset(ds)
-        key = jax.random.PRNGKey(0)
-
-        def loss_fn(params):
-            loss, _ = self._loss(params, self._states, inputs, labels, masks, False, key)
-            return loss
-
-        loss, grads = jax.value_and_grad(loss_fn)(self._params)
-        self.score_value = float(loss)
-        return grads, self.score_value
-
-    def _bind_fit_batch(self, ds, w):
-        """The fit-loop bind: the training tuple plus the bookkeeping
-        only fit needs (PerformanceListener derives samples/sec from the
-        bound batch size; evaluate() shares _bind_dataset without it)."""
-        self._last_batch_size = ds.num_examples()
-        return self._bind_dataset(ds) + (w,)
-
-    def _bind_dataset(self, ds):
+    def _bind(self, ds):
         in_names = self.conf.network_inputs
         out_names = [o for o in self.conf.network_outputs
                      if isinstance(self.conf.nodes[o].layer, (L.OutputLayer, L.LossLayer))]
@@ -778,199 +696,35 @@ class ComputationGraph:
             masks = {out_names[0]: jnp.asarray(ds.labels_mask.value)}
         return inputs, labels, masks
 
-    # --- training --------------------------------------------------------
-    def _step_core(self):
-        """Single train-step computation, shared by the per-step jit and
-        the multi-step lax.scan dispatch (see multilayer._step_core)."""
-        gc = self.conf.global_conf
-        updater = gc.updater
-        tele = self._telemetry
-        from ..learning import precision as _prec
-        from ..optimize import telemetry as _tel
-        from .multilayer import _normalize_gradients
+    # --- training (the step and the loop are nn.train_step's) -------------
+    def _keyed_layers(self):
+        return [(name, self.conf.nodes[name].layer) for name in self._params]
 
-        def core(params, states, upd_state, inputs, labels, masks, key,
-                 iteration, w):
-            def loss_fn(p):
-                loss, new_states = self._loss(p, states, inputs, labels, masks,
-                                              True, key, w=w)
-                return loss, new_states
-
-            (loss, new_states), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
-            if gc.grad_normalization:
-                grads = _normalize_gradients(
-                    grads, gc.grad_normalization, gc.grad_norm_threshold)
-            OpProfiler.get().gauge("precision/grads_flat_in_step", 0)
-            new_params, new_upd = _prec.apply_updater(
-                updater, grads, upd_state, params, iteration, key)
-            if tele is None:
-                return new_params, new_states, new_upd, loss
-            # per-node stats in sorted node-name order (telemetry.groups)
-            aux = _tel.layer_stats(params, new_params, grads, loss)
-            if tele.nan_guard:
-                aux, new_params, new_states, new_upd = _tel.apply_nan_guard(
-                    aux, new_params, params, new_states, states, new_upd,
-                    upd_state)
-            return new_params, new_states, new_upd, loss, aux
-
-        return core
+    def _loss_of(self, params, states, batch, key, *, training=True, w=None,
+                 w_denom=None):
+        """The loss of one batch ``(inputs, labels, masks)``."""
+        return self._loss(params, states, *batch, training, key, w=w,
+                          w_denom=w_denom)
 
     def _build_fit_step(self):
-        core = self._step_core()
-
-        def step(params, states, upd_state, inputs, labels, masks, key,
-                 iteration, w=None):
-            OpProfiler.get().count("trace/graph_fit_step")
-            return core(params, states, upd_state, inputs, labels, masks,
-                        key, iteration, w)
-
+        step = step_program(make_core(self, self._telemetry),
+                            "trace/graph_fit_step", batch_len=3)
         return xprof.register_jit(
             "graph/fit_step", jax.jit(step, donate_argnums=(0, 1, 2)),
             donate=(0, 1, 2))
 
     def _build_chunk_step(self):
-        """steps_per_dispatch=K device loop (see multilayer)."""
-        core = self._step_core()
-        tele = self._telemetry
-
-        def chunk(params, states, upd_state, inputs, labels, masks, keys,
-                  iteration0, ws):
-            OpProfiler.get().count("trace/graph_fit_chunk")
-
-            def body(carry, inp):
-                params, states, upd_state, it = carry
-                ins, lbl, msk, k, w = inp
-                out = core(params, states, upd_state, ins, lbl, msk, k, it, w)
-                if tele is None:
-                    params, states, upd_state, loss = out
-                    return (params, states, upd_state, it + 1), loss
-                params, states, upd_state, loss, aux = out
-                return (params, states, upd_state, it + 1), (loss, aux)
-
-            (params, states, upd_state, _), ys_out = jax.lax.scan(
-                body, (params, states, upd_state, iteration0),
-                (inputs, labels, masks, keys, ws))
-            if tele is None:
-                return params, states, upd_state, ys_out
-            losses, auxes = ys_out
-            return params, states, upd_state, losses, auxes
-
+        chunk = chunk_program(make_core(self, self._telemetry),
+                              "trace/graph_fit_chunk")
         return xprof.register_jit(
             "graph/fit_chunk", jax.jit(chunk, donate_argnums=(0, 1, 2)),
             donate=(0, 1, 2))
-
-    def fit(self, data, epochs: int = 1, batch_size: Optional[int] = None,
-            *, pad_partial: Optional[bool] = None,
-            drop_remainder: bool = False, prefetch: int = 2,
-            steps_per_dispatch: int = 1, host_prefetch: int = 0,
-            resume_from: Optional[str] = None) -> None:
-        """Training loop on the shared input/dispatch pipeline
-        (data/pipeline.py): shape-stable padded batching with the example
-        weight threaded into every output's loss, device placement issued
-        ``prefetch`` batches ahead, and an opt-in ``steps_per_dispatch``
-        lax.scan device loop. See MultiLayerNetwork.fit for knob docs,
-        including ``resume_from`` (exact checkpoint resume)."""
-        self._check_init()
-        from ..learning.precision import note_state_bytes
-
-        prof = OpProfiler.get()
-        self._fit_calls += 1
-        with prof.time_section("fit/enter", call=self._fit_calls):
-            skip = self._begin_fit(resume_from)
-            if self._updater_state is None:
-                self._updater_state = self.conf.global_conf.updater.init(
-                    self._params)
-            note_state_bytes(self._updater_state)
-            if self._fit_step is None:
-                self._fit_step = self._build_fit_step()
-        if isinstance(data, (DataSet, MultiDataSet)) and batch_size is None:
-            self._fit_serial(data, epochs, skip=skip)
-            return
-        if steps_per_dispatch > 1 and self._chunk_step is None:
-            self._chunk_step = self._build_chunk_step()
-
-        def on_epoch():
-            self._epoch += 1
-            self._steps_in_epoch = 0
-            for lst in self._listeners:
-                if hasattr(lst, "epoch_done"):
-                    lst.epoch_done(self, self._epoch)
-
-        _pipe.run_epochs(
-            data, epochs, batch_size,
-            pad_partial=True if pad_partial is None else pad_partial,
-            drop_remainder=drop_remainder, prefetch=prefetch,
-            steps_per_dispatch=steps_per_dispatch,
-            bind=lambda ds, w: self._bind_fit_batch(ds, w),
-            place=jax.device_put,
-            dispatch_one=lambda b: self._dispatch_one(b, prof),
-            dispatch_chunk=lambda g: self._dispatch_chunk(g, prof),
-            stackable=_chunk_stackable, on_epoch=on_epoch,
-            allow_multi=True, host_prefetch=host_prefetch, skip=skip,
-            first_step=self._iteration)
-
-    def _begin_fit(self, resume_from: Optional[str]):
-        from ..util.checkpoint import begin_fit_cursor
-
-        return begin_fit_cursor(self, resume_from,
-                                listeners=self._listeners)
-
-    def _dispatch_one(self, b, prof) -> None:
-        inputs, labels, masks, w = b
-        key = get_random().next_key()
-        with prof.time_section("pipeline/dispatch", step=self._iteration):
-            out = self._fit_step(self._params, self._states,
-                                 self._updater_state, inputs, labels, masks,
-                                 key, jnp.asarray(self._iteration), w)
-        _pipe.note_dispatch(self, self._listeners, out,
-                            self._telemetry is not None)
-
-    def _dispatch_chunk(self, group, prof) -> None:
-        stack = lambda col: jax.tree.map(  # noqa: E731
-            lambda *leaves: jnp.stack(leaves), *[b[col] for b in group])
-        inputs, labels, masks = stack(0), stack(1), stack(2)
-        ws = jnp.stack([b[3] for b in group])
-        keys = jnp.stack([get_random().next_key() for _ in group])
-        with prof.time_section("pipeline/dispatch", step=self._iteration,
-                               steps=len(group)):
-            out = self._chunk_step(self._params, self._states,
-                                   self._updater_state, inputs, labels, masks,
-                                   keys, jnp.asarray(self._iteration), ws)
-        _pipe.note_dispatch(self, self._listeners, out,
-                            self._telemetry is not None, len(group))
-
-    def _fit_serial(self, data, epochs: int = 1, skip=None) -> None:
-        skip_epochs, skip_steps = skip if skip is not None else (0, 0)
-        for e in range(max(1, epochs)):
-            if e < skip_epochs:
-                for _ in _iter_graph_data(data):
-                    pass
-                continue
-            to_skip = skip_steps if e == skip_epochs else 0
-            for ds in _iter_graph_data(data):
-                if to_skip:
-                    to_skip -= 1
-                    continue
-                inputs, labels, masks = self._bind_dataset(ds)
-                key = get_random().next_key()
-                out = self._fit_step(self._params, self._states,
-                                     self._updater_state, inputs, labels,
-                                     masks, key,
-                                     jnp.asarray(self._iteration))
-                _pipe.note_dispatch(self, self._listeners, out,
-                                    self._telemetry is not None)
-            self._epoch += 1
-            self._steps_in_epoch = 0
-            for lst in self._listeners:
-                if hasattr(lst, "epoch_done"):
-                    lst.epoch_done(self, self._epoch)
 
     def evaluate(self, data):
         from ..eval.evaluation import Evaluation
 
         ev = Evaluation()
-        for ds in _iter_graph_data(data):
+        for ds in self._iter_data(data):
             if isinstance(ds, MultiDataSet):
                 out = self.output(*[f for f in ds.features])[0]
                 ev.eval(ds.labels[0].to_numpy(), out.to_numpy())
@@ -981,11 +735,6 @@ class ComputationGraph:
         return ev
 
     # --- persistence ------------------------------------------------------
-    def save(self, path: str, save_updater: bool = False) -> None:
-        from ..util.model_serializer import write_model
-
-        write_model(self, path, save_updater)
-
     @staticmethod
     def load(path: str, load_updater: bool = False) -> "ComputationGraph":
         from ..util.model_serializer import restore_computation_graph
@@ -1006,9 +755,6 @@ class ComputationGraph:
         lines.append(f"Total params: {total}")
         return "\n".join(lines)
 
-    def _check_init(self):
-        if not self._initialized:
-            raise ValueError("call init() first")
 
 
 def _fused_head_score(layer, head, labels, mask, w, w_denom):
@@ -1026,21 +772,3 @@ def _fused_head_score(layer, head, labels, mask, w, w_denom):
         per_token = per_token * w.astype(jnp.float32)[:, None]
         denom = w_denom if w_denom is not None else jnp.maximum(jnp.sum(w), 1.0)
     return layer.fused_score(head.params, head.x, labels, per_token) / denom
-
-
-def _chunk_stackable(group) -> bool:
-    """Stacking precondition for multi-step dispatch: every batch in the
-    chunk binds the same dict keys with the same array shapes."""
-    def sig(b):
-        def d(m):
-            return tuple(sorted((k, tuple(v.shape)) for k, v in m.items()))
-
-        return d(b[0]), d(b[1]), d(b[2]), tuple(b[3].shape)
-
-    first = sig(group[0])
-    return all(sig(b) == first for b in group[1:])
-
-
-def _iter_graph_data(data):
-    # one data protocol for serial and pipelined paths alike
-    yield from _pipe.iter_datasets(data, None, allow_multi=True)

@@ -11,9 +11,8 @@ with donated buffers, executed once per minibatch (SURVEY.md §7.1.1).
 
 from __future__ import annotations
 
-import dataclasses
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -27,35 +26,20 @@ from ..ndarray.ndarray import NDArray
 from ..ndarray.rng import get_random
 from .conf.builder import MultiLayerConfiguration, remat_wrap
 from .conf import layers as L
+from .train_step import (FitLoop, _fold_weights, chunk_program, finish,
+                         make_core, step_program, update)
 
 
-class MultiLayerNetwork:
+class MultiLayerNetwork(FitLoop):
+    _one_batch = (DataSet, tuple)
+
     def __init__(self, conf: MultiLayerConfiguration):
-        self.conf = conf
+        super().__init__(conf)
         self.layers = conf.layers
         self._params: List[Dict[str, jnp.ndarray]] = []
         self._states: List[Dict[str, jnp.ndarray]] = []
-        self._updater_state = None
-        self._initialized = False
-        self._iteration = 0
-        self._epoch = 0
-        self._fit_calls = 0
-        self._listeners: List[Any] = []
-        self._telemetry = None
-        self._fit_step = None
-        self._chunk_step = None
         self._tbptt_step = None
-        self._infer_fn = None
-        self._score_dev = None
         self._rnn_state_map = None
-
-    @property
-    def score_value(self) -> float:
-        return float(self._score_dev) if self._score_dev is not None else float("nan")
-
-    @score_value.setter
-    def score_value(self, v) -> None:
-        self._score_dev = v
 
     # ------------------------------------------------------------------
     def init(self, seed: Optional[int] = None) -> "MultiLayerNetwork":
@@ -75,52 +59,13 @@ class MultiLayerNetwork:
         self._initialized = True
         return self
 
-    def set_listeners(self, *listeners) -> None:
-        self._listeners = list(listeners)
-        for lst in self._listeners:
-            # checkpoint-style listeners snapshot their peers' state
-            # (state_dict protocol) for exact resume
-            bind = getattr(lst, "bind_group", None)
-            if callable(bind):
-                bind(self._listeners)
-        from ..optimize.telemetry import config_for
-
-        cfg = config_for(self._listeners)
-        if cfg != self._telemetry:
-            # telemetry is a build-time property of the jitted step: the
-            # aux pytree is computed IN-GRAPH, so flipping it rebuilds the
-            # step exactly once (trace/<step> stays 1 per fit config) and
-            # adds zero per-iteration host syncs
-            self._telemetry = cfg
-            self._fit_step = None
-            self._chunk_step = None
-            self._tbptt_step = None
-
-    setListeners = set_listeners
-
-    def set_remat_policy(self, policy) -> None:
-        """Switch the rematerialization policy in place. Like telemetry,
-        the policy is a build-time property of the jitted step: flipping
-        it rebuilds the step exactly ONCE on the next fit (one trace/
-        compile), after which the loop is steady again — asserted by
-        tests/test_remat_policies.py under tracecheck."""
-        if policy == self.conf.global_conf.remat_policy:
-            return
-        self.conf.global_conf.remat_policy = policy
-        self._fit_step = None
-        self._chunk_step = None
+    def _drop_steps(self) -> None:
+        super()._drop_steps()
         self._tbptt_step = None
 
+    setListeners = FitLoop.set_listeners
+
     # --- parameter access (flattened, reference params() contract) ------
-    def params(self) -> NDArray:
-        leaves = jax.tree.leaves(self._params)
-        if not leaves:
-            return NDArray(jnp.zeros((0,)))
-        return NDArray(jnp.concatenate([l.ravel() for l in leaves]))
-
-    def num_params(self) -> int:
-        return sum(int(np.prod(l.shape)) for l in jax.tree.leaves(self._params))
-
     def set_params(self, flat: Union[NDArray, np.ndarray]) -> None:
         vec = jnp.asarray(flat.value if isinstance(flat, NDArray) else flat)
         leaves, treedef = jax.tree.flatten(self._params)
@@ -132,8 +77,7 @@ class MultiLayerNetwork:
         if off != vec.size:
             raise ValueError(f"param vector length {vec.size} != model params {off}")
         self._params = jax.tree.unflatten(treedef, out)
-        self._fit_step = None  # donated buffers were replaced
-        self._chunk_step = None
+        self._drop_steps()  # donated buffers were replaced
 
     def param_table(self, layer_idx: int) -> Dict[str, NDArray]:
         return {k: NDArray(v) for k, v in self._params[layer_idx].items()}
@@ -349,398 +293,90 @@ class MultiLayerNetwork:
             return data_loss + reg, (new_states, new_rnn)
         return data_loss + reg, new_states
 
-    def score(self, dataset: DataSet, training: bool = False) -> float:
-        self._check_init()
-        x = jnp.asarray(dataset.features.value)
-        y = jnp.asarray(dataset.labels.value)
-        mask = jnp.asarray(dataset.labels_mask.value) if dataset.labels_mask is not None else None
-        fmask = (jnp.asarray(dataset.features_mask.value)
-                 if dataset.features_mask is not None else None)
-        loss, _ = self._loss(self._params, self._states, x, y, mask, training,
-                             get_random().next_key(), fmask)
-        return float(loss)
+    # --- training (the step and the loop are nn.train_step's) -------------
+    def _keyed_layers(self):
+        return enumerate(self.layers)
 
-    def compute_gradient_and_score(self, dataset: DataSet):
-        """(gradients, score) — the GradientCheckUtil entry point."""
-        self._check_init()
-        x = jnp.asarray(dataset.features.value)
-        y = jnp.asarray(dataset.labels.value)
-        mask = jnp.asarray(dataset.labels_mask.value) if dataset.labels_mask is not None else None
-        fmask = (jnp.asarray(dataset.features_mask.value)
-                 if dataset.features_mask is not None else None)
-        key = jax.random.PRNGKey(0)
+    def _bind(self, ds: DataSet):
+        """DataSet → the batch ``(x, y, mask, fmask)``."""
+        return (jnp.asarray(ds.features.value),
+                jnp.asarray(ds.labels.value),
+                jnp.asarray(ds.labels_mask.value)
+                if ds.labels_mask is not None else None,
+                jnp.asarray(ds.features_mask.value)
+                if ds.features_mask is not None else None)
 
-        def loss_fn(params):
-            loss, _ = self._loss(params, self._states, x, y, mask, False, key,
-                                 fmask)
-            return loss
-
-        loss, grads = jax.value_and_grad(loss_fn)(self._params)
-        self.score_value = float(loss)
-        return grads, self.score_value
-
-    # --- training --------------------------------------------------------
-    def _frozen_indices(self):
-        return [i for i, l in enumerate(self.layers)
-                if isinstance(l, L.FrozenLayer)]
-
-    def _step_core(self):
-        """The single train-step computation, shared verbatim by the
-        per-step jit and the multi-step ``lax.scan`` dispatch so the two
-        paths cannot drift numerically. When telemetry is enabled the core
-        additionally returns the in-graph aux pytree (per-layer grad/
-        update/param norms, update:param ratio, non-finite counts — see
-        optimize.telemetry) computed inside the same compiled module.
-
-        ``hyper`` (keyword-only, default None — the solo paths never pass
-        it): a dict of TRACED per-call scalar hyperparameter overrides,
-        the vmapped-fleet sweep hook (parallel.fleet). Recognized keys:
-        ``lr`` replaces the updater's learning rate, ``l2`` replaces
-        every layer's effective l2 (an additive delta on the loss under
-        the same exclusions the base regularization applies), and
-        ``dropout`` replaces the rate of every layer whose input dropout
-        is configured on. Scalars must be float64 (weak-Python-float
-        matching under x64) so an override equal to the baked value is
-        bitwise identical to the solo step."""
-        gc = self.conf.global_conf
-        updater = gc.updater
-        frozen = self._frozen_indices()
-        tele = self._telemetry
-        from ..learning import precision as _prec
-        from ..optimize import telemetry as _tel
-
-        def core(params, states, upd_state, x, y, mask, key, iteration,
-                 fmask, w, hyper=None):
-            hp = {k: _weak_scalar(v) for k, v in (hyper or {}).items()}
-            up = (dataclasses.replace(updater, learning_rate=hp["lr"])
-                  if "lr" in hp else updater)
-
-            def loss_fn(p):
-                if "dropout" in hp:
-                    with L.dropout_rate_override(hp["dropout"]):
-                        loss, new_states = self._loss(p, states, x, y,
-                                                      mask, True, key,
-                                                      fmask, w=w)
-                else:
-                    loss, new_states = self._loss(p, states, x, y, mask,
-                                                  True, key, fmask, w=w)
-                if "l2" in hp:
-                    loss = loss + _l2_delta(self.conf, self.layers, p,
-                                            hp["l2"])
-                return loss, new_states
-
-            (loss, new_states), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
-            if gc.grad_normalization:
-                grads = _normalize_gradients(
-                    grads, gc.grad_normalization, gc.grad_norm_threshold)
-            OpProfiler.get().gauge("precision/grads_flat_in_step", 0)
-            new_params, new_upd = _prec.apply_updater(
-                up, grads, upd_state, params, iteration, key)
-            for i in frozen:
-                # stop_gradient already zeroes their grads; restoring the
-                # original tensors also shields them from stateful-updater
-                # side effects (weight decay, momentum drift)
-                new_params[i] = params[i]
-            new_params = self._apply_constraints(new_params)
-            if tele is None:
-                return new_params, new_states, new_upd, loss
-            aux = _tel.layer_stats(params, new_params, grads, loss)
-            if tele.nan_guard:
-                aux, new_params, new_states, new_upd = _tel.apply_nan_guard(
-                    aux, new_params, params, new_states, states, new_upd,
-                    upd_state)
-            return new_params, new_states, new_upd, loss, aux
-
-        return core
+    def _loss_of(self, params, states, batch, key, *, training=True, w=None,
+                 w_denom=None, rnn_states=None, l2=None):
+        """The loss of one batch. ``rnn_states`` (TBPTT) makes the aux
+        ``(new_states, new_rnn)``; ``l2`` is the fleet's traced override
+        of every layer's effective l2."""
+        x, y, mask, fmask = batch
+        loss, aux = self._loss(params, states, x, y, mask, training, key,
+                               fmask, rnn_states, w, w_denom)
+        if l2 is not None:
+            loss = loss + _l2_delta(self.conf, self.layers, params, l2)
+        return loss, aux
 
     def _build_fit_step(self):
-        core = self._step_core()
-
-        def step(params, states, upd_state, x, y, mask, key, iteration,
-                 fmask=None, w=None):
-            OpProfiler.get().count("trace/mln_fit_step")
-            return core(params, states, upd_state, x, y, mask, key,
-                        iteration, fmask, w)
-
+        step = step_program(make_core(self, self._telemetry),
+                            "trace/mln_fit_step", batch_len=4)
         return xprof.register_jit(
             "mln/fit_step", jax.jit(step, donate_argnums=(0, 1, 2)),
             donate=(0, 1, 2))
 
     def _build_chunk_step(self):
-        """Multi-step dispatch (``steps_per_dispatch=K``): one jitted
-        module runs K minibatches through a ``lax.scan`` device loop over
-        the stacked chunk — Python dispatch, listener sync, and H2D fencing
-        amortize over K steps."""
-        core = self._step_core()
-        tele = self._telemetry
-
-        def chunk(params, states, upd_state, xs, ys, masks, keys,
-                  iteration0, fmasks=None, ws=None):
-            OpProfiler.get().count("trace/mln_fit_chunk")
-
-            def body(carry, inp):
-                params, states, upd_state, it = carry
-                x, y, m, k, fm, w = inp
-                out = core(params, states, upd_state, x, y, m, k, it, fm, w)
-                if tele is None:
-                    params, states, upd_state, loss = out
-                    return (params, states, upd_state, it + 1), loss
-                params, states, upd_state, loss, aux = out
-                # aux rides the scan's stacked outputs: [K, ...] per leaf
-                return (params, states, upd_state, it + 1), (loss, aux)
-
-            (params, states, upd_state, _), ys_out = jax.lax.scan(
-                body, (params, states, upd_state, iteration0),
-                (xs, ys, masks, keys, fmasks, ws))
-            if tele is None:
-                return params, states, upd_state, ys_out
-            losses, auxes = ys_out
-            return params, states, upd_state, losses, auxes
-
+        chunk = chunk_program(make_core(self, self._telemetry),
+                              "trace/mln_fit_chunk")
         return xprof.register_jit(
             "mln/fit_chunk", jax.jit(chunk, donate_argnums=(0, 1, 2)),
             donate=(0, 1, 2))
 
-    def _apply_constraints(self, params):
-        """Project weights after each update (reference BaseConstraint —
-        applied to weight params, biases/norm params excluded)."""
-        out = params
-        for i, layer in enumerate(self.layers):
-            cs = getattr(layer, "constraints", None)
-            if not cs:
-                continue
-            lp = dict(out[i])
-            for name, w in lp.items():
-                if name in ("b", "beta", "gamma", "mean", "var", "centers"):
-                    continue
-                for c in cs:
-                    w = c.apply(w)
-                lp[name] = w
-            out[i] = lp
-        return out
+    def _serial_only(self) -> bool:
+        # TBPTT has its own segment loop per batch
+        return self.conf.backprop_type == "TruncatedBPTT"
+
+    def _serial_step(self, batch, key) -> None:
+        if self._serial_only() and batch[0].ndim == 3:
+            loss, aux = self._fit_tbptt(*batch, key)
+            _pipe.note_steps(self, self._listeners, [loss],
+                             [aux] if aux is not None else None)
+        else:
+            super()._serial_step(batch, key)
 
     def _build_tbptt_step(self):
         """TBPTT segment step (reference: MultiLayerNetwork
         truncatedBPTTGradient / rnnActivateUsingStoredState): gradients flow
         within the segment only — the incoming recurrent carries are jit
         inputs, so backprop truncates at the segment boundary by
-        construction."""
-        gc = self.conf.global_conf
-        updater = gc.updater
-        frozen = self._frozen_indices()
+        construction. Its own differentiation (the carry rides the aux),
+        then the shared update epilogue and telemetry tail."""
+        updater = self.conf.global_conf.updater
         tele = self._telemetry
-        from ..learning import precision as _prec
-        from ..optimize import telemetry as _tel
 
         def step(params, states, upd_state, rnn_states, x, y, mask, key,
                  iteration, fmask=None):
             def loss_fn(p):
-                loss, aux = self._loss(p, states, x, y, mask, True, key,
-                                       fmask, rnn_states)
-                return loss, aux
+                return self._loss_of(p, states, (x, y, mask, fmask), key,
+                                     rnn_states=rnn_states)
 
-            (loss, (new_states, new_rnn)), grads =                 jax.value_and_grad(loss_fn, has_aux=True)(params)
-            if gc.grad_normalization:
-                grads = _normalize_gradients(grads, gc.grad_normalization,
-                                             gc.grad_norm_threshold)
-            new_params, new_upd = _prec.apply_updater(
-                updater, grads, upd_state, params, iteration, key)
-            for i in frozen:
-                new_params[i] = params[i]
-            new_params = self._apply_constraints(new_params)
-            if tele is None:
-                return new_params, new_states, new_upd, new_rnn, loss
-            aux = _tel.layer_stats(params, new_params, grads, loss)
-            if tele.nan_guard:
-                aux, new_params, new_states, new_upd = _tel.apply_nan_guard(
-                    aux, new_params, params, new_states, states, new_upd,
-                    upd_state)
+            (loss, (new_states, new_rnn)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params)
+            grads, new_params, new_upd = update(
+                self, updater, grads, upd_state, params, iteration, key)
+            out = finish(tele, loss, (params, states, upd_state),
+                         (new_params, new_states, new_upd), grads)
+            if tele is not None and tele.nan_guard:
                 # the recurrent carries of a skipped segment are poisoned
                 # too — restore them alongside the params
-                ok = aux["skipped"] == 0
+                ok = out[4]["skipped"] == 0
                 new_rnn = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
                                        new_rnn, rnn_states)
-            return new_params, new_states, new_upd, new_rnn, loss, aux
+            return (*out[:3], new_rnn, *out[3:])
 
         return xprof.register_jit(
             "mln/tbptt_step", jax.jit(step, donate_argnums=(0, 1, 2)),
             donate=(0, 1, 2))
-
-    def fit(self, data, epochs: int = 1, batch_size: Optional[int] = None,
-            *, pad_partial: Optional[bool] = None,
-            drop_remainder: bool = False, prefetch: int = 2,
-            steps_per_dispatch: int = 1, host_prefetch: int = 0,
-            resume_from: Optional[str] = None) -> None:
-        """The north-star loop (SURVEY.md §3.1): per minibatch, ONE compiled
-        train-step executes forward+backward+updater on device. The host
-        side runs the shared input/dispatch pipeline (data/pipeline.py):
-
-        - ``pad_partial`` (default on when a target batch size is known):
-          the final partial batch is padded to the configured batch size
-          with a zero example-weight mask threaded into the loss, so the
-          step compiles exactly ONCE per fit config instead of retracing
-          on the remainder shape; ``drop_remainder=True`` skips it instead.
-        - ``prefetch``: device placement of upcoming batches is issued this
-          many batches ahead of compute (double-buffered H2D overlap;
-          0 = serial feed).
-        - ``steps_per_dispatch=K``: run K minibatches per Python dispatch
-          through a ``lax.scan`` device loop, syncing loss/listeners once
-          per chunk.
-        - ``host_prefetch=N`` (opt-in): run batch assembly (slicing,
-          padding, array conversion) on a worker thread through an
-          N-deep queue. The default of 0 was chosen on a set-up that
-          is gone (worker-thread jax array creation serialized there);
-          not measured on this chip.
-
-        NOTE on padding numerics: the padded run is numerically identical
-        to the unpadded masked-loss run for per-example models (pinned
-        bit-for-bit in tests). Layers with CROSS-example statistics
-        (BatchNormalization) see the wrapped pad rows in their batch
-        mean/variance on the final partial batch — the same deliberate
-        policy ParallelWrapper has always used (in-distribution wrapped
-        rows beat zero rows); pass ``drop_remainder=True`` or
-        ``pad_partial=False`` if exact BN parity with the unpadded loop
-        matters more than trace stability.
-
-        ``resume_from`` (preemption recovery, SURVEY §5.3): path of a
-        checkpoint written by CheckpointListener. Restores params, layer
-        states, updater state, iteration/epoch counters, the RNG stream
-        key, and listener state, then fast-forwards the input pipeline to
-        the checkpoint's cursor — the resumed call must be given the SAME
-        data/epochs/batch arguments as the killed one, and its loss
-        sequence continues bit-identically (CPU, per-example models)
-        where the uninterrupted run would have gone.
-        """
-        self._check_init()
-        from ..learning.precision import note_state_bytes
-
-        prof = OpProfiler.get()
-        self._fit_calls += 1
-        with prof.time_section("fit/enter", call=self._fit_calls):
-            skip = self._begin_fit(resume_from)
-            if self._updater_state is None:
-                self._updater_state = self.conf.global_conf.updater.init(
-                    self._params)
-            note_state_bytes(self._updater_state)
-            if self._fit_step is None:
-                self._fit_step = self._build_fit_step()
-
-        tbptt = self.conf.backprop_type == "TruncatedBPTT"
-        # Single-DataSet/tuple calls with no batch size have one stable
-        # shape by construction (the bench hot loops); TBPTT has its own
-        # segment loop — both stay on the serial path.
-        if tbptt or (isinstance(data, (DataSet, tuple))
-                     and batch_size is None):
-            self._fit_serial(data, epochs, batch_size, skip=skip)
-            return
-        if steps_per_dispatch > 1 and self._chunk_step is None:
-            self._chunk_step = self._build_chunk_step()
-
-        def on_epoch():
-            self._epoch += 1
-            self._steps_in_epoch = 0
-            for lst in self._listeners:
-                if hasattr(lst, "epoch_done"):
-                    lst.epoch_done(self, self._epoch)
-
-        _pipe.run_epochs(
-            data, epochs, batch_size,
-            pad_partial=True if pad_partial is None else pad_partial,
-            drop_remainder=drop_remainder, prefetch=prefetch,
-            steps_per_dispatch=steps_per_dispatch,
-            bind=self._bind_batch, place=jax.device_put,
-            dispatch_one=lambda b: self._dispatch_one(b, prof),
-            dispatch_chunk=lambda g: self._dispatch_chunk(g, prof),
-            stackable=_same_shapes, on_epoch=on_epoch,
-            host_prefetch=host_prefetch, skip=skip,
-            first_step=self._iteration)
-
-    def _begin_fit(self, resume_from: Optional[str]):
-        from ..util.checkpoint import begin_fit_cursor
-
-        return begin_fit_cursor(self, resume_from,
-                                listeners=self._listeners)
-
-    def _bind_batch(self, ds: DataSet, w):
-        """DataSet → the jit argument tuple (x, y, mask, fmask, w)."""
-        # PerformanceListener derives samples/sec from this
-        self._last_batch_size = ds.num_examples()
-        return (jnp.asarray(ds.features.value),
-                jnp.asarray(ds.labels.value),
-                jnp.asarray(ds.labels_mask.value)
-                if ds.labels_mask is not None else None,
-                jnp.asarray(ds.features_mask.value)
-                if ds.features_mask is not None else None,
-                w)
-
-    def _dispatch_one(self, b, prof) -> None:
-        x, y, mask, fmask, w = b
-        key = get_random().next_key()
-        with prof.time_section("pipeline/dispatch", step=self._iteration):
-            out = self._fit_step(self._params, self._states,
-                                 self._updater_state, x, y, mask, key,
-                                 jnp.asarray(self._iteration), fmask, w)
-        _pipe.note_dispatch(self, self._listeners, out,
-                            self._telemetry is not None)
-
-    def _dispatch_chunk(self, group, prof) -> None:
-        xs, ys, masks, fmasks, ws = _stack_batches(group)
-        # keys drawn in batch order — the chunked loop consumes the SAME
-        # rng stream the per-step loop would
-        keys = jnp.stack([get_random().next_key() for _ in group])
-        with prof.time_section("pipeline/dispatch", step=self._iteration,
-                               steps=len(group)):
-            out = self._chunk_step(self._params, self._states,
-                                   self._updater_state, xs, ys, masks,
-                                   keys, jnp.asarray(self._iteration),
-                                   fmasks, ws)
-        _pipe.note_dispatch(self, self._listeners, out,
-                            self._telemetry is not None, len(group))
-
-    def _fit_serial(self, data, epochs: int = 1,
-                    batch_size: Optional[int] = None, skip=None) -> None:
-        tbptt = self.conf.backprop_type == "TruncatedBPTT"
-        skip_epochs, skip_steps = skip if skip is not None else (0, 0)
-        for e in range(max(1, epochs)):
-            if e < skip_epochs:
-                # resume fast-forward: consume (advances iterator state),
-                # dispatch nothing; on_epoch effects are already in the
-                # restored checkpoint
-                for _ in _iter_data(data, batch_size):
-                    pass
-                continue
-            to_skip = skip_steps if e == skip_epochs else 0
-            for ds in _iter_data(data, batch_size):
-                if to_skip:
-                    to_skip -= 1
-                    continue
-                x = jnp.asarray(ds.features.value)
-                y = jnp.asarray(ds.labels.value)
-                mask = (jnp.asarray(ds.labels_mask.value)
-                        if ds.labels_mask is not None else None)
-                fmask = (jnp.asarray(ds.features_mask.value)
-                         if ds.features_mask is not None else None)
-                key = get_random().next_key()
-                # device scalars throughout; float() only on access (avoids
-                # per-step sync). Listeners get the device values too and
-                # sync only at their own print/collect/drain boundaries.
-                if tbptt and x.ndim == 3:
-                    loss, aux = self._fit_tbptt(x, y, mask, fmask, key)
-                    _pipe.note_steps(self, self._listeners, [loss],
-                                     [aux] if aux is not None else None)
-                else:
-                    out = self._fit_step(self._params, self._states,
-                                         self._updater_state, x, y, mask,
-                                         key, jnp.asarray(self._iteration),
-                                         fmask)
-                    _pipe.note_dispatch(self, self._listeners, out,
-                                        self._telemetry is not None)
-            self._epoch += 1
-            self._steps_in_epoch = 0
-            for lst in self._listeners:
-                if hasattr(lst, "epoch_done"):
-                    lst.epoch_done(self, self._epoch)
 
     def pretrain(self, data, epochs: int = 1) -> None:
         """Layerwise unsupervised pretraining (reference:
@@ -786,7 +422,7 @@ class MultiLayerNetwork:
             upd_state = updater.init(lp)
             it = 0
             for _ in range(max(1, epochs)):
-                for ds in _iter_data(data, None):
+                for ds in self._iter_data(data):
                     x = jnp.asarray(ds.features.value)
                     lp, upd_state, loss = step(
                         lp, upd_state, self._params, x,
@@ -794,8 +430,7 @@ class MultiLayerNetwork:
                     it += 1
                     self._score_dev = loss
             self._params[idx] = lp
-            self._fit_step = None
-            self._chunk_step = None
+            self._drop_steps()
             self._infer_fn = None
 
     def _fit_tbptt(self, x, y, mask, fmask, key):
@@ -883,7 +518,7 @@ class MultiLayerNetwork:
         from ..eval.evaluation import Evaluation
 
         ev = Evaluation()
-        for ds in _iter_data(data, batch_size):
+        for ds in self._iter_data(data, batch_size):
             out = self.output(ds.features, fmask=ds.features_mask)
             ev.eval(ds.labels.to_numpy(), out.to_numpy(),
                     ds.labels_mask.to_numpy() if ds.labels_mask is not None else None)
@@ -893,17 +528,12 @@ class MultiLayerNetwork:
         from ..eval.evaluation import RegressionEvaluation
 
         ev = RegressionEvaluation()
-        for ds in _iter_data(data, batch_size):
+        for ds in self._iter_data(data, batch_size):
             out = self.output(ds.features)
             ev.eval(ds.labels.to_numpy(), out.to_numpy())
         return ev
 
     # --- persistence ------------------------------------------------------
-    def save(self, path: str, save_updater: bool = False) -> None:
-        from ..util.model_serializer import write_model
-
-        write_model(self, path, save_updater)
-
     @staticmethod
     def load(path: str, load_updater: bool = False) -> "MultiLayerNetwork":
         from ..util.model_serializer import restore_multi_layer_network
@@ -930,10 +560,6 @@ class MultiLayerNetwork:
     def n_layers(self) -> int:
         return len(self.layers)
 
-    def _check_init(self) -> None:
-        if not self._initialized:
-            raise ValueError("call init() first")
-
     def clone(self) -> "MultiLayerNetwork":
         import copy
 
@@ -945,22 +571,6 @@ class MultiLayerNetwork:
         net._params = jax.tree.map(jnp.array, self._params)
         net._states = jax.tree.map(jnp.array, self._states)
         return net
-
-
-def _weak_scalar(v):
-    """Re-weak-type a traced f64 hyperparameter scalar so it promotes
-    EXACTLY like the Python float it overrides (a strong f64 tracer
-    would widen f32 updater math to f64 — a different computation, not
-    just different bits). Uses jax's internal weak-type convert — the
-    same mechanism jnp uses for Python scalars; if the private API moves,
-    the override still works strong-typed with ulp-level (documented)
-    deviation from the baked-constant run."""
-    try:
-        from jax._src.lax.lax import _convert_element_type
-
-        return _convert_element_type(v, jnp.dtype(jnp.float64), weak_type=True)
-    except (ImportError, TypeError):    # pragma: no cover - jax internals
-        return v
 
 
 def _l2_delta(conf, layers, params, l2_m):
@@ -983,59 +593,3 @@ def _l2_delta(conf, layers, params, l2_m):
                 continue
             delta = delta + (0.5 * (l2_m - base)) * jnp.sum(jnp.square(wt))
     return delta
-
-
-def _fold_weights(mask, w):
-    """Fold per-example weights ``w`` [B] into an (optional) loss mask —
-    the padded-batch contract: pad rows carry w=0, so their per-element
-    loss terms multiply to exactly 0.0."""
-    if mask is None:
-        return w
-    wb = w
-    while wb.ndim < mask.ndim:
-        wb = wb[..., None]
-    return mask * wb
-
-
-def _same_shapes(group) -> bool:
-    """True when every batch tuple in the chunk has identical array shapes
-    (None members must agree too) — the stacking precondition."""
-    def sig(b):
-        return tuple(None if a is None else tuple(a.shape) for a in b)
-
-    first = sig(group[0])
-    return all(sig(b) == first for b in group[1:])
-
-
-def _stack_batches(group):
-    """Stack K batch tuples [(x, y, mask, fmask, w), ...] along a new
-    leading axis for the scan device loop; None columns stay None."""
-    def col(i):
-        if group[0][i] is None:
-            return None
-        return jnp.stack([b[i] for b in group])
-
-    return col(0), col(1), col(2), col(3), col(4)
-
-
-def _normalize_gradients(grads, mode: str, threshold: float):
-    mode = mode.lower()
-    if mode == "clipelementwiseabsolutevalue":
-        return jax.tree.map(lambda g: jnp.clip(g, -threshold, threshold), grads)
-    if mode == "clipl2pergradient":
-        def clip(g):
-            n = jnp.sqrt(jnp.sum(jnp.square(g)))
-            return jnp.where(n > threshold, g * (threshold / n), g)
-
-        return jax.tree.map(clip, grads)
-    if mode == "clipl2perparamtype" or mode == "renormalizel2perlayer":
-        leaves = jax.tree.leaves(grads)
-        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g)) for g in leaves))
-        scale = jnp.minimum(1.0, threshold / jnp.maximum(gnorm, 1e-12))
-        return jax.tree.map(lambda g: g * scale, grads)
-    raise ValueError(f"unknown gradient normalization {mode!r}")
-
-
-def _iter_data(data, batch_size):
-    # one data protocol for serial and pipelined paths alike
-    yield from _pipe.iter_datasets(data, batch_size)

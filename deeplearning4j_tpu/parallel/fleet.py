@@ -17,8 +17,8 @@ The load-bearing contracts:
   ``MultiLayerNetwork.init(member_seeds[k])`` exactly, the per-member
   stream key is carried IN-GRAPH and split exactly like the solo fit
   path splits its host ``Random`` (``new_key, sub = split(key)`` per
-  step), and the step body IS the solo ``_step_core`` — vmapped, never
-  reimplemented. ``solo_twin(k)`` builds the comparator.
+  step), and the step body IS the solo ``train_step.make_core`` — vmapped,
+  never reimplemented. ``solo_twin(k)`` builds the comparator.
 - **One compile, ever.** Telemetry, per-member hyperparameters, cull,
   spawn, and NaN isolation are all shape-stable data: the alive mask and
   hyper scalars are traced inputs, cull/spawn rewrite state slices with
@@ -70,6 +70,7 @@ import numpy as np
 from ..common import flightrec, xprof
 from ..common.profiler import OpProfiler
 from ..data import pipeline as _pipe
+from ..nn.train_step import make_core
 from ..optimize.telemetry import config_for
 
 logger = logging.getLogger("deeplearning4j_tpu")
@@ -206,8 +207,8 @@ class FleetTrainer:
     """Train M stacked same-architecture members through one vmapped,
     jitted step. ``model`` is the architecture template (an init()-ed
     ``MultiLayerNetwork``); the trainer owns it for tracing — its layer
-    pure functions and ``_step_core`` ARE the member step, so fleet
-    numerics can never drift from solo numerics.
+    pure functions and ``train_step.make_core`` ARE the member step, so
+    fleet numerics can never drift from solo numerics.
 
     Thread-shared by registry (graftlint SHARED_CLASSES): the training
     thread mutates carried state while sinks/serving read exports —
@@ -354,23 +355,15 @@ class FleetTrainer:
     # -- the one compiled step --------------------------------------------
     def _build_fleet_step(self):
         # the member body IS the solo step core (parity by construction);
-        # telemetry is a build-time property exactly as in the solo paths.
-        # The template's own telemetry flag is restored after the build —
-        # _step_core reads it at build time only — so a later SOLO fit of
-        # the template still carries its own listener-implied config.
-        prev = self.model._telemetry
-        self.model._telemetry = self._tele
-        try:
-            core = self.model._step_core()
-        finally:
-            self.model._telemetry = prev
+        # telemetry is a build-time property exactly as in the solo paths
         tele = self._tele
+        core = make_core(self.model, tele)
         member_cull = bool(tele and tele.member_cull)
         with_hyper = self._hyper is not None
 
         def member(p, s, u, key, x_m, y_m, hyp, it):
             new_key, sub = jax.random.split(key)
-            out = core(p, s, u, x_m, y_m, None, sub, it, None, None,
+            out = core(p, s, u, (x_m, y_m, None, None), sub, it, None,
                        hyper=hyp)
             if tele is None:
                 new_p, new_s, new_u, loss = out

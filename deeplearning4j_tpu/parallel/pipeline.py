@@ -1077,14 +1077,10 @@ class PipelineTrainer:
 
     # --- fit surface -----------------------------------------------------
     def set_listeners(self, *ls) -> None:
-        self._listeners = list(ls)
-        for lst in self._listeners:
-            bind = getattr(lst, "bind_group", None)
-            if callable(bind):
-                bind(self._listeners)
-        from ..optimize.telemetry import config_for
+        from ..nn.train_step import group_listeners
 
-        cfg = config_for(self._listeners)
+        self._listeners = list(ls)
+        cfg = group_listeners(self._listeners)
         if cfg != self._telemetry:
             # in-graph telemetry is a build-time property of the step —
             # drop every cached executable (meta/mesh/partition stay)
@@ -1160,7 +1156,7 @@ class PipelineTrainer:
     def fit(self, data, epochs: int = 1, batch_size: Optional[int] = None,
             *, pad_partial: Optional[bool] = None,
             drop_remainder: bool = False, prefetch: int = 2,
-            host_prefetch: int = 0, resume_from: Optional[str] = None,
+            resume_from: Optional[str] = None,
             resume_cursor: Optional[tuple] = None) -> None:
         """Pipeline-parallel training on the shared input/dispatch
         pipeline: batches pad to a multiple of data_axis × n_micro
@@ -1172,7 +1168,7 @@ class PipelineTrainer:
         steps_in_epoch)``: in-memory continuation from the holder's live
         state at a dispatch boundary (the supervisor's remap-and-continue
         path)."""
-        from ..nn.multilayer import _same_shapes
+        from ..nn.train_step import _same_shapes
         from ..util.checkpoint import begin_fit_cursor
         from ..data import pipeline as _pipe
         from .mesh import shard_batch
@@ -1220,8 +1216,7 @@ class PipelineTrainer:
             dispatch_one=lambda b: self._dispatch_one(b, prof),
             dispatch_chunk=lambda g: None,
             stackable=_same_shapes, on_epoch=on_epoch,
-            round_to_multiple_of=self.data_axis * self.n_micro,
-            host_prefetch=host_prefetch, skip=skip,
+            round_to_multiple_of=self.data_axis * self.n_micro, skip=skip,
             pre_dispatch=self._pre_dispatch)
 
     # --- elastic remap (shrink/grow the stage axis, no restart) ----------

@@ -36,9 +36,10 @@ from ..common.profiler import OpProfiler
 from ..data import pipeline as _pipe
 from ..data.dataset import DataSet
 from ..ndarray.rng import get_random
-from ..nn.multilayer import _same_shapes
+from ..nn.train_step import (_same_shapes, finish, group_listeners,
+                             needs_tree_update, update)
 from .accumulator import DenseAllReduceAccumulator, GradientsAccumulator
-from .mesh import elastic_pool, make_mesh, probe_device, shard_batch
+from .mesh import data_sharded, elastic_pool, make_mesh, probe_device
 from .sharding import Zero1Plan, is_flat_state
 
 logger = logging.getLogger("deeplearning4j_tpu")
@@ -126,18 +127,10 @@ class ParallelWrapper:
 
     def set_listeners(self, *ls) -> None:
         self._listeners = list(ls)
-        for lst in self._listeners:
-            # checkpoint-style listeners snapshot their peers' state for
-            # exact resume (see MultiLayerNetwork.set_listeners)
-            bind = getattr(lst, "bind_group", None)
-            if callable(bind):
-                bind(self._listeners)
-        from ..optimize.telemetry import config_for
-
-        cfg = config_for(self._listeners)
+        cfg = group_listeners(self._listeners)
         if cfg != self._telemetry:
             # in-graph telemetry is a build-time property of the SPMD step
-            # (see MultiLayerNetwork.set_listeners); the aux statistics are
+            # (see FitLoop.set_listeners); the aux statistics are
             # aggregated across shards with the same collectives as the
             # weight update
             self._telemetry = cfg
@@ -154,16 +147,23 @@ class ParallelWrapper:
         accumulator (see parallel/accumulator.py):
 
         - dense (default): pmean the grads, every replica applies the full
-          updater redundantly;
+          update epilogue (``nn.train_step.update``) redundantly;
         - encoded (``stateful``): threshold-encode with residual carry,
-          psum the encoded update, dense updater apply — the accumulator
-          state pytree threads through the step (and scan chunks);
+          psum the encoded update, the same dense epilogue — the
+          accumulator state pytree threads through the step (and scan
+          chunks);
         - ZeRO-1 (``zero1``): reduce-scatter the flat grads, apply the
           updater to this replica's 1/N flat slice against SHARDED updater
           state, all-gather the updated params. Bit-identical to dense on
           the same replica count: the flat layout is a pure permutation,
           the built-in updaters are elementwise, and psum_scatter's
-          accumulation order matches psum's.
+          accumulation order matches psum's. Flat shards cannot be
+          clipped by a tree's norm, projected or kept per layer, so a
+          configuration that asks for any of it is refused at build.
+
+        The differentiation is the wrapper's own — collectives stand
+        between the gradient and the update — over the model's
+        ``_loss_of``; the telemetry tail is ``nn.train_step.finish``.
         """
         model = self.model
         updater = model.conf.global_conf.updater
@@ -172,9 +172,7 @@ class ParallelWrapper:
         zero1 = acc.zero1
         stateful = acc.stateful
         plan = self._zero1_plan if zero1 else None
-        is_graph = hasattr(model, "conf") and hasattr(model.conf, "network_inputs")
         tele = self._telemetry
-        from ..learning import precision as _prec
         from ..ops import pallas_update as _pupd
         from ..optimize import telemetry as _tel
 
@@ -205,8 +203,8 @@ class ParallelWrapper:
                     and getattr(model.conf.global_conf, "flat_backward",
                                 True))
 
-        def local_step(params, states, upd_state, acc_state, x, y, mask, w,
-                       key, it):
+        def local_step(params, states, upd_state, acc_state, batch, w, key,
+                       it):
             idx = jax.lax.axis_index(axis)
             # the dense layouts apply the FULL update redundantly on every
             # replica: their stochastic-rounding draws must be the same
@@ -226,18 +224,8 @@ class ParallelWrapper:
             denom = jnp.maximum(real, 1.0) / n_shards
 
             def loss_fn(p):
-                if is_graph:
-                    inputs = {model.conf.network_inputs[0]: x}
-                    out_name = model.conf.network_outputs[0]
-                    loss, new_states = model._loss(p, states, inputs,
-                                                   {out_name: y}, {out_name: mask},
-                                                   True, key, w=w,
-                                                   w_denom=denom)
-                else:
-                    loss, new_states = model._loss(p, states, x, y, mask,
-                                                   True, key, w=w,
-                                                   w_denom=denom)
-                return loss, new_states
+                return model._loss_of(p, states, batch, key, w=w,
+                                      w_denom=denom)
 
             if flat_bwd:
                 flat_params = plan.flatten(params)
@@ -250,12 +238,12 @@ class ParallelWrapper:
                 (loss, new_states), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params)
                 OpProfiler.get().gauge("precision/grads_flat_in_step", 0)
-            if stats:
-                # non-finite counts are taken on the RAW per-shard grads
-                # (reduction would smear one shard's NaN across all of
-                # them) and aggregated with the same collective family as
-                # the weight update
-                raw_nf = jax.lax.psum(_tel.nonfinite_counts(grads), axis)
+            # non-finite counts are taken on the RAW per-shard grads
+            # (reduction would smear one shard's NaN across all of them)
+            # and aggregated with the same collective family as the
+            # weight update
+            raw_nf = (jax.lax.psum(_tel.nonfinite_counts(grads), axis)
+                      if stats else None)
             density = None
             if stateful:
                 grads, acc_state, density = acc.exchange(grads, acc_state,
@@ -289,11 +277,12 @@ class ParallelWrapper:
             else:
                 if not stateful:
                     grads = acc.reduce_gradients(grads)
-                new_params, new_upd = _prec.apply_updater(
-                    updater, grads, upd_state, params, it, shared_key)
+                grads, new_params, new_upd = update(
+                    model, updater, grads, upd_state, params, it, shared_key)
+            aux = None
             if tele is None:
-                return new_params, new_states, new_upd, acc_state, loss
-            if not stats:
+                pass
+            elif not stats:
                 # integrity-only aux: the loss plus the consistency
                 # verdict below — no per-layer stats, no dense grads
                 aux = {"loss": loss}
@@ -306,19 +295,19 @@ class ParallelWrapper:
                          for b in plan.buckets]
                 aux = _tel.sharded_layer_stats(loss, parts, plan.n_layers,
                                                axis, nonfinite=raw_nf)
-            else:
-                # norms on the REDUCED grads / updated params: replicated
-                # values, identical on every shard
-                aux = _tel.layer_stats(params, new_params, grads, loss,
-                                       nonfinite=raw_nf)
-            if density is not None:
-                # encoded-exchange density rides the telemetry aux into
-                # the metrics bus alongside the profiler ledger
-                aux["exchange_density"] = density
-            if tele.nan_guard:
-                aux, new_params, new_states, new_upd = _tel.apply_nan_guard(
-                    aux, new_params, params, new_states, states, new_upd,
-                    upd_state)
+            # dense stats (in the tail) are norms on the REDUCED grads /
+            # updated params: replicated values, identical on every shard.
+            # The encoded-exchange density rides the aux into the metrics
+            # bus alongside the profiler ledger.
+            out = finish(
+                tele, loss, (params, states, upd_state),
+                (new_params, new_states, new_upd), grads, aux=aux,
+                nonfinite=raw_nf,
+                extra=None if density is None
+                else {"exchange_density": density})
+            if tele is None:
+                return (*out[:3], acc_state, loss)
+            new_params, new_states, new_upd, _, aux = out
             if integ:
                 # Replica-consistency fingerprint (common.integrity): the
                 # O(params) bitcast fold of the step's INPUT state — the
@@ -390,8 +379,8 @@ class ParallelWrapper:
             out_specs += (P(),)    # aux pytree: replicated device scalars
         sharded = shard_map(
             local_step, mesh=self.mesh,
-            in_specs=(pspec, P(), uspec, aspec, P("data"), P("data"),
-                      P("data"), P("data"), P(), P()),
+            in_specs=(pspec, P(), uspec, aspec, P("data"), P("data"), P(),
+                      P()),
             out_specs=out_specs,
             check_rep=False)
 
@@ -414,28 +403,17 @@ class ParallelWrapper:
         local_step = self._local_core()
         tele = self._telemetry
 
-        def local_chunk(params, states, upd_state, acc_state, xs, ys, masks,
-                        ws, keys, it0):
+        def local_chunk(params, states, upd_state, acc_state, batches, ws,
+                        keys, it0):
             def body(carry, inp):
-                params, states, upd_state, acc_state, it = carry
-                x, y, m, w, k = inp
-                out = local_step(params, states, upd_state, acc_state, x, y,
-                                 m, w, k, it)
-                if tele is None:
-                    params, states, upd_state, acc_state, loss = out
-                    return (params, states, upd_state, acc_state,
-                            it + 1), loss
-                params, states, upd_state, acc_state, loss, aux = out
-                return (params, states, upd_state, acc_state,
-                        it + 1), (loss, aux)
+                *state, it = carry
+                out = local_step(*state, *inp, it)
+                return (*out[:4], it + 1), out[4:]
 
-            (params, states, upd_state, acc_state, _), ys_out = jax.lax.scan(
+            (*state, _), ys = jax.lax.scan(
                 body, (params, states, upd_state, acc_state, it0),
-                (xs, ys, masks, ws, keys))
-            if tele is None:
-                return params, states, upd_state, acc_state, ys_out
-            losses, auxes = ys_out
-            return params, states, upd_state, acc_state, losses, auxes
+                (batches, ws, keys))
+            return (*state, *ys)
 
         pspec = self._param_specs()
         uspec = self._upd_specs(pspec)
@@ -446,8 +424,7 @@ class ParallelWrapper:
             out_specs += (P(),)
         sharded = shard_map(
             local_chunk, mesh=self.mesh,
-            in_specs=(pspec, P(), uspec, aspec, batch, batch, batch, batch,
-                      P(), P()),
+            in_specs=(pspec, P(), uspec, aspec, batch, batch, P(), P()),
             out_specs=out_specs,
             check_rep=False)
 
@@ -570,6 +547,12 @@ class ParallelWrapper:
                     "elementwise=True; ZeRO-1 weight-update sharding "
                     "(ReduceScatterAccumulator) requires an elementwise "
                     "updater — use the dense accumulator instead")
+            asked = needs_tree_update(model)
+            if asked:
+                raise NotImplementedError(
+                    f"the configuration asks for {asked}, which ZeRO-1 "
+                    "(ReduceScatterAccumulator) cannot honour on its flat "
+                    "parameter shards — use the dense accumulator instead")
             pspec = self._param_specs()
             spec_leaves = ([] if pspec == P() else jax.tree.leaves(
                 pspec, is_leaf=lambda s: isinstance(s, P)))
@@ -916,7 +899,7 @@ class ParallelWrapper:
     def fit(self, data, epochs: int = 1, batch_size: Optional[int] = None,
             *, pad_partial: Optional[bool] = None,
             drop_remainder: bool = False, prefetch: Optional[int] = None,
-            steps_per_dispatch: int = 1, host_prefetch: int = 0,
+            steps_per_dispatch: int = 1,
             resume_from: Optional[str] = None,
             resume_cursor: Optional[tuple] = None) -> None:
         """Data-parallel training on the shared input/dispatch pipeline
@@ -992,32 +975,25 @@ class ParallelWrapper:
             prefetch=self.prefetch if prefetch is None else prefetch,
             steps_per_dispatch=steps_per_dispatch,
             bind=self._bind_batch,
-            place=lambda b: shard_batch(self.mesh, *b),
+            place=lambda b: jax.device_put(b, data_sharded(self.mesh)),
             dispatch_one=lambda b: self._dispatch_one(b, prof),
             dispatch_chunk=lambda g: self._dispatch_chunk(g, prof),
             stackable=_same_shapes, on_epoch=on_epoch,
-            round_to_multiple_of=self.workers_count,
-            host_prefetch=host_prefetch, skip=skip,
+            round_to_multiple_of=self.workers_count, skip=skip,
             first_step=model._iteration)
 
     def _bind_batch(self, ds: DataSet, w):
-        """DataSet → (x, y, mask, w) as HOST arrays. The mask is the RAW
-        labels-mask (ones when absent — shard_map's in_specs need a real
-        array); ``_loss``'s single ``_fold_weights`` application zeroes
-        the pad rows, so w is never applied twice. Staying numpy here
-        matters: the ONLY device placement is the sharded one
-        (``shard_batch`` in the feed) — a jnp conversion first would
-        commit every full batch to device 0 and then reshard it, doubling
-        per-step H2D traffic."""
-        x = ds.features.to_numpy()
-        y = ds.labels.to_numpy()
-        mask = (np.asarray(ds.labels_mask.to_numpy(), np.float32)
-                if ds.labels_mask is not None
-                else np.ones((x.shape[0],), np.float32))
+        """DataSet → ``(batch, w)``, the model's own batch as HOST arrays.
+        The loss's single ``_fold_weights`` application zeroes the pad
+        rows, so w is never applied twice. Staying numpy here matters:
+        the ONLY device placement is the sharded one (in the feed) — a
+        device batch would sit whole on device 0 and then be resharded,
+        doubling per-step H2D traffic."""
         # PerformanceListener derives samples/sec from this (the holder
         # the listener bus sees is the wrapped model)
-        self.model._last_batch_size = int(x.shape[0])
-        return x, y, mask, np.asarray(w, np.float32)
+        self.model._last_batch_size = ds.num_examples()
+        return (jax.tree.map(np.asarray, self.model._bind(ds)),
+                np.asarray(w, np.float32))
 
     def _inject_faults(self, model) -> None:
         """Pre-dispatch drill hook: the ``integrity/fingerprint`` site's
@@ -1033,13 +1009,12 @@ class ParallelWrapper:
 
     def _dispatch_one(self, b, prof) -> None:
         model = self.model
-        xs, ys, ms, ws = b
         self._inject_faults(model)
         key = get_random().next_key()
         with prof.time_section("pipeline/dispatch", step=model._iteration):
             out = self._step(model._params, model._states,
-                             model._updater_state, model._acc_state, xs,
-                             ys, ms, ws, key, jnp.asarray(model._iteration))
+                             model._updater_state, model._acc_state, *b,
+                             key, jnp.asarray(model._iteration))
         # the accumulator state (residual carry / threshold / counters) is
         # the wrapper's own training state — peel it off before the shared
         # note_dispatch decodes the (params, states, upd, loss[, aux])
@@ -1051,18 +1026,18 @@ class ParallelWrapper:
 
     def _dispatch_chunk(self, group, prof) -> None:
         model = self.model
-        # the group's arrays are already SHARDED by the feed's shard_batch:
+        # the group's arrays are already SHARDED by the feed's placement:
         # jnp.stack composes shardings device-side ([K, B, ...] with B
         # still split over the data axis), matching the chunk in_specs
-        stack = lambda i: jnp.stack([b[i] for b in group])  # noqa: E731
+        batches, ws = jax.tree.map(lambda *leaves: jnp.stack(leaves), *group)
         self._inject_faults(model)
         keys = jnp.stack([get_random().next_key() for _ in group])
         with prof.time_section("pipeline/dispatch", step=model._iteration,
                                steps=len(group)):
             out = self._chunk_step(model._params, model._states,
                                    model._updater_state, model._acc_state,
-                                   stack(0), stack(1), stack(2), stack(3),
-                                   keys, jnp.asarray(model._iteration))
+                                   batches, ws, keys,
+                                   jnp.asarray(model._iteration))
         model._acc_state = out[3]
         self._count_collectives(prof, len(group))
         _pipe.note_dispatch(model, self._listeners, out[:3] + out[4:],

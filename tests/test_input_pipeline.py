@@ -4,8 +4,8 @@ The acceptance contract of the trace-stable, overlapped training loop:
 
 1. shape-stable batching — an epoch whose final batch is PARTIAL still
    compiles the train step exactly ONCE (retrace counter proof), and the
-   padded, weight-masked training run produces bit-for-bit the same
-   params as the unpadded masked-loss loop on CPU;
+   padded, weight-masked training run produces the params of the unpadded
+   masked-loss loop (to reduction order: ``_assert_padding_invisible``);
 2. multi-step dispatch — ``steps_per_dispatch=K``'s lax.scan device loop
    matches the per-step loop's final params exactly (same rng stream,
    same core step function);
@@ -55,21 +55,47 @@ def _leaves(model):
     return [np.asarray(l) for l in jax.tree.leaves(model._params)]
 
 
+# A padded last batch and an unpadded one are two batch shapes, so two
+# compiled programs, and XLA promises no reduction order across programs:
+# the pad rows' terms are exactly 0.0, but the sums they sit in may be
+# associated differently. Measured on this tree (jax 0.9, CPU): after the
+# first padded step 1 float32 ulp on 2 of 3 (LSTM case) or 2 of 16 (MLP)
+# elements, max relative 8.7e-8 to 2.1e-7; the later steps then train from
+# parameters 1 ulp apart. Held to 1e-6 of the leaf's largest magnitude,
+# not of each element: a bias that is the remainder of a few updates of
+# opposite sign (1.6e-5 in a leaf of 1.0, 3e-3 in one of 2.8e-2) carries
+# the rounding of its terms, 9-16 ulp of itself after six steps and
+# 1.3e-7 of its leaf. A gap beyond that would be a fault, not an order.
+PAD_RTOL = 1e-6
+
+
+def _assert_padding_invisible(run):
+    """``run(pad_partial)`` trains a fresh model and returns its params'
+    leaves. Padded against padded (equal shapes, one program) stays
+    bit-for-bit; padded against unpadded is held to ``PAD_RTOL``."""
+    padded, again, unpadded = run(True), run(True), run(False)
+    for a, b in zip(padded, again):
+        np.testing.assert_array_equal(a, b)
+    for a, b in zip(padded, unpadded):
+        np.testing.assert_allclose(a, b, rtol=0,
+                                   atol=PAD_RTOL * np.abs(b).max())
+
+
 class TestShapeStableBatching:
     def test_padded_training_matches_masked_unpadded_bitforbit(self):
         """22 examples at batch 8 → 8, 8, 6: the padded run (6→8 with
-        zero example weights) must land on EXACTLY the params of the
-        unpadded weight-masked run — padding is numerically invisible."""
+        zero example weights) must land on the params of the unpadded
+        weight-masked run — padding is numerically invisible."""
         x, y = _data()
-        padded = _mlp()
-        get_random().set_seed(1)
-        padded.fit(NDArrayDataSetIterator(x, y, batch_size=8), epochs=3)
-        unpadded = _mlp()
-        get_random().set_seed(1)
-        unpadded.fit(NDArrayDataSetIterator(x, y, batch_size=8), epochs=3,
-                     pad_partial=False)
-        for a, b in zip(_leaves(padded), _leaves(unpadded)):
-            np.testing.assert_array_equal(a, b)
+
+        def run(pad):
+            model = _mlp()
+            get_random().set_seed(1)
+            model.fit(NDArrayDataSetIterator(x, y, batch_size=8), epochs=3,
+                      pad_partial=pad)
+            return _leaves(model)
+
+        _assert_padding_invisible(run)
 
     def test_one_compile_across_epoch_with_partial_final_batch(self):
         x, y = _data()
@@ -150,11 +176,9 @@ class TestShapeStableBatching:
 
             it = ExistingDataSetIterator(data)
             m.fit(it, epochs=2, batch_size=4, pad_partial=pad)
-            return m
+            return _leaves(m)
 
-        a, b = run(True), run(False)
-        for pa, pb in zip(_leaves(a), _leaves(b)):
-            np.testing.assert_allclose(pa, pb, rtol=0, atol=1e-12)
+        _assert_padding_invisible(run)
 
 
 class TestMultiStepDispatch:
@@ -256,17 +280,17 @@ class TestGraphPipeline:
         x, y = _data()
         prof = OpProfiler.get()
         prof.reset()
-        a = self._graph()
-        get_random().set_seed(1)
-        a.fit(NDArrayDataSetIterator(x, y, batch_size=8), epochs=2)
+
+        def run(pad):
+            g = self._graph()
+            get_random().set_seed(1)
+            g.fit(NDArrayDataSetIterator(x, y, batch_size=8), epochs=2,
+                  pad_partial=pad)
+            return _leaves(g)
+
+        run(True)
         assert prof.counter_value("trace/graph_fit_step") == 1
-        b = self._graph()
-        get_random().set_seed(1)
-        b.fit(NDArrayDataSetIterator(x, y, batch_size=8), epochs=2,
-              pad_partial=False)
-        for pa, pb in zip([np.asarray(l) for l in jax.tree.leaves(a._params)],
-                          [np.asarray(l) for l in jax.tree.leaves(b._params)]):
-            np.testing.assert_array_equal(pa, pb)
+        _assert_padding_invisible(run)
 
     def test_graph_chunked_matches_per_step(self):
         x, y = _data(32)
