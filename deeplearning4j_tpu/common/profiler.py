@@ -392,7 +392,9 @@ class OpProfiler:
         """Sequence-op ledger (``seq/*`` counters, ``ops/ssm.py`` and
         ``ops/pallas_attention.causal_attention``): call sites that took
         the Pallas kernel or the plain XLA path (``scan_kernel`` /
-        ``scan_fallback``, ``attn_kernel`` / ``attn_fallback``) and the
+        ``scan_fallback``, ``attn_kernel`` / ``attn_fallback`` for the
+        attention forward, ``attn_bwd_kernel`` / ``attn_bwd_fallback`` for
+        its backward, counted where the backward itself is traced) and the
         (query block, key block) pairs the attention band computes and
         leaves out of the square (``attn_key_blocks_run`` /
         ``attn_key_blocks_skipped``, per head). Trace-time counters: one

@@ -14,10 +14,11 @@ shape of the mask:
   recipe (recompute p per block from the row max/denominator) as an XLA
   ``lax.scan`` over key blocks. A causal mask with a bias rides in the bias;
   a causal mask without one is ``causal_attention``;
-- ``causal_attention`` (``flash_attention_fwd``): causal, optionally banded
-  by a window, grouped-query heads, a value wider than the head, operands in
-  the inputs' dtype with float32 accumulation. Only the key blocks inside the
-  band are fetched or computed, forward and backward.
+- ``causal_attention`` (``flash_attention_fwd``, ``flash_attention_bwd``):
+  causal, optionally banded by a window, grouped-query heads, a value wider
+  than the head, operands in the inputs' dtype with float32 accumulation. Only
+  the key blocks inside the band are fetched or computed, forward and
+  backward; the backward is one kernel over the same block pairs.
 
 Nothing of size T×T ever materializes in either. ``interpret=True`` runs a
 kernel in Pallas interpret mode (the CPU tests); on the TPU the same kernel
@@ -351,13 +352,17 @@ def flash_attention(q, k, v, causal: bool = False,
 # dtype with float32 accumulation. Query block i meets key blocks
 # lo(i)..i only (lo = 0 without a window), forward and backward: what the
 # mask empties is neither fetched nor computed, and no temporary is larger
-# than one [heads, block, block] tile. The forward is a Pallas kernel on
-# the TPU (``flash_attention_fwd``, which also hands back each row's
-# log-sum-exp) or the XLA loop below; the backward is the XLA loop both
-# times, from the saved log-sum-exp.
+# than one [heads, block, block] tile. On the TPU both directions are Pallas
+# kernels: ``flash_attention_fwd``, which also hands back each row's
+# log-sum-exp, and ``flash_attention_bwd``, one pass over the same block
+# pairs from that log-sum-exp (five products a pair, the score tile never
+# leaving VMEM). The XLA loops below are the path of every other case (the
+# CPU, a shape ``supports_band_kernel`` / ``supports_band_bwd_kernel``
+# refuses) and what the tests hold the kernels to.
 
 BAND_BLOCK = 512
 _MASKED = -1e30     # finite: a row whose first block is all masked stays finite
+_BWD_VMEM_LIMIT = 64 * 1024 * 1024      # of the v5e's 128 MiB, as ops/ssm.py
 
 
 def _band_lo(i, bs: int, window: Optional[int]):
@@ -367,24 +372,32 @@ def _band_lo(i, bs: int, window: Optional[int]):
     return jnp.maximum(i * bs - (window - 1), 0) // bs
 
 
+def _band_pairs(n: int, bs: int, window: Optional[int]) -> tuple:
+    """The band's (key block, query block) pairs as two int32 arrays, key
+    block first: the order the backward kernel walks them in."""
+    lo = [max(i * bs - (window - 1), 0) // bs if window else 0
+          for i in range(n)]
+    pairs = [(j, i) for j in range(n) for i in range(j, n) if lo[i] <= j]
+    return tuple(np.asarray(a, np.int32) for a in zip(*pairs))
+
+
 def _band_width(n: int, bs: int, window: Optional[int]) -> int:
     """Most key blocks any query block sees."""
-    if not window:
-        return n
-    return max(i - max(i * bs - (window - 1), 0) // bs + 1 for i in range(n))
+    return int(np.bincount(_band_pairs(n, bs, window)[1]).max())
 
 
 def band_blocks(T: int, bs: int, window: Optional[int]) -> tuple:
     """(block pairs computed, block pairs of the square left out)."""
     n = T // bs
-    run = sum(i - (max(i * bs - (window - 1), 0) // bs if window else 0) + 1
-              for i in range(n))
+    run = len(_band_pairs(n, bs, window)[0])
     return run, n * n - run
 
 
-def _band_mask(i, j, bs: int, window: Optional[int]):
-    qpos = i * bs + lax.broadcasted_iota(jnp.int32, (bs, bs), 0)
-    kpos = j * bs + lax.broadcasted_iota(jnp.int32, (bs, bs), 1)
+def _band_mask(i, j, bs: int, window: Optional[int], keys_first=False):
+    """[query, key] tile of block pair (i, j), or [key, query]."""
+    q_dim, k_dim = (1, 0) if keys_first else (0, 1)
+    qpos = i * bs + lax.broadcasted_iota(jnp.int32, (bs, bs), q_dim)
+    kpos = j * bs + lax.broadcasted_iota(jnp.int32, (bs, bs), k_dim)
     ok = kpos <= qpos
     if window:
         ok = ok & (qpos - kpos < window)
@@ -552,6 +565,136 @@ def _band_fwd_pallas(q, k, v, scale, window, bs, interpret):
     return (o.reshape(b, hk, g, T, dv), lse[..., 0].reshape(b, hk, g, T))
 
 
+def _band_bwd_kernel(pj_ref, pi_ref, qt_ref, k_ref, kt_ref, v_ref, do_ref,
+                     lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+                     dq_scr, dk_scr, dv_scr,
+                     *, scale: float, bs: int, window: Optional[int]):
+    # One grid step is one block pair of the band (``_band_pairs``: key block
+    # j outer, the query blocks i that see it inner) for the G query heads of
+    # one key/value head: dk_j and dv_j gather in scratch while j stays, the
+    # heads' dq stays in VMEM while the key/value head's pairs run. Tiles are
+    # [key, query], so the per-query log-sum-exp and delta broadcast as rows;
+    # dk and dq are gathered transposed ([D, block]) from q and k that come
+    # transposed too: with the narrow head width as the streamed side of
+    # their products the matrix unit takes half the passes, and nothing is
+    # transposed in here. Traced in the 32-bit world, like _fa_kernel.
+    f32 = jnp.float32
+    nt = (((1,), (1,)), ((), ()))
+    p, last_p = pl.program_id(1), pl.num_programs(1) - 1
+    j, i = pj_ref[p], pi_ref[p]
+
+    @pl.when(p == 0)
+    def _new_head():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    @pl.when((p == 0) | (pj_ref[jnp.maximum(p - 1, 0)] != j))
+    def _new_key_block():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    def pair(masked: bool):
+        k, kt, v = k_ref[0], kt_ref[0], v_ref[0]
+        if masked:
+            ok = _band_mask(i, j, bs, window, keys_first=True)
+        for g in range(qt_ref.shape[1]):
+            qt, do = qt_ref[0, g], do_ref[0, g]                 # [D, bq]
+            s = jnp.dot(k, qt, preferred_element_type=f32) * scale
+            if masked:
+                s = jnp.where(ok, s, _MASKED)
+            pr = jnp.exp(s - lse_ref[0, g, pl.ds(i, 1), :])
+            dv_scr[...] += jnp.dot(pr.astype(do.dtype), do,
+                                   preferred_element_type=f32)
+            dp = lax.dot_general(v, do, nt, preferred_element_type=f32)
+            ds = (pr * (dp - delta_ref[0, g, pl.ds(i, 1), :])
+                  * scale).astype(qt.dtype)
+            dk_scr[...] += lax.dot_general(qt, ds, nt,
+                                           preferred_element_type=f32)
+            dq_scr[g, i] += jnp.dot(kt, ds, preferred_element_type=f32)
+
+    # the mask only empties part of a tile on the diagonal and at the
+    # window's far edge
+    edge = i == j
+    if window:
+        edge = edge | ((i - j + 1) * bs - 1 >= window)
+    pl.when(edge)(functools.partial(pair, True))
+    pl.when(jnp.logical_not(edge))(functools.partial(pair, False))
+
+    @pl.when((p == last_p) | (pj_ref[jnp.minimum(p + 1, last_p)] != j))
+    def _key_block_done():
+        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+    @pl.when(p == last_p)
+    def _head_done():
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _band_bwd_call(h, g, T, d, dv, bs, window, scale, dtypes, interpret):
+    """The backward's ``pallas_call`` for one shape. Kept: what
+    ``pallas_call`` hands back is a ``jit``, so a model's layers of one
+    shape trace the kernel's body once."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    n = T // bs
+    rows_map = lambda h, p, pj, pi: (h, 0, pi[p], 0)        # noqa: E731
+    qt_map = lambda h, p, pj, pi: (h, 0, 0, pi[p])          # noqa: E731
+    kv_map = lambda h, p, pj, pi: (h, pj[p], 0)             # noqa: E731
+    kt_map = lambda h, p, pj, pi: (h, 0, pj[p])             # noqa: E731
+    head4 = lambda h, p, pj, pi: (h, 0, 0, 0)               # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_band_bwd_kernel, scale=scale, bs=bs,
+                          window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(h, len(_band_pairs(n, bs, window)[0])),
+            in_specs=[pl.BlockSpec((1, g, d, bs), qt_map),
+                      pl.BlockSpec((1, bs, d), kv_map),
+                      pl.BlockSpec((1, d, bs), kt_map),
+                      pl.BlockSpec((1, bs, dv), kv_map),
+                      pl.BlockSpec((1, g, bs, dv), rows_map),
+                      pl.BlockSpec((1, g, n, bs), head4),
+                      pl.BlockSpec((1, g, n, bs), head4)],
+            out_specs=[pl.BlockSpec((1, g, n, d, bs),
+                                    lambda h, p, pj, pi: (h, 0, 0, 0, 0)),
+                       pl.BlockSpec((1, d, bs), kt_map),
+                       pl.BlockSpec((1, bs, dv), kv_map)],
+            scratch_shapes=[pltpu.VMEM((g, n, d, bs), f32),
+                            pltpu.VMEM((d, bs), f32),
+                            pltpu.VMEM((bs, dv), f32)]),
+        out_shape=[jax.ShapeDtypeStruct((h, g, n, d, bs), dtypes[0]),
+                   jax.ShapeDtypeStruct((h, d, T), dtypes[1]),
+                   jax.ShapeDtypeStruct((h, T, dv), dtypes[2])],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
+        interpret=interpret, name="flash_attention_bwd")
+
+
+def _band_bwd_pallas(q, k, v, o, lse, do, scale, window, bs, interpret):
+    """Same contract as ``_band_bwd_xla``, as one kernel: five products a
+    block pair, a group's heads summed into dk and dv inside it, k and v
+    never repeated. q and k go in transposed as well, dq and dk leave it as
+    [.., D, block] tiles: turned here, by XLA."""
+    f32 = jnp.float32
+    b, hk, g, T, d = q.shape
+    dv = v.shape[-1]
+    h, n = b * hk, T // bs
+    pj, pi = _band_pairs(n, bs, window)
+    delta = jnp.sum(do.astype(f32) * o.astype(f32), axis=-1)   # [B,Hk,G,T]
+    q3, k3 = q.reshape(h, g, T, d), k.reshape(h, T, d)
+    with jax.enable_x64(False):
+        dq, dk, dv_ = _band_bwd_call(
+            h, g, T, d, dv, bs, window, scale, (q.dtype, k.dtype, v.dtype),
+            interpret,
+        )(jnp.asarray(pj), jnp.asarray(pi), q3.swapaxes(-1, -2), k3,
+          k3.swapaxes(-1, -2), v.reshape(h, T, dv), do.reshape(h, g, T, dv),
+          lse.reshape(h, g, n, bs), delta.reshape(h, g, n, bs))
+    return (dq.swapaxes(-1, -2).reshape(q.shape),
+            dk.swapaxes(-1, -2).reshape(k.shape), dv_.reshape(v.shape))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _band(q, k, v, scale, window, bs, kernel, interpret):
     return _band_fwd(q, k, v, scale, window, bs, kernel, interpret)[0]
@@ -567,6 +710,13 @@ def _band_fwd(q, k, v, scale, window, bs, kernel, interpret):
 
 
 def _band_bwd(scale, window, bs, kernel, interpret, res, do):
+    q = res[0]
+    _, _, g, T, d = q.shape
+    kernel = kernel and supports_band_bwd_kernel(T, d, g, q.dtype.itemsize)
+    OpProfiler.get().count("seq/attn_bwd_kernel" if kernel
+                           else "seq/attn_bwd_fallback")
+    if kernel:
+        return _band_bwd_pallas(*res, do, scale, window, bs, interpret)
     return _band_bwd_xla(*res, do, scale, window, bs)
 
 
@@ -577,6 +727,14 @@ def supports_band_kernel(T: int, d: int, dv: int, bs: int) -> bool:
     # the [bs, bs] score tile's lane dim % 128; head and value widths as
     # compiled for the chip (tests/test_tpu_compile_seq.py)
     return (T % bs == 0 and bs % 128 == 0 and d % 32 == 0 and dv % 32 == 0)
+
+
+def supports_band_bwd_kernel(T: int, d: int, g: int, itemsize: int) -> bool:
+    """Beside ``supports_band_kernel``: the backward kernel keeps the dq of a
+    key/value head's ``g`` query heads in VMEM (a float32 scratch and the
+    double-buffered output block) and leaves half of its limit to the tiles.
+    A longer sequence takes the forward kernel and the XLA backward."""
+    return g * T * d * (4 + 2 * itemsize) <= _BWD_VMEM_LIMIT // 2
 
 
 @op("causal_attention", "nn")
@@ -592,7 +750,16 @@ def causal_attention(q, k, v, window: Optional[int] = None,
     operands as they come (bfloat16 stays bfloat16) and accumulate in
     float32. ``block``: query and key block (``BAND_BLOCK``, shrunk to T); a
     ``T`` it does not divide is padded at the end, where causality hides the
-    padding. ``interpret`` as in ``ops.ssm.selective_scan``."""
+    padding. ``interpret`` as in ``ops.ssm.selective_scan``.
+
+    Where the forward is the Pallas kernel (a TPU, ``allow_pallas``,
+    ``supports_band_kernel``; or ``interpret=True``) the backward is one too
+    (``flash_attention_bwd``: the same band, the same casts of ``p`` and
+    ``ds`` before their products as the XLA loops), unless a group's dq
+    does not fit VMEM (``supports_band_bwd_kernel``); everywhere else both
+    are XLA loops. ``seq/attn_kernel`` / ``seq/attn_fallback`` count the
+    forward's call sites, ``seq/attn_bwd_kernel`` / ``seq/attn_bwd_fallback``
+    the backward's, as a step is traced."""
     from ..common.environment import Environment
 
     b, hq, T, d = q.shape

@@ -28,6 +28,7 @@ from deeplearning4j_tpu.data import DataSet
 from deeplearning4j_tpu.models import Phi4MiniFlash
 from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.conf.inputs import RNNInput
+from deeplearning4j_tpu.ops import pallas_attention as pa
 from deeplearning4j_tpu.ops.pallas_attention import causal_attention
 from deeplearning4j_tpu.ops.ssm import selective_scan
 
@@ -207,12 +208,17 @@ def _plain_gqa(q, k, v, window=None):
     (48, 16, None, None, 16, 32), (48, 16, 8, None, 16, 32),
     (40, 16, 20, None, 16, 32), (48, 16, 48, None, 16, 32),
     (48, 16, 100, None, 16, 32),
-    (256, 128, None, True, 64, 128), (384, 128, 130, True, 64, 128)])
+    (256, 128, None, True, 64, 128), (384, 128, 130, True, 64, 128),
+    # the cell's class (G = 2, Dv = 2 D): a window equal to the block (two key
+    # blocks a query block, one for the first), a window the block does not
+    # divide, a T the block does not divide
+    (384, 128, 128, True, 64, 128), (512, 128, 200, True, 64, 128),
+    (300, 128, None, True, 64, 128), (300, 128, 128, True, 64, 128)])
 def test_causal_attention_band(t, block, window, interpret, d, dv):
     """Window attention = full attention under the band mask (query i sees
     i-window < j <= i), = plain causal attention when window >= T; grouped
-    heads; a T the block does not divide; XLA loops and the Pallas forward
-    under interpret mode. Forward and the gradients of q, k, v."""
+    heads; a T the block does not divide; XLA loops, and the Pallas forward
+    and backward under interpret mode. Forward and the gradients of q, k, v."""
     q, k, v = _qkv(t, d=d, dv=dv)
     w = jax.random.normal(jax.random.PRNGKey(7), (B, 4, t, dv), F32)
     prog = lambda *a: causal_attention(*a, window=window, block=block,  # noqa: E731
@@ -224,6 +230,112 @@ def test_causal_attention_band(t, block, window, interpret, d, dv):
     g = lambda f: jax.grad(lambda *a: jnp.sum(f(*a) * w),   # noqa: E731
                            (0, 1, 2))(q, k, v)
     _tree_close(g(prog), g(ref))
+
+
+def _residuals(t, block, window, dtype, g=2, d=64, dv=128):
+    """(q [B, Hk, G, T, D], k, v, o, lse, do) as ``_band_fwd`` leaves them."""
+    q, k, v = (a.astype(dtype) for a in _qkv(t, hq=2 * g, d=d, dv=dv))
+    q = q.reshape(B, 2, g, t, d)
+    o, lse = pa._band_fwd_xla(q, k, v, 0.125, window, block)
+    do = jax.random.normal(jax.random.PRNGKey(11), o.shape, F32)
+    return q, k, v, o.astype(dtype), lse, do.astype(dtype)
+
+
+@pytest.mark.parametrize("t,block,window,dtype,g,tol", [
+    (384, 128, None, "float32", 2, 1e-5), (384, 128, 128, "float32", 2, 1e-5),
+    (512, 128, 200, "float32", 1, 1e-5),
+    # bfloat16 operands: both sides round p and ds to bfloat16 before their
+    # products and sum in float32, in another order; the results are
+    # bfloat16, so they agree to an ulp of that (2^-8) of the largest
+    (384, 128, None, "bfloat16", 2, 2.0 ** -7),
+    (384, 128, 128, "bfloat16", 2, 2.0 ** -7)])
+def test_band_bwd_kernel_equals_the_loops_on_the_same_residuals(
+        t, block, window, dtype, g, tol):
+    """dq, dk and dv of the Pallas backward (interpret mode) against
+    ``_band_bwd_xla``'s, each by name, on the same residuals and cotangent:
+    not through ``jax.grad``, so a fault in one of the three is named."""
+    res = _residuals(t, block, window, jnp.dtype(dtype), g=g)
+    want = pa._band_bwd_xla(*res, 0.125, window, block)
+    got = pa._band_bwd_pallas(*res, 0.125, window, block, True)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        gap = float(jnp.max(jnp.abs(a.astype(F32) - b.astype(F32)))
+                    / jnp.max(jnp.abs(b.astype(F32))))
+        assert gap <= tol, (name, gap)
+
+
+@pytest.mark.parametrize("window", [None, 128])
+def test_causal_attention_band_bfloat16(window):
+    """bfloat16 operands, as the cell has them: the kernels' gradients
+    against the XLA loops' on the same bfloat16 inputs (an ulp or two of
+    bfloat16), and both against plain float32 attention on the same values
+    (bfloat16's rounding of p, ds and the results: 2%)."""
+    q, k, v = (a.astype(jnp.bfloat16) for a in _qkv(384, d=64, dv=128))
+    w = jax.random.normal(jax.random.PRNGKey(7), (B, 4, 384, 128), F32)
+
+    def grads(f, *a):
+        return jax.grad(lambda *a: jnp.sum(f(*a).astype(F32) * w),
+                        (0, 1, 2))(*a)
+
+    prog = lambda interpret: lambda *a: causal_attention(   # noqa: E731
+        *a, window=window, block=128, interpret=interpret)
+    kernel, loops = grads(prog(True), q, k, v), grads(prog(None), q, k, v)
+    plain = grads(lambda *a: _plain_gqa(*a, window=window),
+                  *(a.astype(F32) for a in (q, k, v)))
+    assert all(a.dtype == jnp.bfloat16 for a in kernel)
+    _tree_close(kernel, loops, 2.0 ** -7)
+    _tree_close(kernel, plain, 0.02)
+    _tree_close(loops, plain, 0.02)
+
+
+@pytest.mark.parametrize("n,bs,window", [
+    (16, 512, None), (16, 512, 512), (12, 128, 130), (12, 128, 200),
+    (9, 16, 8), (9, 16, 1), (7, 128, 1000)])
+def test_band_pairs_are_the_forwards_pairs_key_block_first(n, bs, window):
+    """The pairs the backward's grid walks (key block j outer, the query
+    blocks that see it inner, ascending) are the pairs ``lo(i) <= j <= i`` the
+    forward walks query block first; ``band_blocks`` counts them."""
+    lo = [int(pa._band_lo(jnp.int32(i), bs, window)) for i in range(n)]
+    pj, pi = pa._band_pairs(n, bs, window)
+    assert pj.dtype == pi.dtype == np.int32
+    assert list(zip(pj, pi)) == [(j, i) for j in range(n) for i in range(n)
+                                 if lo[i] <= j <= i]
+    assert len(pj) == pa.band_blocks(n * bs, bs, window)[0]
+    assert max(np.bincount(pj)) <= pa._band_width(n, bs, window)
+
+
+def test_attention_backward_is_counted_once_a_traced_call_site():
+    """``seq/attn_bwd_kernel`` where the backward is the Pallas kernel,
+    ``seq/attn_bwd_fallback`` where it is the XLA loops (the CPU without
+    ``interpret``; a shape the forward kernel refuses); a forward alone
+    counts neither; ``sequence_stats()`` returns both."""
+    prof = OpProfiler.get()
+    read = lambda: (prof.counter_value("seq/attn_bwd_kernel"),   # noqa: E731
+                    prof.counter_value("seq/attn_bwd_fallback"))
+    q, k, v = _qkv(256, d=64, dv=128)
+    loss = lambda interpret, block: lambda *a: causal_attention(  # noqa: E731
+        *a, block=block, interpret=interpret).sum()
+    k0, f0 = read()
+    causal_attention(q, k, v, block=128, interpret=True)
+    assert read() == (k0, f0)
+    jax.grad(loss(True, 128), (0, 1, 2))(q, k, v)
+    assert read() == (k0 + 1, f0)
+    jax.grad(loss(None, 128), (0, 1, 2))(q, k, v)       # the CPU: XLA loops
+    assert read() == (k0 + 1, f0 + 1)
+    jax.grad(loss(True, 64), (0, 1, 2))(q, k, v)        # block % 128: no kernel
+    assert read() == (k0 + 1, f0 + 2)
+    stats = prof.sequence_stats()
+    assert stats["attn_bwd_kernel"] >= 1 and stats["attn_bwd_fallback"] >= 2
+
+
+def test_backward_kernel_has_its_own_shape_predicate():
+    """The dq of a key/value head's query heads stays in VMEM: the cell's
+    shapes fit, a sequence eight times as long takes the forward kernel and
+    the XLA backward."""
+    assert pa.supports_band_bwd_kernel(8192, 64, 2, 2)
+    assert pa.supports_band_bwd_kernel(2048, 32, 1, 4)
+    assert pa.supports_band_kernel(65536, 64, 128, 512)
+    assert not pa.supports_band_bwd_kernel(65536, 64, 2, 2)
 
 
 def test_band_skips_key_blocks():

@@ -1,10 +1,10 @@
 """The sequence kernels compile for the chip at the shapes of the cell
 ``phi4_mini_flash.train_s8k``: the selective scan forward and backward
-(``ops/ssm.py``) and the banded attention forward with grouped heads, a
-window and bfloat16 operands (``ops/pallas_attention.causal_attention``), each
-with its backward. As ``tests/test_tpu_compile.py``: the compiler is installed
-with jax and compiles for a chip that is DESCRIBED, not attached; a compile
-that passes is not a chip run.
+(``ops/ssm.py``) and the banded attention forward and backward with grouped
+heads, a window and bfloat16 operands
+(``ops/pallas_attention.causal_attention``). As ``tests/test_tpu_compile.py``:
+the compiler is installed with jax and compiles for a chip that is DESCRIBED,
+not attached; a compile that passes is not a chip run.
 """
 
 import os
@@ -87,9 +87,12 @@ CASES = {
     "selective_scan_fwd_bwd": (lambda: _scan(True), 2),
     "attention_full_fwd": (lambda: _attention(None, False), 1),
     "attention_window_fwd": (lambda: _attention(512, False), 1),
-    "attention_window_fwd_bwd": (lambda: _attention(512, True), 1),
-    "attention_plain_f32_d64_fwd_bwd": (lambda: _attention(None, True, 64), 1),
-    "attention_plain_f32_d32_fwd_bwd": (lambda: _attention(None, True, 32), 1),
+    # forward + backward kernel (``flash_attention_bwd``): the backward's
+    # shape predicate holds at all four (``supports`` says so below)
+    "attention_full_fwd_bwd": (lambda: _attention(None, True), 2),
+    "attention_window_fwd_bwd": (lambda: _attention(512, True), 2),
+    "attention_plain_f32_d64_fwd_bwd": (lambda: _attention(None, True, 64), 2),
+    "attention_plain_f32_d32_fwd_bwd": (lambda: _attention(None, True, 32), 2),
 }
 
 
@@ -109,5 +112,8 @@ def supports(case):
         return ssm.supports_scan_kernel(D_INNER, D_STATE)
     if "plain" in case:
         d = int(case.split("_d")[1].split("_")[0])
-        return pa.supports_band_kernel(2048, d, d, pa.BAND_BLOCK)
-    return pa.supports_band_kernel(T, 64, 128, pa.BAND_BLOCK)
+        return (pa.supports_band_kernel(2048, d, d, pa.BAND_BLOCK)
+                and pa.supports_band_bwd_kernel(2048, d, 1, 4))
+    return (pa.supports_band_kernel(T, 64, 128, pa.BAND_BLOCK)
+            and (not case.endswith("bwd")
+                 or pa.supports_band_bwd_kernel(T, 64, 2, 2)))
