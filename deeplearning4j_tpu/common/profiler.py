@@ -47,6 +47,7 @@ class OpProfiler:
         ("fleet", "fleet_stats"),
         ("precision", "precision_stats"),
         ("sequence", "sequence_stats"),
+        ("moe", "moe_stats"),
         ("xla", "xla_stats"),
         ("tracecheck", "tracecheck_stats"),
         ("faults", "fault_stats"),
@@ -402,6 +403,19 @@ class OpProfiler:
         until a sequence layer is traced."""
         return {k.split("/", 1)[1]: v for k, v in self._counters.items()
                 if k.startswith("seq/")}
+
+    def moe_stats(self) -> Dict[str, float]:
+        """Routed-expert ledger (``moe/*`` counters, ``ops/moe.py`` and
+        ``RoutedExpertsLayer``): call sites of the grouped matrix product
+        that took the Pallas kernels or ``lax.ragged_dot`` (``gmm_kernel`` /
+        ``gmm_fallback``) and the static rows of the dispatch buffers
+        (``dispatch_rows``: k x tokens a routed layer, the worst case of a
+        dropless layer). Trace-time counters: one bump per call site per
+        traced program, not per execution. The realised load is layer
+        state (``ComputationGraph.expert_load()``). Empty until a routed
+        layer is traced."""
+        return {k.split("/", 1)[1]: v for k, v in self._counters.items()
+                if k.startswith("moe/")}
 
     def xla_stats(self) -> Dict[str, float]:
         """XLA performance-observatory ledger (``common.xprof``): the

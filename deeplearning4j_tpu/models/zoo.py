@@ -1076,3 +1076,119 @@ class Phi4MiniFlash(ZooModel):
         gc.compute_dtype = self.compute_dtype
         gc.remat_policy = self.remat_policy
         return ComputationGraph(conf).init()
+
+
+class Lfm2Moe(ZooModel):
+    """LFM2-24B-A2B (``model_type`` ``lfm2_moe``;
+    huggingface.co/LiquidAI/LFM2-24B-A2B, ``config.json``; the family's
+    modelling code is ``models/lfm2_moe`` of Hugging Face ``transformers``):
+    a decoder of pre-norm blocks ``x + Operator(RMSNorm(x))``, ``x +
+    FFN(RMSNorm(x))`` whose operator is a gated short convolution or, every
+    fourth layer, grouped-query attention with per-head RMSNorm on queries
+    and keys and rotary positions, and whose feed-forward is a dense gated
+    MLP in the first ``num_dense_layers`` layers and ``num_experts`` routed
+    experts, ``num_experts_per_tok`` a token, after them (sigmoid scores, a
+    selection bias, weights normalised over the selected). A final RMSNorm;
+    the head is the embedding table itself.
+
+    ``layers``: the published layer indices to build, in order (all when
+    None) — a pipeline stage, or one period. ``vocab_rows``: rows of the
+    tied embedding/head held here. ``experts_held``: ``(first, count)`` of
+    the experts of every routed layer that live here (all when None): the
+    router keeps its ``num_experts`` outputs and the layer computes its own
+    experts' part (``RoutedExpertsLayer``). ``expert_bias``: the
+    ``num_experts`` selection biases, the same in every routed layer (zeros
+    when None); they are layer state, not parameters. Defaults are the
+    published sizes. Trained through ``ComputationGraph.fit`` on ``[B, T]``
+    integer ids with ``[B, T]`` integer next-token labels; the tokens that
+    selected each expert are ``ComputationGraph.expert_load()``."""
+
+    def __init__(self, layers: Optional[Sequence[int]] = None,
+                 vocab_rows: int = 65536,
+                 experts_held: Optional[Tuple[int, int]] = None,
+                 hidden_size: int = 2048, intermediate_size: int = 11776,
+                 moe_intermediate_size: int = 1536,
+                 num_attention_heads: int = 32, num_key_value_heads: int = 8,
+                 num_experts: int = 64, num_experts_per_tok: int = 4,
+                 routed_scaling_factor: float = 1.0,
+                 num_dense_layers: int = 2, num_hidden_layers: int = 40,
+                 full_attention_every: int = 4, conv_L_cache: int = 3,
+                 norm_eps: float = 1e-5, rope_theta: float = 1e6,
+                 expert_bias: Optional[Sequence[float]] = None,
+                 seq_len: Optional[int] = None,
+                 compute_dtype: Optional[str] = "bfloat16",
+                 state_dtype: Optional[str] = "bfloat16",
+                 remat_policy="full", learning_rate: float = 1e-4,
+                 weight_decay: float = 0.1, seed: int = 123):
+        self.layers = list(range(num_hidden_layers) if layers is None
+                           else layers)
+        self.vocab_rows = vocab_rows
+        self.experts_held = experts_held or (0, num_experts)
+        self.d, self.ff, self.moe_ff = (hidden_size, intermediate_size,
+                                        moe_intermediate_size)
+        self.heads, self.kv_heads = num_attention_heads, num_key_value_heads
+        self.experts, self.top_k = num_experts, num_experts_per_tok
+        self.scale = routed_scaling_factor
+        self.dense_layers = num_dense_layers
+        self.period = full_attention_every
+        self.taps, self.eps, self.theta = conv_L_cache, norm_eps, rope_theta
+        self.expert_bias = (None if expert_bias is None
+                            else [float(b) for b in expert_bias])
+        self.seq_len = seq_len
+        self.compute_dtype, self.state_dtype = compute_dtype, state_dtype
+        self.remat_policy = remat_policy
+        self.learning_rate, self.weight_decay = learning_rate, weight_decay
+        self.seed = seed
+
+    def is_attention(self, l: int) -> bool:
+        """``layer_types[l] == "full_attention"``: layers 2, 6, 10, ..."""
+        return l % self.period == self.period - 2
+
+    def init(self) -> ComputationGraph:
+        updater = AdamW(learning_rate=self.learning_rate, beta1=0.9,
+                        beta2=0.95, epsilon=1e-8,
+                        weight_decay=self.weight_decay)
+        updater.state_dtype = self.state_dtype
+        gb = (ComputationGraphConfiguration
+              .graph_builder(NeuralNetConfiguration.builder()
+                             .seed(self.seed).updater(updater))
+              .add_inputs("ids"))
+        gb.add_layer("embed", L.EmbeddingSequenceLayer(
+            n_out=self.d, weight_init="normal"), "ids")
+        norm = lambda: L.RMSNormLayer(eps=self.eps)         # noqa: E731
+        first, held = self.experts_held
+        prev = "embed"
+        for l in self.layers:
+            if self.is_attention(l):
+                op = L.RotaryAttentionLayer(
+                    n_heads=self.heads, n_kv_heads=self.kv_heads,
+                    head_dim=self.d // self.heads, rope_theta=self.theta,
+                    eps=self.eps)
+            else:
+                op = L.ShortConvLayer(taps=self.taps)
+            if l < self.dense_layers:
+                ffn = L.GatedMLPLayer(n_ff=self.ff)
+            else:
+                ffn = L.RoutedExpertsLayer(
+                    n_routed=self.experts, n_experts=held, first_expert=first,
+                    n_ff=self.moe_ff, top_k=self.top_k, scale=self.scale,
+                    selection_bias=self.expert_bias)
+            gb.add_layer(f"l{l}_ln1", norm(), prev)
+            gb.add_layer(f"l{l}_op", op, f"l{l}_ln1")
+            gb.add_vertex(f"l{l}_add1", ElementWiseVertex(op="add"),
+                          prev, f"l{l}_op")
+            gb.add_layer(f"l{l}_ln2", norm(), f"l{l}_add1")
+            gb.add_layer(f"l{l}_ffn", ffn, f"l{l}_ln2")
+            gb.add_vertex(f"l{l}_add2", ElementWiseVertex(op="add"),
+                          f"l{l}_add1", f"l{l}_ffn")
+            prev = f"l{l}_add2"
+        gb.add_layer("final_ln", norm(), prev)
+        gb.add_layer("head", L.TiedOutputLayer(tied_to="embed"), "final_ln")
+        conf = (gb.set_outputs("head")
+                .set_input_types(InputType.recurrent(self.vocab_rows,
+                                                     self.seq_len))
+                .build())
+        gc = conf.global_conf
+        gc.compute_dtype = self.compute_dtype
+        gc.remat_policy = self.remat_policy
+        return ComputationGraph(conf).init()

@@ -10,7 +10,11 @@ ParamInitializers: dense W=[nIn,nOut], conv W=[out,in,kH,kW] (OIHW),
 bias=[nOut].
 
 Every ``apply`` is functional: (params, x, state, training, rng) -> (y, state)
-where ``state`` carries batchnorm running stats (the only stateful layer).
+where ``state`` is whatever a layer keeps between steps that is not a
+parameter and takes no gradient: BatchNorm's running statistics,
+``RoutedExpertsLayer``'s selection bias and accumulated expert load. The
+networks hand a layer's state in and carry what it returns, whatever its
+kind; ``init_state()`` is empty for a stateless layer.
 """
 
 from __future__ import annotations

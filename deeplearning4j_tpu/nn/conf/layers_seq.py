@@ -1,14 +1,20 @@
-"""Sequence-model layers of a hybrid state-space / attention decoder.
+"""Sequence-model layers of hybrid decoders.
 
 ``MambaLayer`` (Gu & Dao, arXiv:2312.00752, Alg. 2), ``DifferentialAttentionLayer``
 (Ye et al., arXiv:2410.05258, causal, grouped-query, optionally windowed, or
 reading another layer's keys and values), ``GatedMemoryUnit`` (Ren et al.,
 arXiv:2507.06607 section 2), ``GatedMLPLayer`` and ``TiedOutputLayer`` (the
-head that reads the embedding table and computes its loss in token blocks).
-All take and give ``[B, T, F]``. They are plain layer configurations: a
-``ComputationGraph`` wires them with ``LayerNormalization`` and
-``ElementWiseVertex(add)`` into pre-norm residual blocks
-(``models.Phi4MiniFlash``).
+head that reads the embedding table and computes its loss in token blocks):
+``models.Phi4MiniFlash``. ``RMSNormLayer``, ``ShortConvLayer`` (a gated
+depthwise causal convolution of a few taps), ``RotaryAttentionLayer``
+(grouped-query attention with per-head RMSNorm on queries and keys and rotary
+positions) and ``RoutedExpertsLayer`` (a dropless top-k expert layer that
+holds a share of the experts; the second kind of layer with state, after
+BatchNorm: a constant selection bias and the accumulated load of each
+expert): ``models.Lfm2Moe`` (the ``lfm2_moe`` family of Hugging Face
+``transformers``). All take and give ``[B, T, F]``. They are plain layer
+configurations: a ``ComputationGraph`` wires them with a norm layer and
+``ElementWiseVertex(add)`` into pre-norm residual blocks.
 
 Three things here that the older layers do not use, each read by
 ``ComputationGraph``:
@@ -26,11 +32,14 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Dict, NamedTuple, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ...common.profiler import OpProfiler
+from ...ops.moe import (GMM_ROW_TILE, grouped_matmul, rotary_embedding,
+                        route_topk)
 from ...ops.pallas_attention import causal_attention
 from ...ops.ssm import selective_scan
 from ..losses import LossSparseMCXENT
@@ -251,6 +260,269 @@ class GatedMemoryUnit(Layer):
         x, m = x
         with jax.named_scope("gmu"):
             return (m * jax.nn.silu(x @ params["W1"])) @ params["W2"], state
+
+
+def _rms(x, gain, eps):
+    """``x * rsqrt(mean(x^2) + eps) * gain`` over the last axis in float32,
+    back in ``x``'s dtype."""
+    xw = _f32(x)
+    y = xw * jax.lax.rsqrt(jnp.mean(xw * xw, -1, keepdims=True) + eps)
+    return (y * _f32(gain)).astype(x.dtype)
+
+
+@dataclass
+class RMSNormLayer(Layer):
+    """``y = x * rsqrt(mean(x^2) + eps) * gain`` over the feature axis, in
+    float32 whatever the compute dtype; no bias."""
+
+    eps: float = 1e-5
+    full_precision_params = ("gain",)
+
+    def set_input_type(self, input_type):
+        self.n_in = input_type.size
+        return input_type
+
+    def init_params(self, key, dtype=jnp.float32):
+        return {"gain": jnp.ones((self.n_in,), dtype)}
+
+    def apply(self, params, x, state, training, rng):
+        with jax.named_scope("rms_norm"):
+            return _rms(x, params["gain"], self.eps), state
+
+
+@dataclass
+class ShortConvLayer(Layer):
+    """Gated short convolution: ``[B, C, u] = x W_in``; ``v = B * u``;
+    ``c_t = sum_j w_j * v_{t-(taps-1)+j}`` (depthwise, causal, ``v`` zero
+    before the sequence; no bias, no activation); ``y = (C * c) W_out``."""
+
+    taps: int = 3
+
+    def set_input_type(self, input_type):
+        self.n_in = input_type.size
+        return input_type
+
+    def init_params(self, key, dtype=jnp.float32):
+        k1, k2, k3 = jax.random.split(key, 3)
+        d = self.n_in
+        return {"W_in": _normal(k1, (d, 3 * d), dtype),
+                "conv_w": _normal(k2, (self.taps, d), dtype),
+                "W_out": _normal(k3, (d, d), dtype)}
+
+    def apply(self, params, x, state, training, rng):
+        with jax.named_scope("short_conv"):
+            b, c, u = jnp.split(x @ params["W_in"], 3, axis=-1)
+            v = b * u
+            T = v.shape[1]
+            padded = jnp.pad(v, ((0, 0), (self.taps - 1, 0), (0, 0)))
+            conv = sum(padded[:, j:j + T] * params["conv_w"][j]
+                       for j in range(self.taps))
+            return (c * conv) @ params["W_out"], state
+
+
+@dataclass
+class RotaryAttentionLayer(Layer):
+    """Causal grouped-query attention with rotary positions: ``q = x Wq``
+    (``n_heads`` of ``head_dim``), ``k = x Wk``, ``v = x Wv`` (``n_kv_heads``
+    each), no bias; RMSNorm over each head of q and of k with one learned
+    gain each (``q_norm``, ``k_norm``); rotate-half rotary embedding at
+    positions 0..T-1; query head ``h`` reads key/value head ``h //
+    (n_heads / n_kv_heads)``; ``softmax(q k^T / sqrt(head_dim) + causal
+    mask) v``; heads concatenated into ``Wo``."""
+
+    n_heads: int = 0
+    n_kv_heads: int = 0
+    head_dim: int = 64
+    rope_theta: float = 10000.0
+    eps: float = 1e-5
+
+    full_precision_params = ("q_norm", "k_norm")
+
+    def set_input_type(self, input_type):
+        self.n_in = input_type.size
+        return input_type
+
+    def init_params(self, key, dtype=jnp.float32):
+        ks = jax.random.split(key, 4)
+        d, hd = self.n_in, self.head_dim
+        nq, nkv = self.n_heads * hd, self.n_kv_heads * hd
+        return {"Wq": _normal(ks[0], (d, nq), dtype),
+                "Wk": _normal(ks[1], (d, nkv), dtype),
+                "Wv": _normal(ks[2], (d, nkv), dtype),
+                "Wo": _normal(ks[3], (nq, d), dtype),
+                "q_norm": jnp.ones((hd,), dtype),
+                "k_norm": jnp.ones((hd,), dtype)}
+
+    def apply(self, params, x, state, training, rng):
+        hd = self.head_dim
+        with jax.named_scope("rope_attn"):
+            b, T, _ = x.shape
+            pos = jnp.arange(T)
+
+            def heads(w, n, gain=None):     # -> [B, n, T, hd]
+                a = (x @ params[w]).reshape(b, T, n, hd)
+                if gain is not None:
+                    a = _rms(a, params[gain], self.eps)
+                a = a.transpose(0, 2, 1, 3)
+                return (a if gain is None
+                        else rotary_embedding(a, pos, self.rope_theta))
+
+            o = causal_attention(heads("Wq", self.n_heads, "q_norm"),
+                                 heads("Wk", self.n_kv_heads, "k_norm"),
+                                 heads("Wv", self.n_kv_heads))
+            o = o.transpose(0, 2, 1, 3).reshape(b, T, self.n_heads * hd)
+            return o @ params["Wo"], state
+
+
+@jax.custom_vjp
+def _take_rows(x, src, dst, live):
+    """The dispatch: ``x`` ``[n, d]`` -> the buffer's rows ``[cap, d]``, row
+    ``r`` the token of pair ``src[r]`` (pairs are slot-major: pair ``j*n +
+    t`` is token ``t``'s ``j``-th selection). ``dst`` ``[k*n]`` is each
+    pair's row in the buffer and ``live`` whether the pair's expert is held
+    (then ``dst < cap``): the backward gathers by them and sums a token's
+    ``k`` slots, where autodiff would scatter-add."""
+    return x[src % x.shape[0]]
+
+
+def _take_rows_fwd(x, src, dst, live):
+    return _take_rows(x, src, dst, live), (dst, live, x.shape[0])
+
+
+def _take_rows_bwd(res, g):
+    dst, live, n = res
+    back = jnp.where(live[:, None], g[jnp.minimum(dst, g.shape[0] - 1)], 0)
+    return (back.reshape(-1, n, g.shape[-1]).sum(0).astype(g.dtype),
+            None, None, None)
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine(out, p, src, dst, live):
+    """The buffer's rows back to their tokens: ``y[t] = sum_j p[j, t] *
+    out[dst[j*n + t]]`` over the live pairs, float32. ``out`` ``[cap, d]``,
+    ``p`` ``[k, n]``. The backward stays the buffer's size: a row's
+    cotangent is its token's times its weight."""
+    k, n = p.shape
+    back = jnp.where(live[:, None],
+                     out[jnp.minimum(dst, out.shape[0] - 1)], 0)
+    return jnp.sum(_f32(back).reshape(k, n, -1) * p[..., None], axis=0)
+
+
+def _combine_fwd(out, p, src, dst, live):
+    return _combine(out, p, src, dst, live), (out, p, src, dst, live)
+
+
+def _combine_bwd(res, dy):
+    out, p, src, dst, live = res
+    rows = dy[src % p.shape[1]]                            # [cap, d]
+    w = jnp.where(live[src], p.reshape(-1)[src], 0)
+    per_row = jnp.sum(_f32(out) * rows, axis=-1)           # [cap]
+    dp = jnp.where(live, per_row[jnp.minimum(dst, out.shape[0] - 1)], 0)
+    return ((rows * w[:, None]).astype(out.dtype),
+            dp.reshape(p.shape).astype(p.dtype), None, None, None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+@dataclass
+class RoutedExpertsLayer(Layer):
+    """A share of a top-k routed expert layer, dropless. ``n_routed`` is the
+    router's width (the published expert count); the layer holds the experts
+    ``first_expert <= e < first_expert + n_experts`` (all of them where
+    ``n_experts`` is 0). Scores ``s = sigmoid(x Wg)`` over all ``n_routed``
+    in float32; selection ``S = top_k(s + bias)``; weights ``p_e = s_e /
+    (sum_{S} s + 1e-6) * scale`` (``ops.moe.route_topk``); expert ``e``:
+    ``E_e(x) = (silu(x W1_e[:, :ff]) * x W1_e[:, ff:]) W2_e``. Returns ``sum
+    over e in S and held of p_e E_e(x)``: the weights' denominator runs over
+    all selected experts, held or not, what the experts that are not held
+    would add is left out, and a token that selects no held expert gets
+    zero. No capacity: every (token, held expert) pair is computed, for any
+    routing. The pairs are sorted by expert into a buffer and multiplied
+    group by group (``ops.moe.grouped_matmul``, whose kernels skip the tiles
+    beyond the rows that were routed). The buffer holds ``k * tokens`` rows,
+    the worst case (every selection held), so no routing drops a token; the
+    gathers and the elementwise passes around the kernels run over all of
+    it, whatever was routed.
+
+    A layer that holds a share of the experts and trains ALONE sees its load
+    grow: its router is a replica whose gradient the deployment sums over
+    the chips that share the layer, and the one part that comes through the
+    held experts pulls every token towards them (``PERF.md``, PR 32: the
+    held experts' load grew 5.3-fold in 96 steps of AdamW).
+
+    State: ``bias`` ``[n_routed]``, the selection bias, a constant that
+    takes no gradient (whoever trains with a balance rule sets it between
+    steps), and ``expert_load`` ``[n_routed]`` float32, the tokens that
+    selected each expert, accumulated over the training steps since it was
+    last cleared."""
+
+    n_routed: int = 0
+    n_experts: int = 0
+    first_expert: int = 0
+    n_ff: int = 0
+    top_k: int = 1
+    scale: float = 1.0
+    selection_bias: Optional[Sequence[float]] = None    # zeros when None
+
+    full_precision_params = ("Wg",)
+
+    def set_input_type(self, input_type):
+        self.n_in = input_type.size
+        if not self.n_experts:
+            self.n_experts = self.n_routed
+        return input_type
+
+    def init_params(self, key, dtype=jnp.float32):
+        k1, k2, k3 = jax.random.split(key, 3)
+        d, e, ff = self.n_in, self.n_experts, self.n_ff
+        return {"Wg": _normal(k1, (d, self.n_routed), dtype),
+                "W1": _normal(k2, (e, d, 2 * ff), dtype),
+                "W2": _normal(k3, (e, ff, d), dtype)}
+
+    def init_state(self):
+        bias = (jnp.zeros((self.n_routed,), jnp.float32)
+                if self.selection_bias is None
+                else jnp.asarray(self.selection_bias, jnp.float32))
+        return {"bias": bias,
+                "expert_load": jnp.zeros((self.n_routed,), jnp.float32)}
+
+    def apply(self, params, x, state, training, rng):
+        b, T, d = x.shape
+        n, k, held = b * T, self.top_k, self.n_experts
+        xt = x.reshape(n, d)
+        with jax.named_scope("moe_router"):
+            experts, weights, load = route_topk(
+                xt, params["Wg"], state["bias"], k, self.scale)
+            # pairs slot-major: pair j*n + t is token t's j-th selection
+            local = (experts - self.first_expert).T             # [k, n]
+            mine = (local >= 0) & (local < held)
+            p = jnp.where(mine, weights.T, 0.0)
+        with jax.named_scope("moe_dispatch"):
+            # pairs of experts held elsewhere sort behind every group
+            key = jnp.where(mine, local, held).reshape(-1)
+            order = jnp.argsort(key, stable=True).astype(jnp.int32)
+            dst = jnp.argsort(order).astype(jnp.int32)
+            live = mine.reshape(-1)
+            sizes = load[self.first_expert:self.first_expert + held].astype(
+                jnp.int32)
+            cap = -(-n * k // GMM_ROW_TILE) * GMM_ROW_TILE
+            OpProfiler.get().count("moe/dispatch_rows", cap)
+            src = jnp.pad(order, (0, cap - n * k))
+            rows = _take_rows(xt, src, dst, live)
+        with jax.named_scope("moe_experts"):
+            g, u = jnp.split(grouped_matmul(rows, params["W1"], sizes), 2,
+                             axis=-1)
+            out = grouped_matmul(u * jax.nn.silu(g), params["W2"], sizes)
+        with jax.named_scope("moe_combine"):
+            y = _combine(out, p, src, dst, live).astype(xt.dtype)
+        y = y.reshape(b, T, d)
+        if training:
+            state = {**state, "expert_load": state["expert_load"] + load}
+        return y, state
 
 
 class HeadInput(NamedTuple):

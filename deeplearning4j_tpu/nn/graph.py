@@ -720,6 +720,18 @@ class ComputationGraph(FitLoop):
             "graph/fit_chunk", jax.jit(chunk, donate_argnums=(0, 1, 2)),
             donate=(0, 1, 2))
 
+    def expert_load(self, reset: bool = False) -> Dict[str, np.ndarray]:
+        """{node: tokens that selected each expert} of the routed-expert
+        layers, accumulated over the training steps since the last reset.
+        Read here, when asked for: ``fit`` never fetches it."""
+        names = [n for n, st in self._states.items() if "expert_load" in st]
+        out = {n: np.asarray(self._states[n]["expert_load"]) for n in names}
+        if reset:
+            for n in names:
+                self._states[n] = {**self._states[n], "expert_load":
+                                   jnp.zeros_like(self._states[n]["expert_load"])}
+        return out
+
     def evaluate(self, data):
         from ..eval.evaluation import Evaluation
 

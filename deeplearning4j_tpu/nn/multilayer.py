@@ -177,7 +177,7 @@ class MultiLayerNetwork(FitLoop):
         # the output head's configured input dropout applies on this path too
         rng, sub = jax.random.split(rng)
         x = self.layers[i]._maybe_dropout(x, training, sub)
-        new_states.append(states[i])  # output head is stateless; keep list aligned
+        new_states.append(states[i])  # the head's state passes through; keep list aligned
         if rnn_states is not None:
             new_rnn.append(None)
             return x, new_states, new_rnn

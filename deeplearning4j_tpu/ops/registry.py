@@ -120,6 +120,7 @@ def _ensure_loaded() -> None:
         image,
         pallas_attention,
         ssm,
+        moe,
         bitwise,
         embeddings,
     )

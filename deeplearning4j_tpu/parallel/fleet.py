@@ -79,6 +79,9 @@ logger = logging.getLogger("deeplearning4j_tpu")
 SWEEPABLE = ("lr", "l2", "dropout")
 
 
+_fold_in = jax.jit(jax.random.fold_in)
+
+
 class FleetEarlyStop:
     """Per-member early stopping driven from the telemetry bus: a member
     whose loss has not improved by ``min_delta`` for ``patience``
@@ -612,9 +615,12 @@ class FleetTrainer:
                              stacked)
 
         fresh_upd = self.model.conf.global_conf.updater.init(params)
-        new_key = jax.random.fold_in(
+        # through one jitted program with the index as an argument: an
+        # eager fold_in converts the Python int anew, and that conversion
+        # was seen to trace again inside a steady-state region
+        new_key = _fold_in(
             jax.random.PRNGKey(self._seed if seed is None else int(seed)),
-            m)
+            np.uint32(m))
         with self._lock:
             self._params = jax.tree.map(put, self._params, params)
             self._states = jax.tree.map(put, self._states, states)

@@ -249,7 +249,10 @@ class ParallelWrapper:
                 grads, acc_state, density = acc.exchange(grads, acc_state,
                                                          axis)
             loss = jax.lax.pmean(loss, axis)
-            # keep batchnorm running stats consistent across shards
+            # keep layer state consistent across shards: the mean, which is
+            # right for BatchNorm's running statistics (a counter that has
+            # to be summed, RoutedExpertsLayer's expert_load, is not served
+            # by this path yet: ROADMAP.md M2)
             new_states = jax.tree.map(
                 lambda s: jax.lax.pmean(s, axis)
                 if jnp.issubdtype(s.dtype, jnp.floating) else s, new_states)

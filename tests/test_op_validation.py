@@ -1325,6 +1325,48 @@ class TestPallasOps:
                                    rtol=2e-5, atol=2e-5)
 
 
+class TestRoutedExpertOps:
+    """``ops/moe.py``; the layers' cases are in tests/test_moe_layers.py."""
+
+    def test_route_topk(self):
+        rng = np.random.RandomState(8)
+        x = rng.randn(6, 16).astype(np.float32)
+        wg = rng.randn(16, 10).astype(np.float32) * 0.3
+        bias = np.zeros(10, np.float32)
+        bias[3] = 5.0       # selected by every token, weighed by its score
+        experts, weights, load = exec_op("route_topk", x, wg, bias, 2)
+        s = 1.0 / (1.0 + np.exp(-(x @ wg)))
+        ref = np.argsort(-(s + bias), axis=-1)[:, :2]
+        assert np.array_equal(np.asarray(experts), ref)
+        picked = np.take_along_axis(s, ref, -1)
+        np.testing.assert_allclose(
+            np.asarray(weights),
+            picked / (picked.sum(-1, keepdims=True) + 1e-6), rtol=1e-5)
+        assert float(load[3]) == 6 and float(load.sum()) == 12
+
+    def test_grouped_matmul(self):
+        rng = np.random.RandomState(9)
+        x = rng.randn(48, 128).astype(np.float32)
+        w = rng.randn(3, 128, 128).astype(np.float32) * 0.1
+        sizes = np.asarray([20, 0, 17], np.int32)
+        ref = np.zeros((48, 128), np.float32)
+        ref[:20], ref[20:37] = x[:20] @ w[0], x[20:37] @ w[2]
+        for interpret in (None, True):
+            got = exec_op("grouped_matmul", x, w, sizes, row_tile=16,
+                          interpret=interpret)
+            np.testing.assert_allclose(np.asarray(got), ref, atol=2e-5)
+
+    def test_rotary_embedding(self):
+        rng = np.random.RandomState(10)
+        x = rng.randn(2, 5, 8).astype(np.float32)
+        got = exec_op("rotary_embedding", x, np.arange(5), theta=100.0)
+        ang = np.arange(5)[:, None] * 100.0 ** (-np.arange(0, 8, 2) / 8)
+        cos, sin = np.cos(ang), np.sin(ang)
+        a, b = x[..., :4], x[..., 4:]
+        ref = np.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+        np.testing.assert_allclose(np.asarray(got), ref, atol=1e-6)
+
+
 class TestCoverageLedger:
     """The reference's coverage-ledger gate: every registered op must be
     exercised by this suite or explicitly listed as pending with a reason."""

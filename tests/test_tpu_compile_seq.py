@@ -2,7 +2,10 @@
 ``phi4_mini_flash.train_s8k``: the selective scan forward and backward
 (``ops/ssm.py``) and the banded attention forward and backward with grouped
 heads, a window and bfloat16 operands
-(``ops/pallas_attention.causal_attention``). As ``tests/test_tpu_compile.py``:
+(``ops/pallas_attention.causal_attention``); and at the shapes of
+``lfm2_moe.train_b2_s8k``: the grouped expert products forward and backward
+(``ops/moe.py``: 65,536 dispatch rows, 8 experts of 2048 x 3072 and 1536 x
+2048) and the attention with 32 query heads over 8 key/value heads. As ``tests/test_tpu_compile.py``:
 the compiler is installed with jax and compiles for a chip that is DESCRIBED,
 not attached; a compile that passes is not a chip run.
 """
@@ -16,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from deeplearning4j_tpu.ops import moe
 from deeplearning4j_tpu.ops import pallas_attention as pa
 from deeplearning4j_tpu.ops import ssm
 
@@ -82,7 +86,47 @@ def _attention(window, grad, plain=None):
     return (both if grad else fwd), shapes
 
 
+def _grouped(k, n, grad):
+    # the cell's dispatch buffer: 2 x 8,192 tokens x 4 selections, 8 held
+    # experts, bfloat16
+    bf16 = jnp.bfloat16
+    shapes = [((65536, k), bf16), ((8, k, n), bf16), ((8,), jnp.int32)]
+
+    def fwd(x, w, sizes):
+        return moe._gmm(x, w, sizes, moe.GMM_ROW_TILE, False)
+
+    def both(x, w, sizes):
+        return jax.grad(lambda x, w: fwd(x, w, sizes).astype(
+            jnp.float32).sum(), argnums=(0, 1))(x, w)
+
+    return (both if grad else fwd), shapes
+
+
+def _gqa(grad):
+    # lfm2_moe: batch 2, 32 query heads of 64 in groups of 4 over 8 heads
+    bf16 = jnp.bfloat16
+    shapes = [((2, 8, 4, T, 64), bf16), ((2, 8, T, 64), bf16),
+              ((2, 8, T, 64), bf16)]
+
+    def fwd(q, k, v):
+        return pa._band(q, k, v, 0.125, None, pa.BAND_BLOCK, True, False)
+
+    def both(*a):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(*a)
+
+    return (both if grad else fwd), shapes
+
+
 CASES = {
+    "moe_gmm_up_fwd": (lambda: _grouped(2048, 3072, False), 1),
+    # input-gradient and weight-gradient kernels (the forward's result is
+    # not needed for them and is dropped)
+    "moe_gmm_up_bwd": (lambda: _grouped(2048, 3072, True), 2),
+    "moe_gmm_down_fwd": (lambda: _grouped(1536, 2048, False), 1),
+    "moe_gmm_down_bwd": (lambda: _grouped(1536, 2048, True), 2),
+    "attention_gqa4_fwd": (lambda: _gqa(False), 1),
+    "attention_gqa4_fwd_bwd": (lambda: _gqa(True), 2),
     "selective_scan_fwd": (lambda: _scan(False), 1),
     "selective_scan_fwd_bwd": (lambda: _scan(True), 2),
     "attention_full_fwd": (lambda: _attention(None, False), 1),
@@ -108,6 +152,12 @@ def test_sequence_kernel_compiles_for_v5e(case, v5e):
 
 
 def supports(case):
+    if case.startswith("moe_gmm"):
+        return (moe.supports_gmm_kernel(2048, 3072, 2)
+                and moe.supports_gmm_kernel(1536, 2048, 2))
+    if "gqa4" in case:
+        return (pa.supports_band_kernel(T, 64, 64, pa.BAND_BLOCK)
+                and pa.supports_band_bwd_kernel(T, 64, 4, 2))
     if case.startswith("selective_scan"):
         return ssm.supports_scan_kernel(D_INNER, D_STATE)
     if "plain" in case:
