@@ -368,7 +368,7 @@ def bench_resnet50(steps: int, batch: int = 64, image_size: int = 224,
          # img/s: state bytes by dtype + the fused-kernel hit ledger
          "updater_state_bytes": state_bytes,
          "fused_kernel": {k: int(v) for k, v in pstats.items()
-                          if k.startswith("fused_") or k == "sr_draws"},
+                          if k.startswith(("fused_", "sr_"))},
          "xla_roofline": roofline,
          "hbm_watermarks": xprof.watermarks(),
          "data": "synthetic batch, device-resident (train-step config; the "
@@ -1578,9 +1578,9 @@ def bench_mfu_smoke(steps: int, batch: int = 64) -> dict:
     # --- gate 4: interleaved A/B step time -----------------------------
     # Two budgets: the FUSION must be free (fused fp32 vs base ≤5% —
     # measured ~+1% CPU), while the bf16-state config additionally pays
-    # the stochastic-rounding draws (one threefry uint32 per state
-    # element per step — ~10% on CPU where the PRNG is software; on TPU
-    # the hardware PRNG makes it ~free) → ≤20% CPU budget, and its real
+    # the stochastic-rounding draws (one threefry block per parameter
+    # element per step — ~10% on CPU; on the TPU it is vector-unit work
+    # too, PERF.md §6 PR 33) → ≤20% CPU budget, and its real
     # win (0.5x state bytes) is gated above.
     def timed_epoch(name):
         t0 = time.perf_counter()

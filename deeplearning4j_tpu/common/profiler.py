@@ -379,12 +379,20 @@ class OpProfiler:
         update-kernel hits split by execution engine (``fused_buckets_
         pallas`` vs ``fused_buckets_xla``) and the fallbacks onto the
         per-leaf path, the fused BN epilogue hits / residual-chain hits /
-        shape-gate fallbacks, the stochastic-rounding draw count baked
-        into the compiled step (``sr_draws`` — uint32 per element per
-        trace), and the live updater-state byte gauges by dtype
-        (``updater_state_bytes_<dtype>`` + ``_total`` — the footprint
-        the bf16 state mode halves). Counters are trace-time (one bump
-        per compiled step, not per execution); byte gauges are levels.
+        shape-gate fallbacks, what the stochastic rounding of
+        low-precision updater state bakes into the compiled step
+        (``sr_blocks``: ``threefry2x32`` blocks run, one per parameter
+        element whose slots — names sorted — take its halfwords in the
+        order word 0 low, word 0 high, word 1 low, word 1 high;
+        ``sr_elements``: stored elements rounded, 16 bits each;
+        ``sr_draws``: uint32 words the generator returned, two a block
+        and one where ``random_bits_for`` xors them;
+        ``16·sr_elements / (64·sr_blocks)`` is the share of generated
+        bits that are used), and the live updater-state byte gauges by
+        dtype (``updater_state_bytes_<dtype>`` + ``_total`` — the
+        footprint the bf16 state mode halves). Counters are trace-time
+        (one bump per compiled step, not per execution); byte gauges are
+        levels.
         Empty until a fit or fused inference runs."""
         return {k.split("/", 1)[1]: v for k, v in self._counters.items()
                 if k.startswith("precision/")}

@@ -27,6 +27,22 @@ stored moment an unbiased estimator of the fp32 one: E[SR(x)] == x, so
 the error is zero-mean noise instead of a systematic stall
 (tests/test_precision.py pins the unbiasedness).
 
+Where the bits come from: a rounding consumes 16 random bits, and a draw
+is one ``threefry2x32`` block — 20 rounds on the vector unit for two
+32-bit words, four halfwords — on the step's key
+(``fold_in(step key, SR_STREAM_TAG)``, then ``fold_in(.., i)`` for
+parameter leaf ``i``; the dropout stream is never touched). State whose
+slots mirror the parameter tree (every built-in updater's) runs ONE block
+per parameter element (:func:`threefry_words` says what its counter is)
+and hands its halfwords to the slots in the order of their sorted names:
+word 0 low, word 0 high, word 1 low, word 1 high (:func:`slot_bits`;
+AdamW: ``m`` low, ``v`` high, word 1 unused). Any other state draws per
+state leaf. The profiler's ``precision/sr_blocks`` (blocks baked into the
+step), ``precision/sr_elements`` (stored elements rounded) and
+``precision/sr_draws`` (uint32 words the generator returned) say what a
+compiled step pays: ``16·sr_elements / (64·sr_blocks)`` is the share of
+generated bits that are used (0.5 for two slots, 0.25 for one).
+
 Documented numerics envelope (pinned by tests and the ``mfu-smoke``
 bench): with ``state_dtype="bfloat16"`` the per-step training loss tracks
 the fp32-state run within ``|Δ| <= 1e-3 + 0.05 * |loss|`` over the smoke
@@ -40,11 +56,13 @@ changes NOTHING.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.extend.random import threefry2x32_p
 
 from ..common.profiler import OpProfiler
 
@@ -56,19 +74,19 @@ Pytree = Any
 SR_STREAM_TAG = 0x5AD0
 
 
+def _is_floating(leaf) -> bool:
+    return hasattr(leaf, "dtype") and jnp.issubdtype(leaf.dtype,
+                                                     jnp.floating)
+
+
 def cast_floating(tree: Pytree, dtype) -> Pytree:
     """Cast every floating leaf of ``tree`` to ``dtype`` (round-to-nearest),
     leaving integer/bool leaves untouched. THE shared fp32-boundary cast:
     serving's bf16 inference params and the trainer's updater-state
     up/down casts all route through here."""
     dt = jnp.dtype(dtype)
-
-    def c(a):
-        if hasattr(a, "dtype") and jnp.issubdtype(a.dtype, jnp.floating):
-            return jnp.asarray(a, dt)
-        return a
-
-    return jax.tree.map(c, tree)
+    return jax.tree.map(
+        lambda a: jnp.asarray(a, dt) if _is_floating(a) else a, tree)
 
 
 def stochastic_round(x, rbits, dtype=jnp.bfloat16):
@@ -103,33 +121,134 @@ def stochastic_round(x, rbits, dtype=jnp.bfloat16):
     return jnp.where(jnp.isfinite(x32), rounded, x32.astype(jnp.bfloat16))
 
 
-def random_bits_for(key, shape) -> jnp.ndarray:
-    """One uint32 of randomness per element, counted in the profiler's
-    ``precision/sr_draws`` ledger. The counter bumps at TRACE time (the
-    Python body only runs while jax traces), so it records the draws
-    baked into one compiled step — the per-execution draw count of every
-    step that executable runs."""
-    n = 1
-    for d in shape:
-        n *= int(d)
-    OpProfiler.get().count("precision/sr_draws", n)
+def _count_draws(blocks: int, words: int, elements: int) -> None:
+    """The stochastic-rounding ledger. The counters bump at TRACE time (the
+    Python body only runs while jax traces), so they record what is baked
+    into one compiled step — the per-execution counts of every step that
+    executable runs."""
+    prof = OpProfiler.get()
+    prof.count("precision/sr_blocks", blocks)
+    prof.count("precision/sr_draws", words)
+    prof.count("precision/sr_elements", elements)
+
+
+def random_bits_for(key, shape, slots: int = 1) -> jnp.ndarray:
+    """One uint32 of randomness per element (``jax.random.bits``: under
+    ``jax_threefry_partitionable`` one threefry block an element, its two
+    words xored into one), for a caller that rounds ``slots`` stored
+    elements with each word's halfwords (:func:`halfword`) — the flat
+    buckets of :func:`ops.pallas_update.fused_apply`. Counted in the
+    ``precision/sr_*`` ledger: ``sr_draws`` uint32 words returned,
+    ``sr_blocks`` threefry blocks run, ``sr_elements`` stored elements
+    rounded."""
+    n = math.prod(shape)
+    blocks = n if jax.config.jax_threefry_partitionable else (n + 1) // 2
+    _count_draws(blocks, n, n * slots)
     return jax.random.bits(key, shape, dtype=jnp.uint32)
 
 
-def sr_cast_state(state: Pytree, dtype, key) -> Pytree:
-    """Stochastically round every floating leaf of an (fp32) updater-state
-    tree down to ``dtype``, each leaf on its own fold_in-derived stream."""
-    leaves, treedef = jax.tree.flatten(state)
+def halfword(words, which: int):
+    """The one definition of which bits go to which slot: halfword
+    ``which`` of a uint32 array, moved to the LOW 16 bits where
+    :func:`stochastic_round` reads it — 0 the low half, 1 the high half."""
+    return words if which == 0 else words >> jnp.uint32(16)
+
+
+def threefry_words(key, shape):
+    """ONE ``threefry2x32`` block per element of ``shape`` on ``key`` (a
+    raw threefry key pair or a typed threefry key), both output words
+    kept: ``(word0, word1)``, uint32 arrays of ``shape``.
+
+    An element's counter is its row-major index plus ``ndim`` times the
+    key's first word, built from per-axis iotas: no flat array is reshaped
+    between tiled layouts, and — the reason for the offset, which leaves
+    the counters of one key distinct — no index vector is the same for two
+    leaves of one shape. XLA hoists those vectors out of the update's loop
+    fusion, and where two leaves shared them it merged the leaves' updates
+    into one fusion that holds their gradients together (+0.64 GB of step
+    scratch in ``phi4_mini_flash.train_s8k``: PERF.md §6, PR 33)."""
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        key = jax.random.key_data(key)
+    if key.shape[-1:] != (2,):
+        raise ValueError(
+            "stochastic rounding draws threefry2x32 blocks and needs a "
+            f"threefry key (uint32[2]); got key data of shape {key.shape}")
+    n = math.prod(shape)
+    if n > 2 ** 32:
+        raise NotImplementedError(
+            f"a leaf of {n} elements needs a second counter word")
+    k0, k1 = key[..., 0], key[..., 1]
+    hi = lo = jnp.zeros(shape, jnp.uint32)
+    stride = 1
+    for axis in reversed(range(len(shape))):
+        lo = lo + (lax.broadcasted_iota(jnp.uint32, shape, axis)
+                   * jnp.uint32(stride) + k0)
+        stride *= int(shape[axis])
+    _count_draws(n, 2 * n, 0)
+    return threefry2x32_p.bind(k0, k1, hi, lo)
+
+
+# a threefry block is four 16-bit halfwords, handed out in this order:
+# word 0 low, word 0 high, word 1 low, word 1 high
+_HALFWORDS = 4
+
+
+def slot_bits(key, shape, slots: int):
+    """Random bits for rounding ``slots`` stored arrays of ``shape`` (the
+    moments of ONE parameter leaf): a list of ``slots`` uint32 arrays
+    whose low 16 bits are independent uniform halfwords. One block an
+    element serves the first four slots in the order above (AdamW: m the
+    low half of word 0, v its high half); a fifth slot starts a second
+    block on ``fold_in(key, 1)``."""
+    _count_draws(0, 0, math.prod(shape) * slots)
     out = []
-    for i, leaf in enumerate(leaves):
-        if hasattr(leaf, "dtype") and jnp.issubdtype(leaf.dtype,
-                                                     jnp.floating):
-            sub = jax.random.fold_in(key, i)
-            bits = random_bits_for(sub, leaf.shape)
-            out.append(stochastic_round(leaf, bits, dtype))
-        else:
-            out.append(leaf)
-    return jax.tree.unflatten(treedef, out)
+    for first in range(0, slots, _HALFWORDS):
+        sub = jax.random.fold_in(key, first // _HALFWORDS) if first else key
+        words = threefry_words(sub, shape)
+        for j in range(min(_HALFWORDS, slots - first)):
+            out.append(halfword(words[j // 2], j % 2))
+    return out
+
+
+def _mirrors(state, params) -> bool:
+    """Whether ``state`` is slots that each mirror the parameter tree
+    (``{"m": tree, "v": tree}``: every built-in stateful updater)."""
+    if params is None or type(state) is not dict or not state:
+        return False
+    p_leaves, treedef = jax.tree.flatten(params)
+    for slot in state.values():
+        s_leaves, s_def = jax.tree.flatten(slot)
+        if s_def != treedef or any(jnp.shape(a) != jnp.shape(b)
+                                   for a, b in zip(s_leaves, p_leaves)):
+            return False
+    return True
+
+
+def sr_cast_state(state: Pytree, dtype, key, params: Pytree = None) -> Pytree:
+    """Stochastically round every floating leaf of an (fp32) updater-state
+    tree down to ``dtype``.
+
+    State whose slots mirror ``params``: the slots of parameter leaf ``i``
+    share ONE :func:`slot_bits` draw on ``fold_in(key, i)`` — its floating
+    slots, names sorted, take the block's halfwords in order. Any other
+    state (a coupled updater's, a scalar): a draw per state leaf, leaf
+    ``i`` on ``fold_in(key, i)``."""
+    leaves, treedef = jax.tree.flatten(state)
+    if _mirrors(state, params):
+        # a dict flattens in the order of its sorted keys, slot by slot
+        n = len(leaves) // len(state)
+        groups = [range(i, len(leaves), n) for i in range(n)]
+    else:
+        groups = [(i,) for i in range(len(leaves))]
+    for i, group in enumerate(groups):
+        rounded = [j for j in group if _is_floating(leaves[j])]
+        if not rounded:
+            continue
+        bits = slot_bits(jax.random.fold_in(key, i),
+                         jnp.shape(leaves[rounded[0]]), len(rounded))
+        for j, b in zip(rounded, bits):
+            leaves[j] = stochastic_round(leaves[j], b, dtype)
+    return jax.tree.unflatten(treedef, leaves)
 
 
 def state_dtype_of(updater) -> Optional[str]:
@@ -159,7 +278,7 @@ def apply_updater(updater, grads, state, params, iteration, key=None):
     wide = cast_floating(state, jnp.float32)
     new_params, new_state = updater.apply(grads, wide, params, iteration)
     sr_key = jax.random.fold_in(key, SR_STREAM_TAG)
-    new_state = sr_cast_state(new_state, jnp.dtype(sd), sr_key)
+    new_state = sr_cast_state(new_state, jnp.dtype(sd), sr_key, params)
     return new_params, new_state
 
 

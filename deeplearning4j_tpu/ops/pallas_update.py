@@ -110,15 +110,14 @@ def _update_math(kind: str, sc, p, g, slots: Dict[str, Any],
     the stored moments (possibly low-precision); math runs in f32; when
     ``sr_dtype`` is set the new moments are stochastically rounded back
     down with ``bits`` (low halfword first slot, high halfword second)."""
-    from ..learning.precision import stochastic_round
+    from ..learning.precision import halfword, stochastic_round
 
     up = lambda a: a.astype(jnp.float32)  # noqa: E731
 
     def down(a, which: int):
         if sr_dtype is None:
             return a
-        half = bits if which == 0 else (bits >> jnp.uint32(16))
-        return stochastic_round(a, half, sr_dtype)
+        return stochastic_round(a, halfword(bits, which), sr_dtype)
 
     if kind == "sgd":
         return p - sc[0] * g, {}
@@ -271,7 +270,7 @@ def fused_apply(updater, flat_params: Dict[str, Any],
         if sr_dtype is not None and slot_names:
             sub = jax.random.fold_in(jax.random.fold_in(key, SR_STREAM_TAG),
                                      bi)
-            bits = random_bits_for(sub, p.shape)
+            bits = random_bits_for(sub, p.shape, len(slot_names))
         if mode != "xla" and p.dtype == jnp.float32:
             prof.count("precision/fused_buckets_pallas")
             np_, ns = _launch_kernel(kind, sc, p, g, slots, bits, sr_dtype,

@@ -14,8 +14,8 @@ from deeplearning4j_tpu.common.profiler import OpProfiler
 from deeplearning4j_tpu.data import NDArrayDataSetIterator
 from deeplearning4j_tpu.data.dataset import DataSet
 from deeplearning4j_tpu.learning import precision
-from deeplearning4j_tpu.learning.updaters import (Adam, AdamW,
-                                                  GradientUpdater,
+from deeplearning4j_tpu.learning.updaters import (AdaDelta, Adam, AdamW,
+                                                  AMSGrad, GradientUpdater,
                                                   Nesterovs, Sgd)
 from deeplearning4j_tpu.ndarray.rng import set_default_seed
 from deeplearning4j_tpu.nn import (InputType, MultiLayerNetwork,
@@ -133,6 +133,227 @@ class TestStochasticRounding:
         # RTN never leaves 1.0; SR follows the decay toward ~0.45
         assert float(v_rtn) == 1.0
         assert abs(float(v_sr) - v32) < 0.15 * v32
+
+
+# ---------------------------------------------------------------------------
+# where the rounding gets its bits: one threefry block a parameter
+# ---------------------------------------------------------------------------
+
+def bf16_state(updater):
+    updater.state_dtype = "bfloat16"
+    return updater
+
+
+class Slots(GradientUpdater):
+    """An elementwise updater with any number of mirroring slots: slot
+    ``j`` becomes ``state + 2**j * g``, the parameters stay."""
+    elementwise = True
+
+    def __init__(self, names):
+        self.names = list(names)
+        self.learning_rate = 0.1
+        self.state_dtype = "bfloat16"
+
+    def init(self, params):
+        return {n: self._zeros_like(params) for n in self.names}
+
+    def apply(self, grads, state, params, iteration):
+        return params, {
+            n: jax.tree.map(lambda s, g, j=j: s + 2.0 ** j * g, state[n],
+                            grads)
+            for j, n in enumerate(self.names)}
+
+
+def sr_counters():
+    prof = OpProfiler.get()
+    return tuple(int(prof.counter_value(f"precision/{k}"))
+                 for k in ("sr_blocks", "sr_draws", "sr_elements"))
+
+
+class TestBlockDraws:
+    N = 37 * 13 + 13 + 13 * 5          # elements of small_params()
+
+    @pytest.mark.parametrize("name,mk,slots", [
+        ("adam", lambda: Adam(1e-3), 2),
+        ("adamw", lambda: AdamW(1e-3), 2),
+        ("nesterovs", lambda: Nesterovs(0.1, momentum=0.9), 1),
+        ("adadelta", lambda: AdaDelta(), 2),
+        ("amsgrad", lambda: AMSGrad(1e-3), 3),
+        ("five_slots", lambda: Slots("abcde"), 5)])
+    def test_counters_follow_the_shapes(self, name, mk, slots):
+        """One traced step bakes in one block a parameter element for up
+        to four slots; the used-bit share is 16·elements / 64·blocks."""
+        upd = bf16_state(mk())
+        params = small_params()
+        step = jax.jit(lambda g, s, p, k: precision.apply_updater(
+            upd, g, s, p, 0, k))
+        _, new = step(small_grads(params), upd.init(params), params,
+                      jax.random.PRNGKey(0))
+        assert all(l.dtype == BF16 for l in jax.tree.leaves(new))
+        blocks, words, elements = sr_counters()
+        assert blocks == self.N * (1 if slots <= 4 else 2)
+        assert words == 2 * blocks
+        assert elements == self.N * slots
+        share = 16 * elements / (64 * blocks)
+        assert share == min(slots, 4) / 4 if slots <= 4 else share >= 0.5
+        if slots == 2:
+            assert share >= 0.5
+
+    def test_fused_buckets_are_counted_too(self):
+        upd = bf16_state(Adam(1e-3))
+        params = small_params()
+        plan = Zero1Plan(params, 1)
+        flat = plan.flatten(params)
+        pallas_update.fused_apply(
+            upd, flat, plan.flatten(small_grads(params)),
+            upd.init(flat), 0, jax.random.PRNGKey(0), mode="xla")
+        n = sum(int(v.size) for v in flat.values())
+        blocks, words, elements = sr_counters()
+        assert (blocks, words, elements) == (n, n, 2 * n)
+
+    @pytest.mark.parametrize("mk", [
+        lambda: Adam(1e-3), lambda: AdaDelta(),
+        lambda: Nesterovs(0.1, momentum=0.9), lambda: Slots("abcd")],
+        ids=["adam", "adadelta", "nesterovs", "four_slots"])
+    def test_stored_moments_unbiased_through_apply_updater(self, mk):
+        """Mean over many keys of every stored bf16 slot == the f32
+        update, in every halfword position: a slot fed a constant
+        halfword (or none) rounds with a bias and fails."""
+        upd = bf16_state(mk())
+        xs = jnp.asarray([1.004, -3.013, 0.12307, 257.3, 1e-4 * 1.007], f32)
+        params = {"w": jnp.stack([xs, xs[::-1]]),
+                  "w2": jnp.stack([xs[::-1], xs]) * 1.7}
+        grads = jax.tree.map(lambda a: a * 0.37, params)
+        state = upd.init(params)
+        _, want = upd.apply(grads, precision.cast_floating(state, f32),
+                            params, 0)
+        K = 4096
+        keys = jax.random.split(jax.random.PRNGKey(1), K)
+        got = jax.jit(jax.vmap(lambda k: precision.apply_updater(
+            upd, grads, state, params, 0, k)[1]))(keys)
+        for name, leaf in ((n, l) for n in want for l in params):
+            w = np.asarray(want[name][leaf])
+            assert got[name][leaf].dtype == BF16
+            mean = np.asarray(jnp.mean(got[name][leaf].astype(f32), axis=0))
+            ulp = np.abs(w) * 2.0 ** -8 + 1e-12
+            assert np.all(np.abs(mean - w) <= ulp * 4 / np.sqrt(K) + 1e-9), \
+                (name, leaf)
+            # and the slot really is rounded both ways, not truncated
+            assert np.any(np.asarray(got[name][leaf].astype(f32)) != mean)
+
+    def test_slots_of_one_parameter_round_independently(self):
+        """Sample correlation of the round-up indicators of each pair of
+        the four halfword positions over 2^16 elements: within 3 sigma of
+        0 (a halfword shared by two slots reads 1)."""
+        upd = Slots("abcd")
+        n = 1 << 16
+        # halfway between two bf16 neighbours at every slot's scale:
+        # round-up probability 1/2
+        g = jnp.full((256, 256), 1.0 + 2.0 ** -8, f32)
+        params = {"w": g}
+        _, new = jax.jit(lambda k: precision.apply_updater(
+            upd, {"w": g}, upd.init(params), params, 0, k))(
+                jax.random.PRNGKey(11))
+        ups = []
+        for j, name in enumerate("abcd"):
+            want = np.asarray(g) * 2.0 ** j
+            up = (np.asarray(new[name]["w"].astype(f32)) > want).ravel()
+            assert abs(up.mean() - 0.5) < 3 * 0.5 / np.sqrt(n)
+            ups.append(up.astype(np.float64))
+        for a in range(4):
+            for b in range(a + 1, 4):
+                r = np.corrcoef(ups[a], ups[b])[0, 1]
+                assert abs(r) < 3 / np.sqrt(n), (a, b, r)
+
+    def test_halfword_order_is_fixed(self):
+        """Slot names sorted take word 0 low, word 0 high, word 1 low,
+        word 1 high of ONE block on fold_in(key, leaf index)."""
+        key = jax.random.PRNGKey(5)
+        shape = (7, 5)
+        w0, w1 = precision.threefry_words(key, shape)
+        bits = precision.slot_bits(key, shape, 4)
+        want = [w0 & 0xFFFF, w0 >> 16, w1 & 0xFFFF, w1 >> 16]
+        for b, w in zip(bits, want):
+            assert np.array_equal(np.asarray(b) & 0xFFFF, np.asarray(w))
+        # the block is jax's own threefry2x32; an element's counter is its
+        # row-major index plus ndim times the key's first word
+        from jax.extend.random import threefry2x32_p
+        count = (np.arange(35, dtype=np.uint64).reshape(shape)
+                 + 2 * int(key[0])).astype(np.uint32)
+        want0, want1 = threefry2x32_p.bind(
+            key[0], key[1], jnp.zeros(shape, jnp.uint32), jnp.asarray(count))
+        assert np.array_equal(np.asarray(w0), np.asarray(want0))
+        assert np.array_equal(np.asarray(w1), np.asarray(want1))
+        # through the state: leaf i of a mirroring state draws on
+        # fold_in(key, i)
+        x = jax.random.normal(key, shape, f32)
+        state = {"m": [x, x], "v": [2 * x, 2 * x]}
+        out = precision.sr_cast_state(state, BF16, key, [x, x])
+        for i in range(2):
+            u0, _ = precision.threefry_words(jax.random.fold_in(key, i),
+                                             shape)
+            assert np.array_equal(
+                np.asarray(out["m"][i]),
+                np.asarray(precision.stochastic_round(x, u0)))
+            assert np.array_equal(
+                np.asarray(out["v"][i]),
+                np.asarray(precision.stochastic_round(2 * x, u0 >> 16)))
+        assert not np.array_equal(np.asarray(out["m"][0]),
+                                  np.asarray(out["m"][1]))
+
+    @pytest.mark.parametrize("shape", [(7, 5), (13,), (), (1, 3), (3, 2, 5)])
+    def test_odd_small_and_scalar_leaves(self, shape):
+        """An odd leading dimension, a 1-D leaf and a scalar round with
+        the right shapes, and no two slots or elements share bits."""
+        key = jax.random.PRNGKey(2)
+        bits = precision.slot_bits(key, shape, 2)
+        assert [b.shape for b in bits] == [shape, shape]
+        halves = np.stack([np.asarray(b) & 0xFFFF for b in bits]).ravel()
+        assert len(set(halves.tolist())) == halves.size
+        upd = bf16_state(AdamW(1e-3))
+        params = {"p": jnp.ones(shape, f32) * 1.004}
+        _, new = precision.apply_updater(
+            upd, {"p": jnp.full(shape, 0.3, f32)}, upd.init(params), params,
+            0, key)
+        for slot in ("m", "v"):
+            assert new[slot]["p"].shape == shape
+            assert new[slot]["p"].dtype == BF16
+
+    def test_state_that_does_not_mirror_still_rounds_every_leaf(self):
+        """A coupled updater's state (a scalar, a list of another shape,
+        a counter): one draw per floating leaf, integers left alone."""
+        state = {"scale": jnp.asarray(1.004, f32),
+                 "hist": [jnp.full((4, 3), -3.013, f32),
+                          jnp.full((5,), 0.12307, f32)],
+                 "count": jnp.asarray(3, jnp.int32)}
+        params = {"w": jnp.ones((6, 2), f32)}
+        key = jax.random.PRNGKey(4)
+        out = precision.sr_cast_state(state, BF16, key, params)
+        assert out["count"].dtype == jnp.int32 and int(out["count"]) == 3
+        for leaf in (out["scale"], *out["hist"]):
+            assert leaf.dtype == BF16
+        blocks, _, elements = sr_counters()
+        assert blocks == elements == 1 + 12 + 5
+        # each leaf both ways over keys: rounded, not cast
+        outs = jax.vmap(lambda k: precision.sr_cast_state(
+            state, BF16, k, params))(jax.random.split(key, 64))
+        for leaf in (outs["scale"], *outs["hist"]):
+            v = np.asarray(leaf.astype(f32))
+            assert v.min() < v.max()
+        # a slot of another shape than its parameter does not mirror either
+        odd = {"m": {"w": jnp.full((3, 2), 1.004, f32)}}
+        assert precision.sr_cast_state(
+            odd, BF16, key, params)["m"]["w"].dtype == BF16
+
+    def test_a_typed_key_draws_the_raw_key_s_bits(self):
+        raw = jax.random.PRNGKey(9)
+        typed = jax.random.wrap_key_data(raw, impl="threefry2x32")
+        a = precision.threefry_words(raw, (4, 3))
+        b = precision.threefry_words(typed, (4, 3))
+        assert all(np.array_equal(np.asarray(x), np.asarray(y))
+                   for x, y in zip(a, b))
+        with pytest.raises(ValueError, match="threefry"):
+            precision.threefry_words(jnp.zeros((4,), jnp.uint32), (2,))
 
 
 # ---------------------------------------------------------------------------

@@ -9,6 +9,7 @@ run: ``chip_smoke.py`` is what runs the kernels on the device.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -17,7 +18,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from deeplearning4j_tpu.learning import Adam, Nesterovs
+from deeplearning4j_tpu.learning import Adam, AdamW, Nesterovs
+from deeplearning4j_tpu.learning.precision import apply_updater
 from deeplearning4j_tpu.ops.pallas_attention import flash_attention
 from deeplearning4j_tpu.ops.pallas_epilogue import bn_act
 from deeplearning4j_tpu.ops.pallas_update import fused_apply
@@ -116,3 +118,45 @@ def test_kernel_compiles_for_v5e(case, v5e):
     compiled = jax.jit(fn).lower(*args).compile()
     # the Mosaic kernel is in the executable, not an XLA stand-in
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _leaf_update(updater, shapes, v5e):
+    """The per-leaf update of ``shapes`` with bf16 moments, compiled."""
+    updater.state_dtype = "bfloat16"
+    params = {f"l{i}": jax.ShapeDtypeStruct(s, jnp.float32, sharding=v5e)
+              for i, s in enumerate(shapes)}
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+        jax.eval_shape(updater.init, params))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e)
+    return jax.jit(
+        lambda g, s, p, k: apply_updater(updater, g, s, p, jnp.asarray(3), k),
+        donate_argnums=(1, 2)).lower(params, state, params, key).compile()
+
+
+@pytest.mark.parametrize("mk", [lambda: AdamW(1e-4), lambda: Nesterovs(0.1, 0.9)],
+                         ids=["adamw", "nesterovs"])
+def test_leaf_rounding_writes_no_bit_array(mk, v5e):
+    """The threefry block of an element is computed where it is consumed.
+    A bit array laid out apart from the leaf (flat then reshaped, or two
+    words concatenated along an axis) becomes a temporary the size of the
+    leaf in HBM (PR 27, PR 33)."""
+    shape = (2048, 1024)
+    compiled = _leaf_update(mk(), [shape], v5e)
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        shape[0] * shape[1] * 2
+
+
+@pytest.mark.parametrize("shape", [(2048, 1024), (8, 256, 512), (64, 64, 3, 3)],
+                         ids=["matrix", "experts", "conv"])
+def test_same_shaped_leaves_round_in_fusions_of_their_own(shape, v5e):
+    """Three leaves of one shape share no index vector, so XLA does not
+    merge their roundings into one loop fusion — which would hold the
+    three gradients until the last exists (PR 33: +0.64 GB of scratch)."""
+    text = _leaf_update(AdamW(1e-4), [shape] * 3, v5e).as_text()
+    moments = "bf16[" + ",".join(map(str, shape)) + "]"
+    fusions = [line.split(" fusion(")[0]
+               for line in text[text.index("ENTRY"):].splitlines()
+               if re.match(r"\s+%\S+ = .* fusion\(", line)]
+    widest = max(f.count(moments) for f in fusions)
+    assert 1 <= widest <= 2, widest        # m and v of ONE leaf at most
