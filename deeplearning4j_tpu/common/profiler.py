@@ -406,11 +406,15 @@ class OpProfiler:
         its backward, counted where the backward itself is traced) and the
         (query block, key block) pairs the attention band computes and
         leaves out of the square (``attn_key_blocks_run`` /
-        ``attn_key_blocks_skipped``, per head). Trace-time counters: one
-        bump per call site per compiled program, not per execution. Empty
-        until a sequence layer is traced."""
-        return {k.split("/", 1)[1]: v for k, v in self._counters.items()
-                if k.startswith("seq/")}
+        ``attn_key_blocks_skipped``, per head); ``mla_layers``: latent
+        attention layers traced (``LatentAttentionLayer``); and, from the
+        ``mtp/*`` counters, ``mtp_modules``: multi-token-prediction modules
+        traced (``MTPMergeLayer``). Trace-time counters: one bump per call
+        site per compiled program, not per execution. Empty until a sequence
+        layer is traced."""
+        return {k.replace("seq/", "").replace("/", "_"): v
+                for k, v in self._counters.items()
+                if k.startswith(("seq/", "mtp/"))}
 
     def moe_stats(self) -> Dict[str, float]:
         """Routed-expert ledger (``moe/*`` counters, ``ops/moe.py`` and

@@ -130,6 +130,17 @@ class MultiDataSet:
     def num_examples(self) -> int:
         return self.features[0].shape[0]
 
+    def batch_by(self, batch_size: int) -> Iterator["MultiDataSet"]:
+        """Consecutive batches of ``batch_size`` examples, every array cut
+        alike (a missing mask stays missing)."""
+        groups = [g and [a if a is None else a.to_numpy() for a in g]
+                  for g in (self.features, self.labels, self.features_masks,
+                            self.labels_masks)]
+        for i in range(0, self.num_examples(), batch_size):
+            yield MultiDataSet(*[
+                g and [a if a is None else a[i:i + batch_size] for a in g]
+                for g in groups])
+
     def __repr__(self) -> str:
         return (f"MultiDataSet(features={[f.shape for f in self.features]}, "
                 f"labels={[l.shape for l in self.labels]})")

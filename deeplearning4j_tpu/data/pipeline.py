@@ -72,19 +72,11 @@ def iter_datasets(data: Any, batch_size: Optional[int] = None,
     """The one batch-source protocol shared by every fit loop: DataSet
     iterators (reset + __iter__), a single DataSet (optionally re-batched
     by ``batch_size``), a (features, labels) tuple, and — for the graph —
-    MultiDataSet."""
+    a MultiDataSet (re-batched alike)."""
     if isinstance(data, (DataSet, MultiDataSet)):
-        if isinstance(data, MultiDataSet):
-            if not allow_multi:
-                raise TypeError("MultiDataSet requires ComputationGraph.fit")
-            if batch_size is not None:
-                # refusing beats silently training one giant batch
-                raise TypeError(
-                    "a MultiDataSet cannot be re-batched by batch_size; "
-                    "slice it upstream (e.g. an iterator of MultiDataSets) "
-                    "or pass batch_size=None")
-            yield data
-        elif batch_size is None:
+        if isinstance(data, MultiDataSet) and not allow_multi:
+            raise TypeError("MultiDataSet requires ComputationGraph.fit")
+        if batch_size is None:
             yield data
         else:
             yield from data.batch_by(batch_size)

@@ -1,4 +1,5 @@
 from .zoo import (AlexNet, Darknet19, FaceNetNN4Small2, InceptionResNetV1,
-                  LeNet, Lfm2Moe, NASNet, Phi4MiniFlash, ResNet50, SimpleCNN, SqueezeNet,
+                  JoyAILLMFlash, LeNet, Lfm2Moe, NASNet, Phi4MiniFlash,
+                  ResNet50, SimpleCNN, SqueezeNet,
                   TextGenerationLSTM, TinyYOLO, UNet, VGG16, VGG19, Xception,
                   YOLO2, ZooModel, PretrainedType)

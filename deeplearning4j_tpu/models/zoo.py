@@ -1192,3 +1192,151 @@ class Lfm2Moe(ZooModel):
         gc.compute_dtype = self.compute_dtype
         gc.remat_policy = self.remat_policy
         return ComputationGraph(conf).init()
+
+
+class JoyAILLMFlash(ZooModel):
+    """JoyAI-LLM-Flash (``model_type`` ``joyai_llm_flash``, 48B-A2.7B;
+    huggingface.co/jdopensource/JoyAI-LLM-Flash, ``config.json``, which
+    carries the DeepSeek-V3 key set: arXiv:2412.19437 sections 2.1-2.2 and
+    the ``deepseek_v3`` modelling code of Hugging Face ``transformers``): a
+    decoder of pre-norm blocks ``x + MLA(RMSNorm(x))``, ``x + FFN(RMSNorm(x))``
+    whose attention is multi-head latent attention (``LatentAttentionLayer``:
+    a rotated slice of each head in interleaved pairs, ``rope_interleave``)
+    and whose feed-forward is a dense gated MLP in the first
+    ``first_k_dense_replace`` layers and, after them, ``n_routed_experts``
+    routed experts, ``num_experts_per_tok`` a token (sigmoid scores, a
+    selection bias — ``topk_method`` ``noaux_tc`` —, weights normalised over
+    the selected with the family's 1e-20, times ``routed_scaling_factor``)
+    beside ``n_shared_experts`` shared experts that every token meets. A
+    final RMSNorm and an untied head (``LMHeadLayer``). ``mtp``: one
+    multi-token-prediction module after the trunk (``num_nextn_predict_layers``
+    1): ``MTPMergeLayer`` over the trunk's normed output and the next token's
+    embedding, one routed block of its own, its own final RMSNorm and a head
+    that borrows the trunk's matrix; the network's loss is ``L_main +
+    mtp_loss_weight * L_mtp``.
+
+    ``layers``: the published layer indices to build, in order (all when
+    None). ``vocab_rows``: rows of the embedding and of the head held here.
+    ``experts_held``: ``(first, count)`` of the experts of every routed layer
+    that live here (all when None), ``expert_bias``: the ``n_routed_experts``
+    selection biases (zeros when None), both as ``Lfm2Moe``'s. Defaults are
+    the published sizes. Trained through ``ComputationGraph.fit`` on a
+    ``MultiDataSet``: inputs ``ids`` ``[B, T]`` and, with ``mtp``,
+    ``next_ids`` (each position's next token, the main head's labels);
+    labels for ``head`` (next token) and ``mtp_head`` (the token after it,
+    its last position masked)."""
+
+    def __init__(self, layers: Optional[Sequence[int]] = None,
+                 vocab_rows: int = 129280,
+                 experts_held: Optional[Tuple[int, int]] = None,
+                 expert_bias: Optional[Sequence[float]] = None,
+                 mtp: bool = True, hidden_size: int = 2048,
+                 intermediate_size: int = 7168,
+                 moe_intermediate_size: int = 768,
+                 num_attention_heads: int = 32, q_lora_rank: int = 1536,
+                 kv_lora_rank: int = 512, qk_nope_head_dim: int = 128,
+                 qk_rope_head_dim: int = 64, v_head_dim: int = 128,
+                 n_routed_experts: int = 256, n_shared_experts: int = 1,
+                 num_experts_per_tok: int = 8,
+                 routed_scaling_factor: float = 2.5,
+                 first_k_dense_replace: int = 1, num_hidden_layers: int = 40,
+                 n_group: int = 1, topk_group: int = 1,
+                 rms_norm_eps: float = 1e-6, rope_theta: float = 3.2e7,
+                 mtp_loss_weight: float = 0.3,
+                 seq_len: Optional[int] = None,
+                 compute_dtype: Optional[str] = "bfloat16",
+                 state_dtype: Optional[str] = "bfloat16",
+                 remat_policy="full", learning_rate: float = 1e-4,
+                 weight_decay: float = 0.1, seed: int = 123):
+        if (n_group, topk_group) != (1, 1):
+            # group-limited routing picks groups before experts; with one
+            # group that step is the identity, and no other is written here
+            raise ValueError("JoyAILLMFlash routes over one group "
+                             f"(n_group={n_group}, topk_group={topk_group})")
+        self.layers = list(range(num_hidden_layers) if layers is None
+                           else layers)
+        self.vocab_rows = vocab_rows
+        self.experts_held = experts_held or (0, n_routed_experts)
+        self.expert_bias = (None if expert_bias is None
+                            else [float(b) for b in expert_bias])
+        self.mtp, self.mtp_loss_weight = mtp, mtp_loss_weight
+        self.d, self.ff, self.moe_ff = (hidden_size, intermediate_size,
+                                        moe_intermediate_size)
+        self.attention = dict(
+            n_heads=num_attention_heads, q_lora_rank=q_lora_rank,
+            kv_lora_rank=kv_lora_rank, qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            rope_theta=rope_theta, eps=rms_norm_eps)
+        self.experts, self.shared = n_routed_experts, n_shared_experts
+        self.top_k, self.scale = num_experts_per_tok, routed_scaling_factor
+        self.dense_layers, self.eps = first_k_dense_replace, rms_norm_eps
+        self.seq_len = seq_len
+        self.compute_dtype, self.state_dtype = compute_dtype, state_dtype
+        self.remat_policy = remat_policy
+        self.learning_rate, self.weight_decay = learning_rate, weight_decay
+        self.seed = seed
+
+    def _block(self, gb, name: str, prev: str, routed: bool) -> str:
+        """One pre-norm block under the nodes ``<name>_*``; returns its
+        output node."""
+        norm = lambda: L.RMSNormLayer(eps=self.eps)         # noqa: E731
+        gb.add_layer(f"{name}_ln1", norm(), prev)
+        gb.add_layer(f"{name}_attn", L.LatentAttentionLayer(**self.attention),
+                     f"{name}_ln1")
+        gb.add_vertex(f"{name}_add1", ElementWiseVertex(op="add"),
+                      prev, f"{name}_attn")
+        gb.add_layer(f"{name}_ln2", norm(), f"{name}_add1")
+        parts = [f"{name}_ffn"]
+        if not routed:
+            gb.add_layer(f"{name}_ffn", L.GatedMLPLayer(n_ff=self.ff),
+                         f"{name}_ln2")
+        else:
+            first, held = self.experts_held
+            gb.add_layer(f"{name}_ffn", L.RoutedExpertsLayer(
+                n_routed=self.experts, n_experts=held, first_expert=first,
+                n_ff=self.moe_ff, top_k=self.top_k, scale=self.scale,
+                norm_eps=1e-20, selection_bias=self.expert_bias),
+                f"{name}_ln2")
+            # the shared experts: whole on every chip that shares the layer
+            gb.add_layer(f"{name}_shared", L.GatedMLPLayer(
+                n_ff=self.shared * self.moe_ff, scope="shared_expert"),
+                f"{name}_ln2")
+            parts.append(f"{name}_shared")
+        gb.add_vertex(f"{name}_add2", ElementWiseVertex(op="add"),
+                      f"{name}_add1", *parts)
+        return f"{name}_add2"
+
+    def init(self) -> ComputationGraph:
+        updater = AdamW(learning_rate=self.learning_rate, beta1=0.9,
+                        beta2=0.95, epsilon=1e-8,
+                        weight_decay=self.weight_decay)
+        updater.state_dtype = self.state_dtype
+        inputs = ["ids", "next_ids"] if self.mtp else ["ids"]
+        gb = (ComputationGraphConfiguration
+              .graph_builder(NeuralNetConfiguration.builder()
+                             .seed(self.seed).updater(updater))
+              .add_inputs(*inputs))
+        gb.add_layer("embed", L.EmbeddingSequenceLayer(
+            n_out=self.d, weight_init="normal"), "ids")
+        prev = "embed"
+        for l in self.layers:
+            prev = self._block(gb, f"l{l}", prev, l >= self.dense_layers)
+        gb.add_layer("final_ln", L.RMSNormLayer(eps=self.eps), prev)
+        gb.add_layer("head", L.LMHeadLayer(n_out=self.vocab_rows), "final_ln")
+        outputs = ["head"]
+        if self.mtp:
+            gb.add_layer("mtp_merge", L.MTPMergeLayer(
+                embed="embed", eps=self.eps), "final_ln", "next_ids")
+            prev = self._block(gb, "mtp", "mtp_merge", True)
+            gb.add_layer("mtp_final_ln", L.RMSNormLayer(eps=self.eps), prev)
+            gb.add_layer("mtp_head", L.TiedOutputLayer(
+                tied_to="head", loss_weight=self.mtp_loss_weight),
+                "mtp_final_ln")
+            outputs.append("mtp_head")
+        tokens = InputType.recurrent(self.vocab_rows, self.seq_len)
+        conf = (gb.set_outputs(*outputs)
+                .set_input_types(*[tokens] * len(inputs)).build())
+        gc = conf.global_conf
+        gc.compute_dtype = self.compute_dtype
+        gc.remat_policy = self.remat_policy
+        return ComputationGraph(conf).init()

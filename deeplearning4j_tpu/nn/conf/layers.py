@@ -1179,9 +1179,12 @@ class RnnOutputLayer(OutputLayer):
 
 @dataclass
 class LossLayer(Layer):
-    """No-param loss head (reference conf.layers.LossLayer)."""
+    """No-param loss head (reference conf.layers.LossLayer).
+    ``loss_weight``: what this head's score counts for in a network's total
+    loss (``ComputationGraph`` sums its outputs' scores)."""
 
     loss: Union[str, ILossFunction, None] = None
+    loss_weight: float = 1.0
 
     def __post_init__(self):
         if self.loss is None:

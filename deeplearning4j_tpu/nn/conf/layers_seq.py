@@ -12,7 +12,12 @@ positions) and ``RoutedExpertsLayer`` (a dropless top-k expert layer that
 holds a share of the experts; the second kind of layer with state, after
 BatchNorm: a constant selection bias and the accumulated load of each
 expert): ``models.Lfm2Moe`` (the ``lfm2_moe`` family of Hugging Face
-``transformers``). All take and give ``[B, T, F]``. They are plain layer
+``transformers``). ``LatentAttentionLayer`` (attention through low-rank
+latents, a rotated slice of each head, keys wider than values),
+``MTPMergeLayer`` (the entry of a multi-token-prediction module) and
+``LMHeadLayer`` (the head that owns its matrix; ``TiedOutputLayer`` is the
+same head reading another node's): ``models.JoyAILLMFlash`` (the DeepSeek-V3
+family). All take and give ``[B, T, F]``. They are plain layer
 configurations: a ``ComputationGraph`` wires them with a norm layer and
 ``ElementWiseVertex(add)`` into pre-norm residual blocks.
 
@@ -25,7 +30,8 @@ Three things here that the older layers do not use, each read by
 - ``full_precision_params``: leaves that stay float32 under a reduced
   ``compute_dtype`` (a decay rate, a step bias), and ``borrowed_params()``:
   leaves that belong to another node (the tied head reads the embedding's
-  table: one leaf, one gradient, one optimizer state).
+  table: one leaf, one gradient, one optimizer state; a leaf may be borrowed
+  by several nodes, and its gradient is the sum over its uses).
 """
 
 from __future__ import annotations
@@ -61,9 +67,12 @@ def _f32(a):
 
 @dataclass
 class GatedMLPLayer(Layer):
-    """``[g, u] = x W1``; ``y = (u * silu(g)) W2``; no bias."""
+    """``[g, u] = x W1``; ``y = (u * silu(g)) W2``; no bias. ``scope``: the
+    ``jax.named_scope`` its ops carry in a trace (a shared expert beside a
+    routed layer is the same layer under another name)."""
 
     n_ff: int = 0
+    scope: str = "gated_mlp"
 
     def set_input_type(self, input_type):
         self.n_in = input_type.size
@@ -75,7 +84,7 @@ class GatedMLPLayer(Layer):
                 "W2": _normal(k2, (self.n_ff, self.n_in), dtype)}
 
     def apply(self, params, x, state, training, rng):
-        with jax.named_scope("gated_mlp"):
+        with jax.named_scope(self.scope):
             g, u = jnp.split(x @ params["W1"], 2, axis=-1)
             return (u * jax.nn.silu(g)) @ params["W2"], state
 
@@ -374,6 +383,123 @@ class RotaryAttentionLayer(Layer):
             return o @ params["Wo"], state
 
 
+@dataclass
+class LatentAttentionLayer(Layer):
+    """Multi-head latent attention (DeepSeek-V2/V3: arXiv:2405.04434 section
+    2.1, arXiv:2412.19437 section 2.1.1), causal, as it is trained: queries
+    and keys/values come through low-rank latents, and only a slice of each
+    head is rotated. ``c_q = RMSNorm(x W_qa)`` (``q_lora_rank`` wide); ``q =
+    c_q W_qb`` -> ``n_heads`` heads of ``[q_nope (qk_nope_head_dim) ; q_rope
+    (qk_rope_head_dim)]``. ``x W_kva`` -> ``[c_kv (kv_lora_rank) ; k_rope
+    (qk_rope_head_dim)]``; ``c_kv = RMSNorm(c_kv)``; ``c_kv W_kvb`` ->
+    ``n_heads`` heads of ``[k_nope (qk_nope_head_dim) ; v (v_head_dim)]``.
+    Rotary positions 0..T-1 on ``q_rope`` of every head and on the one
+    ``k_rope`` a token, which all heads share, in interleaved pairs ``(2i,
+    2i+1)`` (the family's ``rope_interleave``). ``k_h = [k_nope_h ;
+    k_rope]``; ``softmax(q_h k_h^T / sqrt(qk_nope_head_dim +
+    qk_rope_head_dim) + causal mask) v_h``; heads concatenated into ``W_o``.
+    No bias. The keys are expanded per head and go through
+    ``causal_attention`` with a head width that is not the value's
+    (``[B, H, T, 192]`` / ``[B, H, T, 128]`` at the published sizes); the
+    latent cache and the absorbed products of decoding are serving's."""
+
+    n_heads: int = 0
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 10000.0
+    eps: float = 1e-6
+
+    full_precision_params = ("q_norm", "kv_norm")
+
+    def set_input_type(self, input_type):
+        self.n_in = input_type.size
+        return input_type
+
+    def init_params(self, key, dtype=jnp.float32):
+        ks = jax.random.split(key, 5)
+        d, h = self.n_in, self.n_heads
+        nope, rope, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                          self.v_head_dim)
+        return {"W_qa": _normal(ks[0], (d, self.q_lora_rank), dtype),
+                "q_norm": jnp.ones((self.q_lora_rank,), dtype),
+                "W_qb": _normal(ks[1], (self.q_lora_rank, h * (nope + rope)),
+                                dtype),
+                "W_kva": _normal(ks[2], (d, self.kv_lora_rank + rope), dtype),
+                "kv_norm": jnp.ones((self.kv_lora_rank,), dtype),
+                "W_kvb": _normal(ks[3], (self.kv_lora_rank, h * (nope + dv)),
+                                 dtype),
+                "W_o": _normal(ks[4], (h * dv, d), dtype)}
+
+    def apply(self, params, x, state, training, rng):
+        h, nope, r = self.n_heads, self.qk_nope_head_dim, self.kv_lora_rank
+        b, T, _ = x.shape
+        OpProfiler.get().count("seq/mla_layers")
+
+        def rope(a):        # [B, heads, T, qk_rope_head_dim]
+            return rotary_embedding(a, jnp.arange(T), self.rope_theta,
+                                    interleaved=True)
+
+        with jax.named_scope("mla_q"):
+            c_q = _rms(x @ params["W_qa"], params["q_norm"], self.eps)
+            q = (c_q @ params["W_qb"]).reshape(b, T, h, -1).transpose(
+                0, 2, 1, 3)
+            q = jnp.concatenate([q[..., :nope], rope(q[..., nope:])], -1)
+        with jax.named_scope("mla_kv"):
+            kva = x @ params["W_kva"]
+            c_kv = _rms(kva[..., :r], params["kv_norm"], self.eps)
+            kv = (c_kv @ params["W_kvb"]).reshape(b, T, h, -1).transpose(
+                0, 2, 1, 3)
+            k_rope = rope(kva[:, None, :, r:])      # one a token
+            k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+                k_rope, (b, h, T, k_rope.shape[-1]))], -1)
+        with jax.named_scope("mla_attn"):
+            o = causal_attention(q, k, kv[..., nope:])
+        with jax.named_scope("mla_out"):
+            o = o.transpose(0, 2, 1, 3).reshape(b, T, -1)
+            return o @ params["W_o"], state
+
+
+@dataclass
+class MTPMergeLayer(Layer):
+    """The entry of a multi-token-prediction module (DeepSeek-V3,
+    arXiv:2412.19437 section 2.2). Inputs ``(h, ids)``: the trunk's output
+    ``[B, T, d]`` and the ids ``[B, T]`` of each position's NEXT token; ``m =
+    [RMSNorm_e(Emb(ids)) ; RMSNorm_h(h)] W_eh`` (``2d -> d``) with ``Emb`` the
+    table of node ``embed`` itself (a borrowed leaf). The module's block, its
+    norm and a head that borrows the trunk's follow as graph nodes."""
+
+    embed: str = ""
+    eps: float = 1e-6
+    multi_input = True
+    full_precision_params = ("e_norm", "h_norm")
+
+    def set_input_type(self, input_type):
+        self.n_in = input_type[0].size
+        return input_type[0]
+
+    def borrowed_params(self) -> Dict[str, Tuple[str, str]]:
+        return {"E": (self.embed, "W")}
+
+    def init_params(self, key, dtype=jnp.float32):
+        d = self.n_in
+        return {"e_norm": jnp.ones((d,), dtype),
+                "h_norm": jnp.ones((d,), dtype),
+                "W_eh": _normal(key, (2 * d, d), dtype)}
+
+    def apply(self, params, x, state, training, rng):
+        h, ids = x
+        OpProfiler.get().count("mtp/modules")
+        with jax.named_scope("mtp_merge"):
+            e = jnp.take(params["E"], ids.astype(jnp.int32), axis=0)
+            both = jnp.concatenate(
+                [_rms(e, params["e_norm"], self.eps),
+                 _rms(h, params["h_norm"], self.eps)], -1)
+            return both @ params["W_eh"], state
+
+
 @jax.custom_vjp
 def _take_rows(x, src, dst, live):
     """The dispatch: ``x`` ``[n, d]`` -> the buffer's rows ``[cap, d]``, row
@@ -435,7 +561,7 @@ class RoutedExpertsLayer(Layer):
     ``first_expert <= e < first_expert + n_experts`` (all of them where
     ``n_experts`` is 0). Scores ``s = sigmoid(x Wg)`` over all ``n_routed``
     in float32; selection ``S = top_k(s + bias)``; weights ``p_e = s_e /
-    (sum_{S} s + 1e-6) * scale`` (``ops.moe.route_topk``); expert ``e``:
+    (sum_{S} s + norm_eps) * scale`` (``ops.moe.route_topk``); expert ``e``:
     ``E_e(x) = (silu(x W1_e[:, :ff]) * x W1_e[:, ff:]) W2_e``. Returns ``sum
     over e in S and held of p_e E_e(x)``: the weights' denominator runs over
     all selected experts, held or not, what the experts that are not held
@@ -466,6 +592,7 @@ class RoutedExpertsLayer(Layer):
     n_ff: int = 0
     top_k: int = 1
     scale: float = 1.0
+    norm_eps: float = 1e-6      # in the weights' denominator
     selection_bias: Optional[Sequence[float]] = None    # zeros when None
 
     full_precision_params = ("Wg",)
@@ -496,7 +623,8 @@ class RoutedExpertsLayer(Layer):
         xt = x.reshape(n, d)
         with jax.named_scope("moe_router"):
             experts, weights, load = route_topk(
-                xt, params["Wg"], state["bias"], k, self.scale)
+                xt, params["Wg"], state["bias"], k, self.scale,
+                self.norm_eps)
             # pairs slot-major: pair j*n + t is token t's j-th selection
             local = (experts - self.first_expert).T             # [k, n]
             mine = (local >= 0) & (local < held)
@@ -532,16 +660,17 @@ class HeadInput(NamedTuple):
 
 
 @dataclass
-class TiedOutputLayer(LossLayer):
-    """Language-model head tied to an embedding: ``logits = x E^T`` with
-    ``E`` the table of node ``tied_to`` itself. Labels are ``[B, T]`` integer
-    ids; the loss is the sparse softmax cross-entropy in float32, the mean
-    over a sequence's positions (then over sequences, as every head here).
-    In training the loss is computed ``HEAD_TOKEN_BLOCK`` positions at a
-    time under ``jax.checkpoint``, so the ``[B*T, vocabulary]`` logits are
-    never whole."""
+class LMHeadLayer(LossLayer):
+    """Language-model head that owns its matrix: ``logits = x W^T`` with
+    ``W`` ``[n_out, n_in]`` (a row a vocabulary entry, as an embedding
+    table's), no bias. Labels are ``[B, T]`` integer ids; the loss is the
+    sparse softmax cross-entropy in float32, the mean over a sequence's
+    (unmasked) positions, then over sequences, as every head here, times
+    ``loss_weight`` in the network's total. In training the loss is computed
+    ``HEAD_TOKEN_BLOCK`` positions at a time under ``jax.checkpoint``, so the
+    ``[B*T, vocabulary]`` logits are never whole."""
 
-    tied_to: str = ""
+    n_out: int = 0
 
     def __post_init__(self):
         self.loss = LossSparseMCXENT()
@@ -552,8 +681,12 @@ class TiedOutputLayer(LossLayer):
         self.n_in = input_type.size
         return input_type
 
-    def borrowed_params(self) -> Dict[str, Tuple[str, str]]:
-        return {"W": (self.tied_to, "W")}
+    @property
+    def has_params(self):
+        return True
+
+    def init_params(self, key, dtype=jnp.float32):
+        return {"W": _normal(key, (self.n_out, self.n_in), dtype)}
 
     def pre_output(self, params, x):
         with jax.named_scope("head"):
@@ -594,3 +727,20 @@ class TiedOutputLayer(LossLayer):
                 (xs.reshape(-1, tb, d), ys.reshape(-1, tb),
                  ws.reshape(-1, tb)))
             return total
+
+
+@dataclass
+class TiedOutputLayer(LMHeadLayer):
+    """``LMHeadLayer`` whose ``W`` is node ``tied_to``'s own leaf: an
+    embedding's table (a tied head) or another head's matrix (a
+    multi-token-prediction module's head). One leaf, one gradient (the sum
+    over its uses), one optimizer state."""
+
+    tied_to: str = ""
+
+    @property
+    def has_params(self):
+        return False
+
+    def borrowed_params(self) -> Dict[str, Tuple[str, str]]:
+        return {"W": (self.tied_to, "W")}

@@ -639,29 +639,31 @@ class ComputationGraph(FitLoop):
             if not isinstance(node.layer, (L.OutputLayer, L.LossLayer)):
                 continue
             pre = acts[out_name]
-            if isinstance(pre, L.HeadInput):
-                total = total + _fused_head_score(
-                    node.layer, pre, labels[out_name],
-                    masks.get(out_name) if masks else None, w, w_denom)
-                continue
-            # under reduced-precision compute, reduce the loss in fp32; leave
-            # fp64 runs (gradient checks) untouched
-            if self.conf.global_conf.compute_dtype and \
-                    jnp.issubdtype(pre.dtype, jnp.floating):
-                pre = pre.astype(jnp.float32)
             mask = masks.get(out_name) if masks else None
-            if w is None:
-                total = total + node.layer.loss.compute_score(
-                    labels[out_name], pre, node.layer.activation, mask,
-                    average=True)
+            if isinstance(pre, L.HeadInput):
+                score = _fused_head_score(node.layer, pre, labels[out_name],
+                                          mask, w, w_denom)
             else:
-                # example-weighted mean (shape-stable batching): pad rows
-                # carry w=0 and the divisor is the real example count
-                s = node.layer.loss.compute_score(
-                    labels[out_name], pre, node.layer.activation,
-                    _fold_weights(mask, w), average=False)
-                total = total + s / (w_denom if w_denom is not None
-                                     else jnp.maximum(jnp.sum(w), 1.0))
+                # under reduced-precision compute, reduce the loss in fp32;
+                # leave fp64 runs (gradient checks) untouched
+                if self.conf.global_conf.compute_dtype and \
+                        jnp.issubdtype(pre.dtype, jnp.floating):
+                    pre = pre.astype(jnp.float32)
+                if w is None:
+                    score = node.layer.loss.compute_score(
+                        labels[out_name], pre, node.layer.activation, mask,
+                        average=True)
+                else:
+                    # example-weighted mean (shape-stable batching): pad rows
+                    # carry w=0 and the divisor is the real example count
+                    score = node.layer.loss.compute_score(
+                        labels[out_name], pre, node.layer.activation,
+                        _fold_weights(mask, w), average=False) / (
+                            w_denom if w_denom is not None
+                            else jnp.maximum(jnp.sum(w), 1.0))
+            # a LossLayer's loss_weight (an OutputLayer has none: 1)
+            weight = getattr(node.layer, "loss_weight", 1.0)
+            total = total + (score if weight == 1.0 else weight * score)
         gc = self.conf.global_conf
         reg = 0.0
         for lname, lp in params.items():

@@ -29,7 +29,8 @@ gradient). Two implementations:
 Which one ran is counted when the step is traced (``moe/gmm_kernel``,
 ``moe/gmm_fallback``).
 
-``rotary_embedding``: rotate-half rotary position embedding.
+``rotary_embedding``: rotary position embedding, the pairs a half apart
+(rotate-half) or interleaved.
 """
 
 from __future__ import annotations
@@ -72,7 +73,7 @@ def route_topk(x, w_gate, bias, k: int, scale: float = 1.0,
     _, experts = lax.top_k(scores + bias.astype(f32), k)
     picked = jnp.take_along_axis(scores, experts, axis=-1)
     weights = picked / (jnp.sum(picked, -1, keepdims=True) + norm_eps) * scale
-    # a comparison against every expert, not a scatter: 64 bins
+    # a comparison against every expert, not a scatter: E bins
     load = jnp.sum(experts[..., None] == jnp.arange(w_gate.shape[1]),
                    axis=(0, 1), dtype=jnp.float32)
     return experts.astype(jnp.int32), weights, load
@@ -82,17 +83,31 @@ def route_topk(x, w_gate, bias, k: int, scale: float = 1.0,
 
 
 @op("rotary_embedding", "nn")
-def rotary_embedding(x, positions, theta: float = 10000.0):
-    """Rotate-half rotary embedding over the whole last axis ``D`` (even) of
-    ``x`` ``[..., T, D]`` at ``positions`` ``[T]``: ``inv_freq_i =
-    theta^(-2i/D)``, ``cos`` and ``sin`` of ``positions * inv_freq`` repeated
-    over the two halves, ``x cos + rotate_half(x) sin`` with
-    ``rotate_half([a, b]) = [-b, a]``. Computed in float32 (float64 stays),
-    returned in ``x``'s dtype."""
+def rotary_embedding(x, positions, theta: float = 10000.0,
+                     interleaved: bool = False):
+    """Rotary position embedding over the whole last axis ``D`` (even) of
+    ``x`` ``[..., T, D]`` at ``positions`` ``[T]``, ``inv_freq_i =
+    theta^(-2i/D)``. Rotate-half (the default): the pair of frequency ``i``
+    is ``(x_i, x_{i+D/2})``; ``cos`` and ``sin`` of ``positions * inv_freq``
+    repeated over the two halves, ``x cos + rotate_half(x) sin`` with
+    ``rotate_half([a, b]) = [-b, a]``. ``interleaved``: the pair is
+    ``(x_{2i}, x_{2i+1})`` (``rope_interleave`` of the DeepSeek-V3 family):
+    ``y_{2i} = x_{2i} cos_i - x_{2i+1} sin_i``, ``y_{2i+1} = x_{2i+1} cos_i +
+    x_{2i} sin_i``, each element's partner fetched by a shift along the axis,
+    so nothing is reshaped. Computed in float32 (float64 stays), returned in
+    ``x``'s dtype."""
     d = x.shape[-1]
     wide = jnp.promote_types(x.dtype, jnp.float32)
     inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=wide) / d))
     angles = positions.astype(wide)[:, None] * inv_freq[None, :]
+    if interleaved:
+        cos = jnp.repeat(jnp.cos(angles), 2, axis=-1)
+        sin = jnp.repeat(jnp.sin(angles), 2, axis=-1)
+        xw = x.astype(wide)
+        partner = jnp.where(jnp.arange(d) % 2 == 0,
+                            -jnp.roll(xw, -1, axis=-1),
+                            jnp.roll(xw, 1, axis=-1))
+        return (xw * cos + partner * sin).astype(x.dtype)
     cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)
     sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)
     xw = x.astype(wide)

@@ -320,6 +320,58 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     _close(y, total)
 
 
+# --- the share test at the DeepSeek-V3 family's form ----------------------------
+
+JOYAI = _load(os.path.join(BENCH, "configs", "joyai_llm_flash.py"),
+              "bench_conf_joyai_shares")
+JOYAI_SIZES = JOYAI.sizes_of(json.load(open(os.path.join(
+    BENCH, "configs", "joyai_llm_flash.json"))), True)
+
+
+def test_the_sixteen_shares_and_one_shared_expert_add_up_to_the_uncut_layer():
+    """``joyai_llm_flash``'s cut: sixteen layers that hold experts
+    16c..16c+15 of the same 256 behind the same router (top-8, scale 2.5,
+    epsilon 1e-20) give routed parts that, with the shared expert — which
+    every chip computes alike — counted ONCE, add up to the uncut reference
+    layer's output; and every share counts the same ``expert_load``."""
+    sz = JOYAI_SIZES
+    ref = JOYAI.ref_ops(sz, compare.EXACT)
+    e, n, ff = sz["router_width"], sz["n_routed_experts"], \
+        sz["moe_intermediate_size"]
+    assert (e, n, sz["num_experts_per_tok"], sz["hidden_size"]) == (
+        256, 16, 8, D)
+    bias = jnp.asarray(sz["expert_bias"], F32)
+
+    def routed(first, held):
+        return _layer(L.RoutedExpertsLayer(
+            n_routed=e, n_experts=held, first_expert=first, n_ff=ff,
+            top_k=sz["num_experts_per_tok"],
+            scale=sz["routed_scaling_factor"], norm_eps=sz["route_norm_eps"],
+            selection_bias=sz["expert_bias"]))
+
+    whole, p = routed(0, e)
+    shared, ps = _layer(L.GatedMLPLayer(n_ff=sz["n_shared_experts"] * ff,
+                                        scope="shared_expert"))
+    x = _x(5)
+    xt = x.reshape(-1, D)
+    experts, weights, load = ref.route(p, bias, xt)
+    uncut = (ref.experts_of(p, xt, experts, weights, held=(0, e))
+             + ref.mlp(ps, xt))
+    total, loads = shared.apply(ps, x, {}, True, None)[0], []
+    for c in range(e // n):
+        share, _ = routed(n * c, n)
+        pc = {"Wg": p["Wg"], "W1": p["W1"][n * c:n * c + n],
+              "W2": p["W2"][n * c:n * c + n]}
+        y, st = share.apply(pc, x, share.init_state(), True, None)
+        total = total + y
+        loads.append(np.asarray(st["expert_load"]))
+    _close(total.reshape(-1, D), uncut)
+    assert all(np.array_equal(l, np.asarray(load)) for l in loads)
+    assert float(np.asarray(load).sum()) == B * T * sz["num_experts_per_tok"]
+    y, _ = whole.apply(p, x, whole.init_state(), True, None)
+    _close(y.reshape(-1, D) + ref.mlp(ps, xt), uncut)
+
+
 # --- the five-layer model through ComputationGraph.fit ------------------------
 
 SEQ = 32

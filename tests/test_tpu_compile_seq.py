@@ -5,7 +5,11 @@ heads, a window and bfloat16 operands
 (``ops/pallas_attention.causal_attention``); and at the shapes of
 ``lfm2_moe.train_b2_s8k``: the grouped expert products forward and backward
 (``ops/moe.py``: 65,536 dispatch rows, 8 experts of 2048 x 3072 and 1536 x
-2048) and the attention with 32 query heads over 8 key/value heads. As ``tests/test_tpu_compile.py``:
+2048) and the attention with 32 query heads over 8 key/value heads; and at
+the shapes of ``joyai_llm_flash.train_b2_s8k``: the attention with 32 heads
+whose keys are 192 wide and whose values are 128 wide (latent attention as it
+is trained), and the grouped products over 131,072 dispatch rows and 16
+experts of 2048 x 1536 and 768 x 2048. As ``tests/test_tpu_compile.py``:
 the compiler is installed with jax and compiles for a chip that is DESCRIBED,
 not attached; a compile that passes is not a chip run.
 """
@@ -86,11 +90,11 @@ def _attention(window, grad, plain=None):
     return (both if grad else fwd), shapes
 
 
-def _grouped(k, n, grad):
+def _grouped(k, n, grad, rows=65536, held=8):
     # the cell's dispatch buffer: 2 x 8,192 tokens x 4 selections, 8 held
-    # experts, bfloat16
+    # experts, bfloat16 (joyai_llm_flash: x 8 selections, 16 held)
     bf16 = jnp.bfloat16
-    shapes = [((65536, k), bf16), ((8, k, n), bf16), ((8,), jnp.int32)]
+    shapes = [((rows, k), bf16), ((held, k, n), bf16), ((held,), jnp.int32)]
 
     def fwd(x, w, sizes):
         return moe._gmm(x, w, sizes, moe.GMM_ROW_TILE, False)
@@ -118,7 +122,31 @@ def _gqa(grad):
     return (both if grad else fwd), shapes
 
 
+def _mla(grad):
+    # joyai_llm_flash: batch 2, 32 heads, keys [k_nope (128) ; k_rope (64)]
+    # expanded per head, values 128 wide: 192 is one and a half passes of a
+    # 128-wide unit
+    bf16 = jnp.bfloat16
+    shapes = [((2, 32, 1, T, 192), bf16), ((2, 32, T, 192), bf16),
+              ((2, 32, T, 128), bf16)]
+
+    def fwd(q, k, v):
+        return pa._band(q, k, v, 192 ** -0.5, None, pa.BAND_BLOCK, True, False)
+
+    def both(*a):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(*a)
+
+    return (both if grad else fwd), shapes
+
+
 CASES = {
+    "attention_mla_d192_dv128_fwd": (lambda: _mla(False), 1),
+    "attention_mla_d192_dv128_fwd_bwd": (lambda: _mla(True), 2),
+    "moe_gmm_e16_up_fwd_bwd": (
+        lambda: _grouped(2048, 1536, True, 131072, 16), 2),
+    "moe_gmm_e16_down_fwd_bwd": (
+        lambda: _grouped(768, 2048, True, 131072, 16), 2),
     "moe_gmm_up_fwd": (lambda: _grouped(2048, 3072, False), 1),
     # input-gradient and weight-gradient kernels (the forward's result is
     # not needed for them and is dropped)
@@ -152,6 +180,12 @@ def test_sequence_kernel_compiles_for_v5e(case, v5e):
 
 
 def supports(case):
+    if "mla" in case:
+        return (pa.supports_band_kernel(T, 192, 128, pa.BAND_BLOCK)
+                and pa.supports_band_bwd_kernel(T, 192, 1, 2))
+    if case.startswith("moe_gmm_e16"):
+        return (moe.supports_gmm_kernel(2048, 1536, 2)
+                and moe.supports_gmm_kernel(768, 2048, 2))
     if case.startswith("moe_gmm"):
         return (moe.supports_gmm_kernel(2048, 3072, 2)
                 and moe.supports_gmm_kernel(1536, 2048, 2))
