@@ -406,7 +406,11 @@ class OpProfiler:
         its backward, counted where the backward itself is traced) and the
         (query block, key block) pairs the attention band computes and
         leaves out of the square (``attn_key_blocks_run`` /
-        ``attn_key_blocks_skipped``, per head); ``mla_layers``: latent
+        ``attn_key_blocks_skipped``, per query head);
+        ``attn_fwd_grid_steps``: the grid steps the forward kernel's calls
+        issue, one a pair a key/value head (``attn_key_blocks_run`` over the
+        query heads a key/value head; 0 from a call site on the XLA path);
+        ``mla_layers``: latent
         attention layers traced (``LatentAttentionLayer``); and, from the
         ``mtp/*`` counters, ``mtp_modules``: multi-token-prediction modules
         traced (``MTPMergeLayer``). Trace-time counters: one bump per call

@@ -16,9 +16,12 @@ shape of the mask:
   a causal mask without one is ``causal_attention``;
 - ``causal_attention`` (``flash_attention_fwd``, ``flash_attention_bwd``):
   causal, optionally banded by a window, grouped-query heads, a value wider
-  than the head, operands in the inputs' dtype with float32 accumulation. Only
-  the key blocks inside the band are fetched or computed, forward and
-  backward; the backward is one kernel over the same block pairs.
+  than the head, operands in the inputs' dtype with float32 accumulation. Both
+  kernels walk the band as a scalar-prefetched list of (query block, key
+  block) pairs — the forward query block first, the backward key block first
+  — with a key/value head's query heads inside one grid step: no grid step is
+  issued, and nothing fetched or computed, for a pair outside the band.
+  ``seq/attn_fwd_grid_steps`` counts the steps the forward's calls issue.
 
 Nothing of size T×T ever materializes in either. ``interpret=True`` runs a
 kernel in Pallas interpret mode (the CPU tests); on the TPU the same kernel
@@ -353,10 +356,18 @@ def flash_attention(q, k, v, causal: bool = False,
 # lo(i)..i only (lo = 0 without a window), forward and backward: what the
 # mask empties is neither fetched nor computed, and no temporary is larger
 # than one [heads, block, block] tile. On the TPU both directions are Pallas
-# kernels: ``flash_attention_fwd``, which also hands back each row's
-# log-sum-exp, and ``flash_attention_bwd``, one pass over the same block
-# pairs from that log-sum-exp (five products a pair, the score tile never
-# leaving VMEM). The XLA loops below are the path of every other case (the
+# kernels whose grid is (key/value head, block pair of the band): the pairs
+# come as two scalar-prefetched index lists (``_band_pairs``) and a group's
+# query heads share one step.
+# ``flash_attention_fwd`` walks the pairs query block first (two products a
+# pair, the running statistics in scratch while the query block stays) and
+# also hands back each row's log-sum-exp, laid out ``[head, group, query
+# block, row]``; ``flash_attention_bwd`` walks them key block first, one pass
+# from that log-sum-exp (five products a pair, the score tile never leaving
+# VMEM). ``seq/attn_fwd_grid_steps`` counts the grid steps the forward's call
+# issues: the band's pairs a key/value head, ``attn_key_blocks_run / g``
+# (``b·hq·n·`` the band's width on the clamped rectangle this grid replaced).
+# The XLA loops below are the path of every other case (the
 # CPU, a shape ``supports_band_kernel`` / ``supports_band_bwd_kernel``
 # refuses) and what the tests hold the kernels to.
 
@@ -372,18 +383,19 @@ def _band_lo(i, bs: int, window: Optional[int]):
     return jnp.maximum(i * bs - (window - 1), 0) // bs
 
 
-def _band_pairs(n: int, bs: int, window: Optional[int]) -> tuple:
-    """The band's (key block, query block) pairs as two int32 arrays, key
-    block first: the order the backward kernel walks them in."""
+def _band_pairs(n: int, bs: int, window: Optional[int],
+                query_first: bool = False) -> tuple:
+    """The band's block pairs as two int32 arrays (key blocks, query
+    blocks), in the order a kernel walks them: key block first (the
+    backward: dk and dv stay while the key block stays) or query block first
+    (the forward: the accumulator stays while the query block stays); the
+    other index ascends inside."""
     lo = [max(i * bs - (window - 1), 0) // bs if window else 0
           for i in range(n)]
     pairs = [(j, i) for j in range(n) for i in range(j, n) if lo[i] <= j]
+    if query_first:
+        pairs.sort(key=lambda ji: ji[::-1])
     return tuple(np.asarray(a, np.int32) for a in zip(*pairs))
-
-
-def _band_width(n: int, bs: int, window: Optional[int]) -> int:
-    """Most key blocks any query block sees."""
-    return int(np.bincount(_band_pairs(n, bs, window)[1]).max())
 
 
 def band_blocks(T: int, bs: int, window: Optional[int]) -> tuple:
@@ -491,78 +503,111 @@ def _band_bwd_xla(q, k, v, o, lse, do, scale, window, bs):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-def _band_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                 *, scale: float, bs: int, nb: int, window: Optional[int]):
-    # traced in the 32-bit world, like _fa_kernel above
-    i = pl.program_id(1)
-    jj = pl.program_id(2)
-    kb = _band_lo(i, bs, window) + jj
+def _band_fwd_kernel(pj_ref, pi_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                     m_scr, l_scr, acc_scr,
+                     *, scale: float, bs: int, window: Optional[int]):
+    # One grid step is one block pair of the band (``_band_pairs``, query
+    # block i outer, the key blocks j it sees inner) for the G query heads of
+    # one key/value head: the k and v tiles are fetched once for the group,
+    # the heads' running maximum, denominator and unnormalised output stay in
+    # scratch while i stays, and o and the log-sum-exp are written as i
+    # changes. Tiles are [query, key]; the row statistics are kept as
+    # [block, 128] tiles whose lanes all hold the row's value, so that they
+    # meet the score tile and the accumulator lane for lane and nothing is
+    # reduced across lanes but the tile itself. Traced in the 32-bit world,
+    # like _fa_kernel.
+    f32 = jnp.float32
+    nt = (((1,), (1,)), ((), ()))
+    p, last_p = pl.program_id(1), pl.num_programs(1) - 1
+    j, i = pj_ref[p], pi_ref[p]
+    dv = v_ref.shape[-1]
 
-    @pl.when(jj == 0)
-    def _init():
+    def lanes(stat, width):
+        """A [block, 128] statistic against a tile ``width`` lanes wide."""
+        reps = -(-width // 128)
+        wide = stat if reps == 1 else jnp.tile(stat, (1, reps))
+        return wide if width == reps * 128 else wide[:, :width]
+
+    @pl.when((p == 0) | (pi_ref[jnp.maximum(p - 1, 0)] != i))
+    def _new_query_block():
         m_scr[...] = jnp.full_like(m_scr, _MASKED)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(kb <= i)
-    def _compute():
-        v = v_ref[0]
-        s = lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        s = jnp.where(_band_mask(i, kb, bs, window), s, _MASKED)
-        m_prev = jnp.max(m_scr[...], axis=1, keepdims=True)      # [bs, 1]
-        l_prev = jnp.max(l_scr[...], axis=1, keepdims=True)
+    # one tile body, masked on every pair: the chip read a second, unmasked
+    # body under ``pl.when`` (as the backward has) no faster at any shape
+    k, v = k_ref[0], v_ref[0]
+    ok = _band_mask(i, j, bs, window)
+    for g in range(q_ref.shape[0]):
+        s = lax.dot_general(q_ref[g], k, nt,
+                            preferred_element_type=f32) * scale
+        s = jnp.where(ok, s, _MASKED)
+        m_prev = m_scr[g]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        pr = jnp.exp(s - lanes(m_new, bs))
         alpha = jnp.exp(m_prev - m_new)
-        acc_scr[...] = acc_scr[...] * alpha + lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ones = jnp.ones((1, m_scr.shape[1]), jnp.float32)
-        m_scr[...] = m_new * ones
-        l_scr[...] = (l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)) * ones
+        l_scr[g] = l_scr[g] * alpha + jnp.sum(pr, axis=1, keepdims=True)
+        acc_scr[g] = acc_scr[g] * lanes(alpha, dv) + jnp.dot(
+            pr.astype(v.dtype), v, preferred_element_type=f32)
+        m_scr[g] = m_new
 
-    @pl.when(jj == nb - 1)
-    def _finalize():
-        l = jnp.max(l_scr[...], axis=1, keepdims=True)
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[...] + jnp.log(l_scr[...])
+    @pl.when((p == last_p) | (pi_ref[jnp.minimum(p + 1, last_p)] != i))
+    def _query_block_done():
+        for g in range(q_ref.shape[0]):
+            l = l_scr[g]
+            o_ref[g] = (acc_scr[g] / lanes(l, dv)).astype(o_ref.dtype)
+            # the rows' log-sum-exp as one row of the head's [n, block]
+            lse_ref[0, g, pl.ds(i, 1), :] = (m_scr[g] + jnp.log(l)).T[:1]
+
+
+@functools.lru_cache(maxsize=None)
+def _band_fwd_call(h, g, T, d, dv, bs, window, scale, dtype, interpret):
+    """The forward's ``pallas_call`` for one shape, kept as
+    ``_band_bwd_call`` is: a model's layers of one shape trace the unrolled
+    body once."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    n = T // bs
+    # q and o stay [query head, T, ·], a key/value head's g heads one block
+    rows_map = lambda h, p, pj, pi: (h, pi[p], 0)           # noqa: E731
+    kv_map = lambda h, p, pj, pi: (h, pj[p], 0)             # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_band_fwd_kernel, scale=scale, bs=bs,
+                          window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(h, len(_band_pairs(n, bs, window)[0])),
+            in_specs=[pl.BlockSpec((g, bs, d), rows_map),
+                      pl.BlockSpec((1, bs, d), kv_map),
+                      pl.BlockSpec((1, bs, dv), kv_map)],
+            out_specs=[pl.BlockSpec((g, bs, dv), rows_map),
+                       pl.BlockSpec((1, g, n, bs),
+                                    lambda h, p, pj, pi: (h, 0, 0, 0))],
+            scratch_shapes=[pltpu.VMEM((g, bs, 128), f32),
+                            pltpu.VMEM((g, bs, 128), f32),
+                            pltpu.VMEM((g, bs, dv), f32)]),
+        out_shape=[jax.ShapeDtypeStruct((h * g, T, dv), dtype),
+                   jax.ShapeDtypeStruct((h, g, n, bs), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret, name="flash_attention_fwd")
 
 
 def _band_fwd_pallas(q, k, v, scale, window, bs, interpret):
-    """Same contract as ``_band_fwd_xla``; the group's heads index their
-    key/value head in the block maps, nothing is repeated."""
-    from jax.experimental.pallas import tpu as pltpu
-
+    """Same contract as ``_band_fwd_xla``, as one kernel over the band's
+    block pairs, query block first; k and v are never repeated for a group's
+    heads, and the log-sum-exp leaves the kernel as the backward reads it."""
     b, hk, g, T, d = q.shape
     dv = v.shape[-1]
-    n = T // bs
-    nb = _band_width(n, bs, window)
-    q3 = q.reshape(b * hk * g, T, d)
-    k3, v3 = k.reshape(b * hk, T, d), v.reshape(b * hk, T, dv)
-
-    def kv_map(h, i, j):
-        return (h // g, jnp.minimum(_band_lo(i, bs, window) + j, i), 0)
-
+    h = b * hk
+    pj, pi = _band_pairs(T // bs, bs, window, query_first=True)
     with jax.enable_x64(False):
-        o, lse = pl.pallas_call(
-            functools.partial(_band_kernel, scale=scale, bs=bs, nb=nb,
-                              window=window),
-            grid=(b * hk * g, n, nb),
-            in_specs=[pl.BlockSpec((1, bs, d), lambda h, i, j: (h, i, 0)),
-                      pl.BlockSpec((1, bs, d), kv_map),
-                      pl.BlockSpec((1, bs, dv), kv_map)],
-            out_specs=[pl.BlockSpec((1, bs, dv), lambda h, i, j: (h, i, 0)),
-                       pl.BlockSpec((1, bs, 128), lambda h, i, j: (h, i, 0))],
-            out_shape=[jax.ShapeDtypeStruct((b * hk * g, T, dv), q.dtype),
-                       jax.ShapeDtypeStruct((b * hk * g, T, 128),
-                                            jnp.float32)],
-            scratch_shapes=[pltpu.VMEM((bs, 128), jnp.float32),
-                            pltpu.VMEM((bs, 128), jnp.float32),
-                            pltpu.VMEM((bs, dv), jnp.float32)],
-            interpret=interpret, name="flash_attention_fwd",
-        )(q3, k3, v3)
-    return (o.reshape(b, hk, g, T, dv), lse[..., 0].reshape(b, hk, g, T))
+        o, lse = _band_fwd_call(
+            h, g, T, d, dv, bs, window, scale, q.dtype, interpret,
+        )(jnp.asarray(pj), jnp.asarray(pi), q.reshape(h * g, T, d),
+          k.reshape(h, T, d), v.reshape(h, T, dv))
+    return o.reshape(b, hk, g, T, dv), lse.reshape(b, hk, g, T)
 
 
 def _band_bwd_kernel(pj_ref, pi_ref, qt_ref, k_ref, kt_ref, v_ref, do_ref,
@@ -759,7 +804,11 @@ def causal_attention(q, k, v, window: Optional[int] = None,
     does not fit VMEM (``supports_band_bwd_kernel``); everywhere else both
     are XLA loops. ``seq/attn_kernel`` / ``seq/attn_fallback`` count the
     forward's call sites, ``seq/attn_bwd_kernel`` / ``seq/attn_bwd_fallback``
-    the backward's, as a step is traced."""
+    the backward's, as a step is traced; ``seq/attn_key_blocks_run`` /
+    ``seq/attn_key_blocks_skipped`` the band's block pairs a query head and
+    what the square has beside them; ``seq/attn_fwd_grid_steps`` the grid
+    steps the forward kernel's call issues — a pair for each key/value head,
+    ``attn_key_blocks_run / (Hq/Hk)``, and 0 on the XLA path."""
     from ..common.environment import Environment
 
     b, hq, T, d = q.shape
@@ -782,6 +831,7 @@ def causal_attention(q, k, v, window: Optional[int] = None,
     run, skipped = band_blocks(Tp, bs, window)
     prof.count("seq/attn_key_blocks_run", run * b * hq)
     prof.count("seq/attn_key_blocks_skipped", skipped * b * hq)
+    prof.count("seq/attn_fwd_grid_steps", run * b * hk if kernel else 0)
     scale = float(sm_scale if sm_scale is not None else 1.0 / np.sqrt(d))
     o = _band(q.reshape(b, hk, g, Tp, d), k, v, scale,
               int(window) if window else None, bs, kernel, bool(interpret))

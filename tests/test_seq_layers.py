@@ -232,6 +232,33 @@ def test_causal_attention_band(t, block, window, interpret, d, dv):
     _tree_close(g(prog), g(ref))
 
 
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
+                                       ("bfloat16", 2.0 ** -7)])
+@pytest.mark.parametrize("d,dv", [(64, 64), (64, 128), (192, 128), (64, 192)])
+@pytest.mark.parametrize("window", [None, 128, 200])
+@pytest.mark.parametrize("g", [1, 2, 4])
+def test_band_fwd_kernel_equals_the_loops(g, window, d, dv, dtype, tol):
+    """o and the log-sum-exp of the Pallas forward (interpret mode) against
+    ``_band_fwd_xla``'s on the same q, k, v, each by name: a key/value head's
+    1, 2 or 4 query heads in one grid step; the whole causal band, a window of
+    one block and one the block does not divide; the cells' head and value
+    widths, and a value one and a half statistics tiles wide. o leaves the kernel in the inputs' dtype (bfloat16: an ulp of the
+    largest), the log-sum-exp in float32 from float32 sums on both sides."""
+    t, block = 384, 128
+    ks = jax.random.split(jax.random.PRNGKey(g), 3)
+    q, k, v = (jax.random.normal(key, shape, F32).astype(dtype)
+               for key, shape in zip(ks, [(1, 2, g, t, d), (1, 2, t, d),
+                                          (1, 2, t, dv)]))
+    want = pa._band_fwd_xla(q, k, v, d ** -0.5, window, block)
+    got = pa._band_fwd_pallas(q, k, v, d ** -0.5, window, block, True)
+    assert got[0].dtype == q.dtype and got[1].dtype == F32
+    for name, a, b, lim in zip(("o", "lse"), got, want, (tol, 1e-5)):
+        assert a.shape == b.shape, name
+        gap = float(jnp.max(jnp.abs(a.astype(F32) - b))
+                    / jnp.max(jnp.abs(b)))
+        assert gap <= lim, (name, gap)
+
+
 def _residuals(t, block, window, dtype, g=2, d=64, dv=128):
     """(q [B, Hk, G, T, D], k, v, o, lse, do) as ``_band_fwd`` leaves them."""
     q, k, v = (a.astype(dtype) for a in _qkv(t, hq=2 * g, d=d, dv=dv))
@@ -288,20 +315,27 @@ def test_causal_attention_band_bfloat16(window):
     _tree_close(loops, plain, 0.02)
 
 
+@pytest.mark.parametrize("query_first", [False, True])
 @pytest.mark.parametrize("n,bs,window", [
     (16, 512, None), (16, 512, 512), (12, 128, 130), (12, 128, 200),
     (9, 16, 8), (9, 16, 1), (7, 128, 1000)])
-def test_band_pairs_are_the_forwards_pairs_key_block_first(n, bs, window):
+def test_band_pairs_are_the_forwards_pairs_key_block_first(n, bs, window,
+                                                           query_first):
     """The pairs the backward's grid walks (key block j outer, the query
-    blocks that see it inner, ascending) are the pairs ``lo(i) <= j <= i`` the
-    forward walks query block first; ``band_blocks`` counts them."""
+    blocks that see it inner, ascending) and the pairs the forward's grid
+    walks (``query_first``: query block i outer, its key blocks contiguous
+    and ascending) are the same set ``lo(i) <= j <= i``; ``band_blocks``
+    counts them."""
     lo = [int(pa._band_lo(jnp.int32(i), bs, window)) for i in range(n)]
-    pj, pi = pa._band_pairs(n, bs, window)
+    pj, pi = pa._band_pairs(n, bs, window, query_first)
     assert pj.dtype == pi.dtype == np.int32
-    assert list(zip(pj, pi)) == [(j, i) for j in range(n) for i in range(n)
-                                 if lo[i] <= j <= i]
+    band = [(j, i) for j in range(n) for i in range(n) if lo[i] <= j <= i]
+    if query_first:
+        band.sort(key=lambda ji: (ji[1], ji[0]))
+        starts = [p for p in range(len(pi)) if p == 0 or pi[p - 1] != pi[p]]
+        assert [int(pi[p]) for p in starts] == list(range(n))
+    assert list(zip(pj, pi)) == band
     assert len(pj) == pa.band_blocks(n * bs, bs, window)[0]
-    assert max(np.bincount(pj)) <= pa._band_width(n, bs, window)
 
 
 def test_attention_backward_is_counted_once_a_traced_call_site():
@@ -338,17 +372,26 @@ def test_backward_kernel_has_its_own_shape_predicate():
     assert not pa.supports_band_bwd_kernel(65536, 64, 2, 2)
 
 
-def test_band_skips_key_blocks():
-    """The counters say how much of the square the band leaves out."""
-    prof = OpProfiler.get()
-    run0 = prof.counter_value("seq/attn_key_blocks_run")
-    skip0 = prof.counter_value("seq/attn_key_blocks_skipped")
-    q, k, v = _qkv(64)
-    causal_attention(q, k, v, window=8, block=8)
+@pytest.mark.parametrize("t,block,window,interpret,run", [
     # 8 query blocks: the first sees 1 key block, the others 2
-    assert prof.counter_value("seq/attn_key_blocks_run") - run0 == 15 * B * 4
-    assert (prof.counter_value("seq/attn_key_blocks_skipped") - skip0
-            == (64 - 15) * B * 4)
+    (64, 8, 8, None, 15),
+    # the forward kernel, two query heads a key/value head: the whole causal
+    # band of 4 blocks, and a window of one block
+    (512, 128, None, True, 10), (512, 128, 128, True, 7)])
+def test_band_skips_key_blocks(t, block, window, interpret, run):
+    """The counters say how much of the square the band leaves out, and how
+    many grid steps the forward kernel's call issues: the band's pairs once a
+    key/value head (``_qkv``: 4 query heads over 2), none on the XLA path."""
+    prof = OpProfiler.get()
+    names = ("seq/attn_key_blocks_run", "seq/attn_key_blocks_skipped",
+             "seq/attn_fwd_grid_steps")
+    was = [prof.counter_value(n) for n in names]
+    q, k, v = _qkv(t, d=64, dv=128)
+    causal_attention(q, k, v, window=window, block=block, interpret=interpret)
+    n = t // block
+    assert [prof.counter_value(n) - w for n, w in zip(names, was)] == [
+        run * B * 4, (n * n - run) * B * 4, run * B * 2 if interpret else 0]
+    assert "attn_fwd_grid_steps" in prof.sequence_stats()
 
 
 def test_differential_attention_with_lambda_zero_is_plain_gqa():

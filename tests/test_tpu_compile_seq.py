@@ -68,14 +68,14 @@ def _scan(grad):
                                      narrow, narrow]
 
 
-def _attention(window, grad, plain=None):
+def _attention(window, grad, plain=None, t=T):
     # the two score maps on the batch axis: 2 x 20 query heads of 64 over
     # 2 x 10 key heads, the pair's 128-wide value; ``plain``: float32, equal
     # head counts and a value as wide as the head (what
     # ``flash_attention(causal=True)`` hands over), at that width
     bf16 = jnp.bfloat16
-    shapes = [((2, 10, 2, T, 64), bf16), ((2, 10, T, 64), bf16),
-              ((2, 10, T, 128), bf16)]
+    shapes = [((2, 10, 2, t, 64), bf16), ((2, 10, t, 64), bf16),
+              ((2, 10, t, 128), bf16)]
     if plain:
         shapes = [((2, 4, 1, 2048, plain), jnp.float32)] + [
             ((2, 4, 2048, plain), jnp.float32)] * 2
@@ -159,6 +159,11 @@ CASES = {
     "selective_scan_fwd_bwd": (lambda: _scan(True), 2),
     "attention_full_fwd": (lambda: _attention(None, False), 1),
     "attention_window_fwd": (lambda: _attention(512, False), 1),
+    # the longest sequence ``supports_band_kernel`` promises the forward
+    # (``tests/test_seq_layers.py``): 128 blocks, a prefetched list of 8,256
+    # pairs in scalar memory; the backward there is the XLA loops
+    "attention_full_t65536_fwd": (
+        lambda: _attention(None, False, t=65536), 1),
     # forward + backward kernel (``flash_attention_bwd``): the backward's
     # shape predicate holds at all four (``supports`` says so below)
     "attention_full_fwd_bwd": (lambda: _attention(None, True), 2),
@@ -194,6 +199,9 @@ def supports(case):
                 and pa.supports_band_bwd_kernel(T, 64, 4, 2))
     if case.startswith("selective_scan"):
         return ssm.supports_scan_kernel(D_INNER, D_STATE)
+    if "t65536" in case:
+        return (pa.supports_band_kernel(65536, 64, 128, pa.BAND_BLOCK)
+                and not pa.supports_band_bwd_kernel(65536, 64, 2, 2))
     if "plain" in case:
         d = int(case.split("_d")[1].split("_")[0])
         return (pa.supports_band_kernel(2048, d, d, pa.BAND_BLOCK)

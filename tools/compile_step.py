@@ -14,9 +14,12 @@ for the process, so the ops take their Pallas lowerings as they do on the chip.
 ``temp`` is ``memory_analysis().temp_size_in_bytes``: the benchmark's
 ``memory_step_scratch_bytes`` (PR 33: 4,142,870,528 here against 4,142,902,784
 on the chip for ``lfm2_moe.train_b2_s8k``), so a change's effect on
-``hbm_peak_gb`` can be read before any chip call. Nothing runs: no time, no
-rate. One process at a time (libtpu's lock). ``--root`` takes an unpacked
-parent commit, to compare. Only ``ComputationGraph.fit`` cells.
+``hbm_peak_gb`` can be read before any chip call; ``sequence_stats`` are the
+``seq/*`` counters that tracing the step bumped (call sites on a kernel or on
+the XLA path, the attention band's pairs and the forward's grid steps).
+Nothing runs: no time, no rate. One process at a time (libtpu's lock).
+``--root`` takes an unpacked parent commit, to compare. Only
+``ComputationGraph.fit`` cells.
 """
 
 import argparse
@@ -56,6 +59,7 @@ def main() -> None:
     sizes = conf.sizes_of(cfg, False)
     job = conf.build(cfg, sizes, 1, mix)
     model = job.model
+    from deeplearning4j_tpu.common.profiler import OpProfiler
     from deeplearning4j_tpu.nn.train_step import make_core, step_program
 
     batch = model._bind(job.feed(gen.make(mix, sizes, 1, 1)))
@@ -89,7 +93,9 @@ def main() -> None:
            "outputs": mem.output_size_in_bytes,
            "aliased": mem.alias_size_in_bytes,
            "pallas_calls": text.count("tpu_custom_call"),
-           "rounding_fusions_by_bf16_outputs": sorted(rounding.items())})
+           "rounding_fusions_by_bf16_outputs": sorted(rounding.items()),
+           # what tracing the step bumped, the kernels' switch on
+           "sequence_stats": OpProfiler.get().sequence_stats()})
 
 
 if __name__ == "__main__":
