@@ -1,0 +1,23 @@
+"""Layer: kernels. Device time a step, self time, every phase, of the head
+vertices (``scope_kinds()``: ``TiedOutputLayer``, ``LMHeadLayer``; a
+prediction module's head among them): the token-block loops of the fused
+score, forward and backward, which the ledger's ``device_ops`` show as
+``while``. ``stop`` and the table are ``scope_ms.update``'s."""
+
+import importlib.util
+import os
+
+_spec = importlib.util.spec_from_file_location(
+    "bench_metrics_scope_ms_update",
+    os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                 "scope_ms.update.py"))
+_first = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_first)
+
+stop = _first.stop
+
+KINDS = ("TiedOutputLayer", "LMHeadLayer")
+
+
+def read(ctx):
+    return _first.total(ctx, lambda r: r.get("kind") in KINDS)

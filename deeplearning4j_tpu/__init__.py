@@ -33,6 +33,14 @@ import jax as _jax
 # requested (on TPU, f64 is slow/emulated; the reference's fp64 paths are
 # gradient checks, which run on CPU).
 _jax.config.update("jax_enable_x64", True)
+# The step's named scopes are HLO metadata, and ``OpProfiler.scope_times``
+# reads a trace by them. JAX's persistent cache keys an executable WITHOUT
+# its metadata by default, so a step cached by an older tree or another
+# checkout would be loaded with that tree's names (or none) and the profile
+# would be booked to them. Key the cache on the metadata too: an entry then
+# serves the source positions it was compiled from (as the steps that hold
+# a Pallas kernel always did).
+_jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 from .common.dtypes import DataType
 from .common.environment import Environment

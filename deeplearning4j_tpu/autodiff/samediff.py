@@ -45,6 +45,7 @@ from ..ndarray.rng import get_random
 from ..learning.precision import apply_updater
 from ..learning.schedules import ISchedule
 from ..learning.updaters import Adam, GradientUpdater
+from ..nn.train_step import FORWARD, UPDATE
 from ..ops.registry import all_ops, get_op
 
 # v2: control-flow nodes ("control" key) + scope-prefixed npz array keys
@@ -577,7 +578,8 @@ class SameDiff:
                 if not training and node.op_name in _TRAIN_ONLY_IDENTITY:
                     res = args[0]
                 else:
-                    res = desc.fn(*args, **kwargs)
+                    with jax.named_scope(_op_scope(node.outputs[0])):
+                        res = desc.fn(*args, **kwargs)
                 if node.n_outputs > 1:
                     for out_name, r in zip(node.outputs, res):
                         env[out_name] = r
@@ -717,23 +719,32 @@ class SameDiff:
         l1, l2 = tc.l1, tc.l2
 
         def step(params, upd_state, ph, key, iteration):
+            # the scopes of nn.train_step's step: ``forward`` (each op under
+            # its variable's directory, ``_op_scope``), ``update``
+            @jax.named_scope(FORWARD)
             def loss_fn(p):
                 loss = fn(p, ph, key)[0]
-                reg = 0.0
-                if l2:
-                    # DL4J L2: score += 0.5*l2*||w||^2 (grad = l2*w) — matches
-                    # MultiLayerNetwork._loss
-                    reg = reg + 0.5 * l2 * sum(jnp.sum(jnp.square(w)) for w in p.values())
-                if l1:
-                    reg = reg + l1 * sum(jnp.sum(jnp.abs(w)) for w in p.values())
-                return jnp.sum(loss) + reg
+                with jax.named_scope("loss"):
+                    reg = 0.0
+                    if l2:
+                        # DL4J L2: score += 0.5*l2*||w||^2 (grad = l2*w) —
+                        # matches MultiLayerNetwork._loss
+                        reg = reg + 0.5 * l2 * sum(
+                            jnp.sum(jnp.square(w)) for w in p.values())
+                    if l1:
+                        reg = reg + l1 * sum(jnp.sum(jnp.abs(w))
+                                             for w in p.values())
+                    return jnp.sum(loss) + reg
 
             loss, grads = jax.value_and_grad(loss_fn)(params)
-            if tc.grad_clip_value:
-                grads = jax.tree.map(
-                    lambda g: jnp.clip(g, -tc.grad_clip_value, tc.grad_clip_value), grads)
-            new_params, new_state = apply_updater(
-                updater, grads, upd_state, params, iteration, key)
+            with jax.named_scope(UPDATE):
+                if tc.grad_clip_value:
+                    with jax.named_scope("grad_norm"):
+                        grads = jax.tree.map(
+                            lambda g: jnp.clip(g, -tc.grad_clip_value,
+                                               tc.grad_clip_value), grads)
+                new_params, new_state = apply_updater(
+                    updater, grads, upd_state, params, iteration, key)
             return new_params, new_state, loss
 
         jitted = xprof.register_jit(
@@ -1098,6 +1109,16 @@ _N_OUTPUTS = {
 
 # train-only stochastic ops that become identity at inference
 _TRAIN_ONLY_IDENTITY = {"dropout", "alpha_dropout", "gaussian_dropout", "gaussian_noise"}
+
+
+def _op_scope(name: str) -> str:
+    """The ``jax.named_scope`` an op runs under: its output variable's
+    directory, at most four components deep
+    (``bert/encoder/layer_3/attention`` for an imported node
+    ``bert/encoder/layer_3/attention/self/MatMul``); a name without a
+    directory is its own scope."""
+    parts = [p for p in name.split("/") if p]
+    return "/".join(parts[:-1][:4] or parts) or "op"
 
 
 def _lower_control(node: "_Node", env: Dict[str, Any], training: bool, key):

@@ -57,6 +57,7 @@ class OpProfiler:
 
     def __init__(self) -> None:
         self._trace_dir: Optional[str] = None
+        self._last_trace_dir: Optional[str] = None
         self._sections: Dict[str, Dict[str, float]] = {}
         self._counters: Dict[str, int] = {}
         self._gauge_names: set = set()
@@ -75,7 +76,7 @@ class OpProfiler:
         with self._lock:
             if self._trace_dir is not None:
                 raise RuntimeError("profiler already tracing")
-            self._trace_dir = logdir
+            self._trace_dir = self._last_trace_dir = logdir
         try:
             jax.profiler.start_trace(logdir)
         except BaseException:
@@ -107,6 +108,22 @@ class OpProfiler:
             yield self
         finally:
             self.stop()
+
+    def scope_times(self, logdir: Optional[str] = None,
+                    step_program: str = "jit_step") -> dict:
+        """Where the device time of a traced step went, by the step's
+        named scopes (phase / vertex / the layer's own): the reading of
+        ``common.xprof.scope_times`` over the session that
+        :meth:`trace` last wrote (or over ``logdir``). ``step_program``:
+        the prefix of the step program's name. A model's ``scope_kinds()``
+        says which layer class each row's vertex is. ``python3 -m
+        deeplearning4j_tpu.common.xprof LOGDIR`` prints the same table."""
+        from . import xprof
+
+        logdir = logdir or self._last_trace_dir
+        if logdir is None:
+            raise ValueError("no trace yet: run the step under trace(logdir)")
+        return xprof.scope_times(logdir, step_program)
 
     # --- host-side section counters (OpProfiler counter analog) ---------
     @contextlib.contextmanager

@@ -1,5 +1,5 @@
 """XLA performance observatory: executable census, roofline ledger, HBM
-watermarks.
+watermarks, device time by model scope.
 
 The repo can time a step (profiler sections, bench fences) but before
 this module it could not say *why* a step is slow: no per-executable
@@ -9,7 +9,7 @@ PAPERS.md) argues that graph-level optimization is only steerable with
 per-kernel cost models; this is that layer, built on jax's own
 ``lowered.cost_analysis()`` / ``compiled.memory_analysis()`` artifacts.
 
-Three instruments, one module:
+Four instruments, one module:
 
 1. **Executable census** — every long-lived compiled function in the
    package registers under a stable name from :data:`EXEC_SITES`
@@ -58,6 +58,14 @@ Three instruments, one module:
    full census (:func:`dump_memory_census` → ``memcensus.json`` beside
    ``blackbox.jsonl``) so OOM-class failures carry the memory picture
    alongside the event tail.
+
+4. **Device time by model scope** — :func:`scope_times` reads a profiler
+   session's raw ``XSpace`` (``OpProfiler.trace(logdir)``) and books every
+   device op of the traced step, self time only, to the step's own names:
+   phase (``forward`` / ``recompute`` / ``backward`` / ``update``), vertex,
+   the layer's inner scope, with the op's ``hlo_category``, flops and
+   bytes; the program's host sections ride the same file.
+   ``python3 -m deeplearning4j_tpu.common.xprof LOGDIR`` prints it.
 
 Census overhead is one enabled-flag read plus two ``perf_counter`` calls
 and a lock per dispatch (``configure(enabled=False)`` reduces it to the
@@ -116,8 +124,13 @@ pallas/update_bucket        fused flat-bucket updater kernels (counted
 
 from __future__ import annotations
 
+import bisect
+import functools
+import glob
+import gzip
 import json
 import os
+import re
 import threading
 import time
 import weakref
@@ -969,3 +982,305 @@ def watermarks() -> Dict[str, Dict[str, Any]]:
 
 def dump_memory_census(path: str) -> str:
     return _CENSUS.dump_memory_census(path)
+
+
+# --- device time by model scope (the reader of the step's named scopes) ------
+#
+# nn.train_step names the step's phases (``forward``, ``update`` ...), the
+# networks name each vertex, the layers their inner parts. A profiler session
+# (``OpProfiler.trace(logdir)``) writes every device op with the name stack of
+# the instruction it ran: the stat ``tf_op`` of the op's event metadata in the
+# raw ``XSpace`` proto, beside ``hlo_category``, ``flops`` and
+# ``bytes_accessed`` (``jax.profiler.ProfileData`` exposes none of them).
+
+#: JAX's own wrapping in a name stack, never a scope. ``while`` and ``cond``
+#: only with JAX's next word behind them: an imported graph has directories
+#: of those names
+_WRAPPING = re.compile(
+    r"(?<![^/])(?:while/(?:body|cond)|cond/branch_\w+|checkpoint|"
+    r"rematted_computation|closed_call|shard_map)(?![^/])")
+_REMAT = "rematted_computation"     # jax.checkpoint's forward run again
+
+
+#: what the reader touches of tsl/profiler/protobuf/xplane.proto (the rest of
+#: a message stays in it as unknown fields): message -> (field, number,
+#: scalar type | *repeated message | {map's value message})
+_XPLANE_SCHEMA = {
+    "XStat": (("metadata_id", 1, "int64"), ("double_value", 2, "double"),
+              ("uint64_value", 3, "uint64"), ("int64_value", 4, "int64"),
+              ("str_value", 5, "string"), ("bytes_value", 6, "bytes"),
+              ("ref_value", 7, "uint64")),
+    "XEvent": (("metadata_id", 1, "int64"), ("offset_ps", 2, "int64"),
+               ("duration_ps", 3, "int64"), ("stats", 4, "*XStat")),
+    "XLine": (("name", 2, "string"), ("timestamp_ns", 3, "int64"),
+              ("events", 4, "*XEvent")),
+    "XEventMetadata": (("id", 1, "int64"), ("name", 2, "string"),
+                       ("stats", 5, "*XStat")),
+    "XStatMetadata": (("id", 1, "int64"), ("name", 2, "string")),
+    "XPlane": (("name", 2, "string"), ("lines", 3, "*XLine"),
+               ("event_metadata", 4, "{XEventMetadata"),
+               ("stat_metadata", 5, "{XStatMetadata")),
+    "XSpace": (("planes", 1, "*XPlane"),),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _xplane_classes():
+    """``XSpace`` as a message class built from :data:`_XPLANE_SCHEMA` in a
+    descriptor pool of its own: no TensorFlow import, C-speed parsing."""
+    from google.protobuf import (descriptor_pb2, descriptor_pool,
+                                 message_factory)
+
+    T = descriptor_pb2.FieldDescriptorProto
+    f = descriptor_pb2.FileDescriptorProto(
+        name="dl4j_tpu/xplane.proto", package="dl4j_tpu.xplane",
+        syntax="proto3")
+
+    def add(fields, number, name, kind, label=T.LABEL_OPTIONAL):
+        if kind[0] in "*{":
+            fields.add(name=name, number=number, type=T.TYPE_MESSAGE,
+                       label=label, type_name=".dl4j_tpu.xplane." + kind[1:])
+        else:
+            fields.add(name=name, number=number, label=label,
+                       type=getattr(T, "TYPE_" + kind.upper()))
+
+    for message, fields in _XPLANE_SCHEMA.items():
+        m = f.message_type.add(name=message)
+        for name, number, kind in fields:
+            if kind[0] == "{":     # map<int64, message>: a nested entry type
+                entry = m.nested_type.add(name=name.title().replace("_", "")
+                                          + "Entry")
+                entry.options.map_entry = True
+                add(entry.field, 1, "key", "int64")
+                add(entry.field, 2, "value", kind)
+                kind = f"*{message}.{entry.name}"
+            add(m.field, number, name, kind,
+                T.LABEL_REPEATED if kind[0] == "*" else T.LABEL_OPTIONAL)
+    stat = f.message_type[0]        # XStat's values are one ``oneof``
+    stat.oneof_decl.add(name="value")
+    for field in stat.field[1:]:
+        field.oneof_index = 0
+    pool = descriptor_pool.DescriptorPool()
+    pool.Add(f)
+    return message_factory.GetMessageClass(
+        pool.FindMessageTypeByName("dl4j_tpu.xplane.XSpace"))
+
+
+def read_xspace(path: str):
+    """The ``XSpace`` of a profiler session: ``path`` is its ``logdir`` (the
+    newest ``*.xplane.pb`` under it) or one such file, ``.gz`` or not."""
+    if os.path.isdir(path):
+        found = sorted(glob.glob(os.path.join(
+            path, "plugins", "profile", "*", "*.xplane.pb")))
+        if not found:
+            raise FileNotFoundError(f"no *.xplane.pb under {path}")
+        path = found[-1]
+    with (gzip.open if path.endswith(".gz") else open)(path, "rb") as fh:
+        return _xplane_classes().FromString(fh.read())
+
+
+def short_op_name(hlo: str) -> str:
+    """``%fusion.12 = f32[..] fusion(..), kind=kOutput, ..`` (or the bare
+    ``fusion.12``) -> ``fusion[kOutput]``: the benchmark reducer's
+    ``short_name``."""
+    name = re.sub(r"\.\d+$", "", hlo.split(" = ", 1)[0].lstrip("%"))
+    kind = re.search(r"kind=(k\w+)", hlo)
+    return f"{name}[{kind.group(1)}]" if kind else name
+
+
+def classify_scope(tf_op: str) -> Tuple[str, str, str]:
+    """``(phase, vertex, inner)`` of an op's name stack
+    (``jit(step)/transpose(jvp(forward))/jvp(forward)/checkpoint/
+    rematted_computation/attn_1/mla_q/dot_general:``). The phase is the
+    step's first scope (``nn.train_step.FORWARD`` / ``UPDATE``), bare or as a
+    transformation spells it: JAX writes the backward of ``forward``
+    ``transpose(jvp(forward))`` (under ``vmap``:
+    ``vmap(transpose(jvp(forward)))``) and the forward run again under
+    ``jax.checkpoint`` ``.../rematted_computation/...``. A component in
+    parentheses is never a scope, JAX's own wrapping (:data:`_WRAPPING`) is
+    dropped, and the last component is the primitive. In ``update`` there is
+    no vertex: its first scope is ``inner``."""
+    from ..nn.train_step import FORWARD, UPDATE    # the names the step emits
+
+    parts = [p for p in re.split("[:;]", tf_op, 1)[0].split("/") if p][:-1]
+    remat = _REMAT in parts
+    scopes, phase = [], ""
+    for p in _WRAPPING.sub("", "/".join(parts)).split("/"):
+        name = p.rsplit("(", 1)[-1].rstrip(")")
+        if not phase and not scopes and name in (FORWARD, UPDATE):
+            phase = "backward" if "transpose(" in p else name
+        elif p and "(" not in p:
+            scopes.append(p)
+    if phase == UPDATE:
+        return phase, "", "/".join(scopes[:1])
+    if remat:
+        phase = "recompute"
+    return phase or "other", "/".join(scopes[:1]), "/".join(scopes[1:])
+
+
+def _self_times(events: list) -> list:
+    """Self time of each ``(start, end, ...)`` event of one line: at any
+    instant the innermost running event owns the time, so an op nested in a
+    ``while`` is counted once and the self times sum to the union."""
+    order = sorted(range(len(events)),
+                   key=lambda i: (events[i][0], -events[i][1]))
+    out, stack, cursor = [0] * len(events), [], 0
+
+    def close(until):
+        nonlocal cursor
+        while stack and events[stack[-1]][1] <= until:
+            top = stack.pop()
+            out[top] += max(0, events[top][1] - cursor)
+            cursor = max(cursor, events[top][1])
+
+    for i in order:
+        start = events[i][0]
+        close(start)
+        if stack:
+            out[stack[-1]] += max(0, start - cursor)
+        cursor = max(cursor, start)
+        stack.append(i)
+    close(float("inf"))
+    return out
+
+
+def _stat_value(stat, names: dict):
+    """An ``XStat``'s value; a string interned as a stat's name
+    (``ref_value``) looked up in ``names``."""
+    field = stat.WhichOneof("value")
+    if field == "ref_value":
+        return names.get(stat.ref_value, "")
+    return getattr(stat, field) if field else None
+
+
+def _book_sections(plane, names, host: Dict[str, list]) -> None:
+    """Add a host plane's spans called one of ``names`` (the program's
+    sections, ``OpProfiler.time_section``; nested by thread) to ``host``:
+    ``[count, total, self]`` in ps. Self time is a section's time outside
+    the sections inside it; the Python tracer's and the runtime's own spans
+    do not count against it."""
+    for line in plane.lines:
+        t0 = line.timestamp_ns * 1000
+        spans = [(t0 + e.offset_ps, t0 + e.offset_ps + e.duration_ps, name)
+                 for e in line.events for name in
+                 [plane.event_metadata[e.metadata_id].name.split("#", 1)[0]]
+                 if name in names]
+        for (s, e, name), own in zip(spans, _self_times(spans)):
+            h = host.setdefault(name, [0, 0, 0])
+            h[0] += 1
+            h[1] += e - s
+            h[2] += own
+
+
+def scope_times(logdir: str, step_program: str = "jit_step") -> Dict[str, Any]:
+    """Device time of the traced step by model scope, self time only,
+    averaged over the device planes, for the executions of the programs whose
+    name starts with ``step_program``: ``{"steps", "step_ms", "rows":
+    [{"phase", "vertex", "inner", "op", "category", "ms", "calls", "flops",
+    "bytes"}, ...], "unattributed_ms", "host": {section: {"count",
+    "total_ms", "self_ms"}}}``, ms a step; rows sum to ``step_ms``.
+    Unattributed: an op with no vertex outside ``update`` — outside every
+    scope, or in forward, recompute or backward beside the vertices.
+    ``host``: the sections this process's ``OpProfiler`` has timed, as the
+    trace's host planes hold them. A trace with device planes and no such
+    program is an error; one without a device plane (a CPU run) gives no
+    rows."""
+    space = read_xspace(logdir)
+    sections = set(OpProfiler.get().get_statistics())
+    rows: Dict[tuple, list] = {}
+    host: Dict[str, list] = {}
+    programs = set()
+    steps = busy = planes = 0
+    for plane in space.planes:
+        stat_names = {k: v.name for k, v in plane.stat_metadata.items()}
+        lines = {l.name: l for l in plane.lines}
+        if not (plane.name.startswith("/device:") and "XLA Ops" in lines
+                and "XLA Modules" in lines):
+            _book_sections(plane, sections, host)
+            continue
+        planes += 1
+        meta = {}
+        for mid, m in plane.event_metadata.items():
+            stats = {stat_names.get(s.metadata_id, ""):
+                     _stat_value(s, stat_names) for s in m.stats}
+            meta[mid] = (
+                classify_scope(str(stats.get("tf_op", ""))),
+                short_op_name(m.name),
+                str(stats.get("hlo_category", "")),
+                float(stats.get("flops") or 0),
+                float(stats.get("bytes_accessed") or 0))
+        t0 = lines["XLA Modules"].timestamp_ns * 1000
+        programs.update(plane.event_metadata[e.metadata_id].name
+                        for e in lines["XLA Modules"].events)
+        runs = sorted(
+            (t0 + e.offset_ps, t0 + e.offset_ps + e.duration_ps)
+            for e in lines["XLA Modules"].events
+            if plane.event_metadata[e.metadata_id].name.startswith(
+                step_program))
+        steps += len(runs)
+        starts = [r[0] for r in runs]
+        t0 = lines["XLA Ops"].timestamp_ns * 1000
+        events = [(t0 + e.offset_ps, t0 + e.offset_ps + e.duration_ps,
+                   e.metadata_id) for e in lines["XLA Ops"].events]
+        for (s, e, mid), own in zip(events, _self_times(events)):
+            k = bisect.bisect_right(starts, s) - 1
+            if k < 0 or s >= runs[k][1]:
+                continue
+            busy += own
+            row = rows.setdefault(meta[mid][:3], [0, 0, 0.0, 0.0])
+            row[0] += own
+            row[1] += 1
+            row[2] += meta[mid][3]
+            row[3] += meta[mid][4]
+    if planes and not steps:
+        raise ValueError(f"no program named {step_program!r}* ran in the "
+                         f"trace; it holds {sorted(programs)}")
+    n = max(steps, 1)
+    ms = 1e-9 / n       # picoseconds over all planes' steps -> ms a step
+    table = [{"phase": ph, "vertex": vx, "inner": inner, "op": op,
+              "category": cat, "ms": own * ms, "calls": calls / n,
+              "flops": flops / n, "bytes": nbytes / n}
+             for ((ph, vx, inner), op, cat), (own, calls, flops, nbytes)
+             in rows.items()]
+    table.sort(key=lambda r: -r["ms"])
+    return {
+        "steps": steps / max(planes, 1), "step_ms": busy * ms, "rows": table,
+        "unattributed_ms": sum(r["ms"] for r in table
+                               if not r["vertex"] and r["phase"] != "update"),
+        "host": {name: {"count": c, "total_ms": t * 1e-9,
+                        "self_ms": own * 1e-9}
+                 for name, (c, t, own) in sorted(host.items())}}
+
+
+def scope_table(times: Dict[str, Any], by: str = "vertex") -> str:
+    """``scope_times`` as text, grouped ``by`` ``vertex`` or ``phase`` (or
+    a key the caller joined to the rows, a model's ``scope_kinds()`` as
+    ``kind``): ms a step, most expensive first."""
+    groups: Dict[str, float] = {}
+    for r in times["rows"]:
+        key = r.get(by) or f"({r['phase']}/{r['inner'] or '-'})"
+        groups[key] = groups.get(key, 0.0) + r["ms"]
+    total = times["step_ms"] or 1.0
+    out = [f"{times['steps']:g} steps, {times['step_ms']:.3f} ms a step "
+           f"on the device, unattributed {times['unattributed_ms']:.3f} ms"]
+    out += [f"{ms:10.3f} ms {100 * ms / total:6.2f}%  {key}"
+            for key, ms in sorted(groups.items(), key=lambda kv: -kv[1])]
+    return "\n".join(out)
+
+
+def _main(argv=None) -> int:
+    import argparse
+
+    ap = argparse.ArgumentParser(
+        prog="python3 -m deeplearning4j_tpu.common.xprof",
+        description="Device time of a traced step by model scope.")
+    ap.add_argument("logdir", help="OpProfiler.trace's logdir, or one "
+                    "*.xplane.pb[.gz]")
+    ap.add_argument("--by", default="vertex", choices=("vertex", "phase"))
+    args = ap.parse_args(argv)
+    print(scope_table(scope_times(args.logdir), args.by))
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(_main())

@@ -266,19 +266,23 @@ def apply_updater(updater, grads, state, params, iteration, key=None):
     stochastically round the NEW moments back down using ``key`` (the
     step's RNG stream, fold_in-tagged so dropout draws are untouched).
     Parameters stay fp32 throughout — only the stored state narrows.
+    Scopes ``updater`` and ``sr``, inside the caller's ``update``.
     """
     sd = state_dtype_of(updater)
     if not sd:
-        return updater.apply(grads, state, params, iteration)
+        with jax.named_scope("updater"):
+            return updater.apply(grads, state, params, iteration)
     if key is None:
         raise ValueError(
             f"{type(updater).__name__}(state_dtype={sd!r}) needs the step "
             "RNG key for stochastic rounding — this fit path does not "
             "thread one; unset state_dtype or use a pipeline fit")
-    wide = cast_floating(state, jnp.float32)
-    new_params, new_state = updater.apply(grads, wide, params, iteration)
-    sr_key = jax.random.fold_in(key, SR_STREAM_TAG)
-    new_state = sr_cast_state(new_state, jnp.dtype(sd), sr_key, params)
+    with jax.named_scope("updater"):
+        wide = cast_floating(state, jnp.float32)
+        new_params, new_state = updater.apply(grads, wide, params, iteration)
+    with jax.named_scope("sr"):     # the rounding and its threefry draw
+        sr_key = jax.random.fold_in(key, SR_STREAM_TAG)
+        new_state = sr_cast_state(new_state, jnp.dtype(sd), sr_key, params)
     return new_params, new_state
 
 

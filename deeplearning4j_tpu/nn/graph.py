@@ -29,8 +29,8 @@ from .conf import layers as L
 from .conf.builder import (GlobalConf, MultiLayerConfiguration, _deser_obj,
                            _ser_obj, remat_wrap)
 from .conf.inputs import CNNFlatInput, CNNInput, FFInput, InputType, RNNInput, cnn_to_ff, flat_to_cnn
-from .train_step import (FitLoop, _fold_weights, chunk_program, make_core,
-                         step_program)
+from .train_step import (FORWARD, FitLoop, _fold_weights, chunk_program,
+                         make_core, step_program, vertex_scope)
 
 
 # --- graph vertices (reference conf/graph/*) ---------------------------------
@@ -500,9 +500,13 @@ class ComputationGraph(FitLoop):
                               if jnp.issubdtype(a.dtype, jnp.floating) else a)
             keep = {n: self.conf.nodes[n].layer.full_precision_params
                     for n in params}
-            params = {n: {k: (v if k in keep[n] else jax.tree.map(cast, v))
-                          for k, v in lp.items()}
-                      for n, lp in params.items()}
+            cast_params = {}
+            for n, lp in params.items():
+                with jax.named_scope(vertex_scope(n)):
+                    cast_params[n] = {
+                        k: (v if k in keep[n] else jax.tree.map(cast, v))
+                        for k, v in lp.items()}
+            params = cast_params
             inputs = {k: cast(v) for k, v in inputs.items()}
         acts: Dict[str, jnp.ndarray] = {}
         new_states = dict(states)
@@ -532,22 +536,25 @@ class ComputationGraph(FitLoop):
                 (xbn, bnp, bns, bnl), other = pending_add.pop(add_name)
                 from ..ops.pallas_epilogue import bn_act
 
-                y = bn_act(xbn, bns["mean"], bns["var"], bnp.get("gamma"),
-                           bnp.get("beta"), epsilon=bnl.eps,
-                           axis=1 if xbn.ndim == 4 else -1, act="relu",
-                           residual=other)
-                if y is None:
-                    # shape gate refused: replay the dense chain verbatim
-                    bn_out, _ = bnl.apply(bnp, xbn, bns, training, sub)
-                    y, _ = node.layer.apply(params.get(name, {}),
-                                            bn_out + other,
-                                            states.get(name, {}),
-                                            training, sub)
+                with jax.named_scope(vertex_scope(name)):
+                    y = bn_act(xbn, bns["mean"], bns["var"],
+                               bnp.get("gamma"), bnp.get("beta"),
+                               epsilon=bnl.eps,
+                               axis=1 if xbn.ndim == 4 else -1, act="relu",
+                               residual=other)
+                    if y is None:
+                        # shape gate refused: replay the dense chain verbatim
+                        bn_out, _ = bnl.apply(bnp, xbn, bns, training, sub)
+                        y, _ = node.layer.apply(params.get(name, {}),
+                                                bn_out + other,
+                                                states.get(name, {}),
+                                                training, sub)
                 acts[name] = y
                 continue
             ins = [acts[i] for i in node.inputs]
             if node.kind == "vertex":
-                acts[name] = node.vertex.apply(*ins)
+                with jax.named_scope(vertex_scope(name)):
+                    acts[name] = node.vertex.apply(*ins)
                 continue
             x = ins[0]
             if 0 in node.preprocessors:
@@ -566,22 +573,27 @@ class ComputationGraph(FitLoop):
                                     states.get(name, {}), node.layer)
                 continue
             if to_preout and name in out_set and isinstance(node.layer, (L.OutputLayer, L.LossLayer)):
-                x = node.layer._maybe_dropout(x, training, sub)
-                head_params = lp
-                if hasattr(node.layer, "fused_score"):
-                    # the head computes its own loss from its input, in
-                    # blocks, and keeps its own precision rule
-                    acts[name] = L.HeadInput(x, head_params)
-                    continue
-                if cd:
-                    # run the head matmul + downstream loss in fp32 (matches
-                    # the MultiLayerNetwork mixed-precision policy)
-                    f32 = lambda a: (a.astype(jnp.float32)
-                                     if jnp.issubdtype(a.dtype, jnp.floating) else a)
-                    head_params = jax.tree.map(f32, head_params)
-                    x = f32(x)
-                acts[name] = node.layer.pre_output(head_params, x)
+                with jax.named_scope(vertex_scope(name)):
+                    x = node.layer._maybe_dropout(x, training, sub)
+                    head_params = lp
+                    if hasattr(node.layer, "fused_score"):
+                        # the head computes its own loss from its input, in
+                        # blocks, and keeps its own precision rule
+                        acts[name] = L.HeadInput(x, head_params)
+                        continue
+                    if cd:
+                        # run the head matmul + downstream loss in fp32
+                        # (matches the MultiLayerNetwork mixed-precision
+                        # policy)
+                        f32 = lambda a: (a.astype(jnp.float32)
+                                         if jnp.issubdtype(a.dtype, jnp.floating) else a)
+                        head_params = jax.tree.map(f32, head_params)
+                        x = f32(x)
+                    acts[name] = node.layer.pre_output(head_params, x)
             else:
+                # the vertex's scope lies INSIDE what remat wraps, so the
+                # recomputed forward carries it too
+                @jax.named_scope(vertex_scope(name))
                 def run(lp, xx, st, k, _l=node.layer):
                     return _l.apply(lp, xx, st, training, k)
 
@@ -640,44 +652,49 @@ class ComputationGraph(FitLoop):
                 continue
             pre = acts[out_name]
             mask = masks.get(out_name) if masks else None
-            if isinstance(pre, L.HeadInput):
-                score = _fused_head_score(node.layer, pre, labels[out_name],
-                                          mask, w, w_denom)
-            else:
-                # under reduced-precision compute, reduce the loss in fp32;
-                # leave fp64 runs (gradient checks) untouched
-                if self.conf.global_conf.compute_dtype and \
-                        jnp.issubdtype(pre.dtype, jnp.floating):
-                    pre = pre.astype(jnp.float32)
-                if w is None:
-                    score = node.layer.loss.compute_score(
-                        labels[out_name], pre, node.layer.activation, mask,
-                        average=True)
-                else:
-                    # example-weighted mean (shape-stable batching): pad rows
-                    # carry w=0 and the divisor is the real example count
-                    score = node.layer.loss.compute_score(
-                        labels[out_name], pre, node.layer.activation,
-                        _fold_weights(mask, w), average=False) / (
-                            w_denom if w_denom is not None
-                            else jnp.maximum(jnp.sum(w), 1.0))
+            # a fused head's token-block loops are the vertex's own work;
+            # every other head's score is the ``loss``
+            with jax.named_scope(vertex_scope(out_name)
+                                 if isinstance(pre, L.HeadInput) else "loss"):
+                score = self._score_of(node.layer, pre, labels[out_name],
+                                       mask, w, w_denom)
             # a LossLayer's loss_weight (an OutputLayer has none: 1)
             weight = getattr(node.layer, "loss_weight", 1.0)
             total = total + (score if weight == 1.0 else weight * score)
         gc = self.conf.global_conf
         reg = 0.0
-        for lname, lp in params.items():
-            layer = self.conf.nodes[lname].layer
-            l1 = layer.l1 if layer.l1 is not None else gc.l1
-            l2 = layer.l2 if layer.l2 is not None else gc.l2
-            for pname, w in lp.items():
-                if pname in ("b", "beta"):
-                    continue
-                if l2:
-                    reg = reg + 0.5 * l2 * jnp.sum(jnp.square(w))
-                if l1:
-                    reg = reg + l1 * jnp.sum(jnp.abs(w))
+        with jax.named_scope("loss"):
+            for lname, lp in params.items():
+                layer = self.conf.nodes[lname].layer
+                l1 = layer.l1 if layer.l1 is not None else gc.l1
+                l2 = layer.l2 if layer.l2 is not None else gc.l2
+                for pname, w in lp.items():
+                    if pname in ("b", "beta"):
+                        continue
+                    if l2:
+                        reg = reg + 0.5 * l2 * jnp.sum(jnp.square(w))
+                    if l1:
+                        reg = reg + l1 * jnp.sum(jnp.abs(w))
         return total + reg, new_states
+
+    def _score_of(self, layer, pre, labels, mask, w, w_denom):
+        """One output's score from its pre-output (or its ``HeadInput``)."""
+        if isinstance(pre, L.HeadInput):
+            return _fused_head_score(layer, pre, labels, mask, w, w_denom)
+        # under reduced-precision compute, reduce the loss in fp32;
+        # leave fp64 runs (gradient checks) untouched
+        if self.conf.global_conf.compute_dtype and \
+                jnp.issubdtype(pre.dtype, jnp.floating):
+            pre = pre.astype(jnp.float32)
+        if w is None:
+            return layer.loss.compute_score(labels, pre, layer.activation,
+                                            mask, average=True)
+        # example-weighted mean (shape-stable batching): pad rows
+        # carry w=0 and the divisor is the real example count
+        return layer.loss.compute_score(
+            labels, pre, layer.activation, _fold_weights(mask, w),
+            average=False) / (w_denom if w_denom is not None
+                              else jnp.maximum(jnp.sum(w), 1.0))
 
     def _bind(self, ds):
         in_names = self.conf.network_inputs
@@ -702,6 +719,15 @@ class ComputationGraph(FitLoop):
     def _keyed_layers(self):
         return [(name, self.conf.nodes[name].layer) for name in self._params]
 
+    def scope_kinds(self) -> dict:
+        """Every layer node and vertex node, those without parameters of
+        their own too (a tied head, a merge)."""
+        return {vertex_scope(name): type(node.layer if node.kind == "layer"
+                                         else node.vertex).__name__
+                for name, node in self.conf.nodes.items()
+                if node.kind in ("layer", "vertex")}
+
+    @jax.named_scope(FORWARD)
     def _loss_of(self, params, states, batch, key, *, training=True, w=None,
                  w_denom=None):
         """The loss of one batch ``(inputs, labels, masks)``."""

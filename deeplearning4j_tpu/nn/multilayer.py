@@ -26,8 +26,8 @@ from ..ndarray.ndarray import NDArray
 from ..ndarray.rng import get_random
 from .conf.builder import MultiLayerConfiguration, remat_wrap
 from .conf import layers as L
-from .train_step import (FitLoop, _fold_weights, chunk_program, finish,
-                         make_core, step_program, update)
+from .train_step import (FORWARD, FitLoop, _fold_weights, chunk_program,
+                         finish, make_core, step_program, update, vertex_scope)
 
 
 class MultiLayerNetwork(FitLoop):
@@ -90,7 +90,11 @@ class MultiLayerNetwork(FitLoop):
             return params, x
         ct = jnp.dtype(cd)
         cast = lambda a: a.astype(ct) if jnp.issubdtype(a.dtype, jnp.floating) else a
-        return jax.tree.map(cast, params), cast(x)
+        cast_params = []
+        for i, lp in enumerate(params):     # each layer's casts are its own
+            with jax.named_scope(vertex_scope(i)):
+                cast_params.append(jax.tree.map(cast, lp))
+        return cast_params, cast(x)
 
     # --- forward ---------------------------------------------------------
     def _apply_layer(self, layer, lp, x, st, training, rng, fmask,
@@ -103,6 +107,9 @@ class MultiLayerNetwork(FitLoop):
         activations instead of keeping them live across the step. The
         selective-list form matches on the layer INDEX here."""
 
+        # the layer's scope lies INSIDE what remat wraps, so the recomputed
+        # forward carries it too
+        @jax.named_scope(vertex_scope(idx))
         def run(lp, x, st, rng, fmask):
             if layer.weight_noise is not None:
                 rng, sub = jax.random.split(rng)
@@ -151,6 +158,7 @@ class MultiLayerNetwork(FitLoop):
                 fmask = layer.derive_mask(x)   # see _forward
             rng, sub = jax.random.split(rng)
             if rnn_states is not None and layer.is_rnn():
+                @jax.named_scope(vertex_scope(i))
                 def run_rnn(lp, xx, rs, st, k, _l=layer):
                     return _l.apply_rnn(lp, xx, rs, st, training, k)
 
@@ -247,48 +255,51 @@ class MultiLayerNetwork(FitLoop):
             pre, new_states = self._forward_to_preout(params, states, x,
                                                       training, rng, fmask)
             new_rnn = None
-        # under reduced-precision compute, run the head + loss reduction in
-        # fp32; leave fp64 runs (gradient checks) untouched
-        if self.conf.global_conf.compute_dtype:
-            head_params = jax.tree.map(
-                lambda a: (a.astype(jnp.float32)
-                           if jnp.issubdtype(a.dtype, jnp.floating) else a),
-                params[-1])
-            if jnp.issubdtype(pre.dtype, jnp.floating):
-                pre = pre.astype(jnp.float32)
-        else:
-            head_params = params[-1]
-        if w is None:
-            data_loss = out_layer.compute_score(head_params, pre, labels,
-                                                mask, average=True)
-        else:
-            # example-weighted mean (shape-stable batching): pad rows carry
-            # w=0, so the weighted sum excludes them exactly and the divisor
-            # is the REAL example count — numerically the same loss the
-            # unpadded batch would produce (sum over reals / n_real).
-            # ``w_denom`` overrides the divisor for SPMD shards, where the
-            # correct denominator is global_real/num_shards so the pmean of
-            # per-shard losses equals the global mean over real examples
-            # (the regularization term stays unscaled either way).
-            total = out_layer.compute_score(head_params, pre, labels,
-                                            _fold_weights(mask, w),
-                                            average=False)
-            data_loss = total / (w_denom if w_denom is not None
-                                 else jnp.maximum(jnp.sum(w), 1.0))
+        with jax.named_scope(vertex_scope(len(self.layers) - 1)):
+            # under reduced-precision compute, run the head + loss reduction
+            # in fp32; leave fp64 runs (gradient checks) untouched
+            if self.conf.global_conf.compute_dtype:
+                head_params = jax.tree.map(
+                    lambda a: (a.astype(jnp.float32)
+                               if jnp.issubdtype(a.dtype, jnp.floating) else a),
+                    params[-1])
+                if jnp.issubdtype(pre.dtype, jnp.floating):
+                    pre = pre.astype(jnp.float32)
+            else:
+                head_params = params[-1]
+            if w is None:
+                data_loss = out_layer.compute_score(head_params, pre, labels,
+                                                    mask, average=True)
+            else:
+                # example-weighted mean (shape-stable batching): pad rows
+                # carry w=0, so the weighted sum excludes them exactly and
+                # the divisor is the REAL example count — numerically the
+                # same loss the unpadded batch would produce (sum over reals
+                # / n_real). ``w_denom`` overrides the divisor for SPMD
+                # shards, where the correct denominator is
+                # global_real/num_shards so the pmean of per-shard losses
+                # equals the global mean over real examples (the
+                # regularization term stays unscaled either way).
+                total = out_layer.compute_score(head_params, pre, labels,
+                                                _fold_weights(mask, w),
+                                                average=False)
+                data_loss = total / (w_denom if w_denom is not None
+                                     else jnp.maximum(jnp.sum(w), 1.0))
         reg = 0.0
         gc = self.conf.global_conf
-        for lp, layer in zip(params, self.layers):
-            if isinstance(layer, L.FrozenLayer):
-                continue  # frozen params take no updates, incl. weight decay
-            l1 = layer.l1 if layer.l1 is not None else gc.l1
-            l2 = layer.l2 if layer.l2 is not None else gc.l2
-            for name, w in lp.items():
-                if name in ("b", "beta", "mean", "var"):
-                    continue  # biases/norm params excluded (reference default)
-                if l2:
-                    reg = reg + 0.5 * l2 * jnp.sum(jnp.square(w))
-                if l1:
-                    reg = reg + l1 * jnp.sum(jnp.abs(w))
+        with jax.named_scope("loss"):
+            for lp, layer in zip(params, self.layers):
+                if isinstance(layer, L.FrozenLayer):
+                    continue  # frozen params take no updates, incl. weight decay
+                l1 = layer.l1 if layer.l1 is not None else gc.l1
+                l2 = layer.l2 if layer.l2 is not None else gc.l2
+                for name, w in lp.items():
+                    if name in ("b", "beta", "mean", "var"):
+                        continue  # biases/norm params excluded (reference default)
+                    if l2:
+                        reg = reg + 0.5 * l2 * jnp.sum(jnp.square(w))
+                    if l1:
+                        reg = reg + l1 * jnp.sum(jnp.abs(w))
         if new_rnn is not None:
             return data_loss + reg, (new_states, new_rnn)
         return data_loss + reg, new_states
@@ -306,6 +317,7 @@ class MultiLayerNetwork(FitLoop):
                 jnp.asarray(ds.features_mask.value)
                 if ds.features_mask is not None else None)
 
+    @jax.named_scope(FORWARD)
     def _loss_of(self, params, states, batch, key, *, training=True, w=None,
                  w_denom=None, rnn_states=None, l2=None):
         """The loss of one batch. ``rnn_states`` (TBPTT) makes the aux

@@ -8,12 +8,18 @@ the same. A network gives a trainer
 - ``_bind(ds) -> batch``: a DataSet as the pytree its loss reads
   (``(x, y, mask, fmask)`` / ``(inputs, labels, masks)``);
 - ``_loss_of(params, states, batch, key, *, training=True, w=None,
-  w_denom=None, ...) -> (loss, new_states)``: the loss;
+  w_denom=None, ...) -> (loss, new_states)``: the loss, under the scope
+  ``forward`` (so every trainer that differentiates it inherits the name);
 - ``_keyed_layers()``: ``(key, layer)`` for every entry of its params
   container, which says what to keep after an update (``FrozenLayer``) and
   what to project (``constraints``).
 
-and this module writes the rest once: the update epilogue (:func:`update`),
+and this module writes the rest once, under the step's fixed scopes
+(``jax.named_scope``: :data:`FORWARD` on the networks' ``_loss_of``,
+:data:`UPDATE` with ``grad_norm``, ``updater``, ``sr`` and ``constraints``
+inside, ``telemetry``, each vertex under :func:`vertex_scope`;
+``common.xprof.scope_times`` reads them back from a trace): the update
+epilogue (:func:`update`),
 the telemetry tail (:func:`finish`), the step body (:func:`make_core`), the
 per-step and ``lax.scan`` chunk programs (:func:`step_program`,
 :func:`chunk_program`; the network jits them under its census names, which
@@ -45,6 +51,8 @@ from .conf import layers as L
 # leaves that constraints leave alone (reference BaseConstraint: weights
 # only, biases and norm params excluded)
 _UNCONSTRAINED = ("b", "beta", "gamma", "mean", "var", "centers")
+#: the step's phase scopes (``common.xprof.classify_scope`` reads them back)
+FORWARD, UPDATE = "forward", "update"
 
 
 # --- the step -----------------------------------------------------------------
@@ -79,15 +87,25 @@ def _fold_weights(mask, w):
     return mask * wb
 
 
+def vertex_scope(key) -> str:
+    """The ``jax.named_scope`` of one entry of a network's params container:
+    a graph vertex's name (a ``/`` would read as a further scope), a
+    ``MultiLayerNetwork`` layer's index as ``layer<i>``."""
+    return f"layer{key}" if isinstance(key, int) else str(key).replace("/", ".")
+
+
+@jax.named_scope(UPDATE)
 def update(model, updater, grads, upd_state, params, iteration, key):
     """The update epilogue: gradient normalisation
     (``GlobalConf.grad_normalization``) → ``apply_updater`` → frozen layers
     restored → constraints projected. Returns ``(grads, new_params,
-    new_upd)``, the gradients as the updater got them."""
+    new_upd)``, the gradients as the updater got them. Scope ``update``;
+    ``apply_updater`` names ``updater`` and ``sr`` inside it."""
     gc = model.conf.global_conf
     if gc.grad_normalization:
-        grads = _normalize_gradients(grads, gc.grad_normalization,
-                                     gc.grad_norm_threshold)
+        with jax.named_scope("grad_norm"):
+            grads = _normalize_gradients(grads, gc.grad_normalization,
+                                         gc.grad_norm_threshold)
     new_params, new_upd = apply_updater(updater, grads, upd_state, params,
                                         iteration, key)
     for k, layer in model._keyed_layers():
@@ -97,10 +115,11 @@ def update(model, updater, grads, upd_state, params, iteration, key):
             # side effects (weight decay, momentum drift)
             new_params[k] = params[k]
         if getattr(layer, "constraints", None):
-            new_params[k] = {
-                name: (leaf if name in _UNCONSTRAINED
-                       else _project(layer.constraints, leaf))
-                for name, leaf in new_params[k].items()}
+            with jax.named_scope("constraints"):
+                new_params[k] = {
+                    name: (leaf if name in _UNCONSTRAINED
+                           else _project(layer.constraints, leaf))
+                    for name, leaf in new_params[k].items()}
     return grads, new_params, new_upd
 
 
@@ -137,14 +156,15 @@ def finish(tele, loss, old, new, grads=None, *, aux=None, nonfinite=None,
     taken on raw per-shard gradients; ``extra``: further aux entries."""
     if tele is None:
         return (*new, loss)
-    if aux is None:
-        aux = _tel.layer_stats(old[0], new[0], grads, loss,
-                               nonfinite=nonfinite)
-    if extra:
-        aux.update(extra)
-    if tele.nan_guard:
-        aux, *new = _tel.apply_nan_guard(aux, new[0], old[0], new[1],
-                                         old[1], new[2], old[2])
+    with jax.named_scope("telemetry"):
+        if aux is None:
+            aux = _tel.layer_stats(old[0], new[0], grads, loss,
+                                   nonfinite=nonfinite)
+        if extra:
+            aux.update(extra)
+        if tele.nan_guard:
+            aux, *new = _tel.apply_nan_guard(aux, new[0], old[0], new[1],
+                                             old[1], new[2], old[2])
     return (*new, loss, aux)
 
 
@@ -322,6 +342,14 @@ class FitLoop:
 
     def num_params(self) -> int:
         return sum(int(np.prod(l.shape)) for l in jax.tree.leaves(self._params))
+
+    def scope_kinds(self) -> dict:
+        """``{vertex scope: layer class name}``: the kind of each vertex
+        that the step names (:func:`vertex_scope`), for a reader of
+        ``common.xprof.scope_times`` to group vertices without parsing
+        their names."""
+        return {vertex_scope(k): type(layer).__name__
+                for k, layer in self._keyed_layers()}
 
     def score(self, ds, training: bool = False) -> float:
         """The loss of one DataSet at the current parameters."""
