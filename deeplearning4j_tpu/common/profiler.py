@@ -441,7 +441,10 @@ class OpProfiler:
         """Routed-expert ledger (``moe/*`` counters, ``ops/moe.py`` and
         ``RoutedExpertsLayer``): call sites of the grouped matrix product
         that took the Pallas kernels or ``lax.ragged_dot`` (``gmm_kernel`` /
-        ``gmm_fallback``) and the static rows of the dispatch buffers
+        ``gmm_fallback``), call sites of the experts' gated MLP that ran as
+        one fused op or as two products around an XLA activation
+        (``gated_kernel`` / ``gated_fallback``; either way its two products
+        count as grouped products) and the static rows of the dispatch buffers
         (``dispatch_rows``: k x tokens a routed layer, the worst case of a
         dropless layer). Trace-time counters: one bump per call site per
         traced program, not per execution. The realised load is layer

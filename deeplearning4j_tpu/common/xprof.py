@@ -1277,8 +1277,16 @@ def _main(argv=None) -> int:
     ap.add_argument("logdir", help="OpProfiler.trace's logdir, or one "
                     "*.xplane.pb[.gz]")
     ap.add_argument("--by", default="vertex", choices=("vertex", "phase"))
+    ap.add_argument("--inner", default="", help="also list the rows of this "
+                    "layer scope (moe_experts, mla_q, ...) by phase and op, "
+                    "summed over the vertices")
     args = ap.parse_args(argv)
-    print(scope_table(scope_times(args.logdir), args.by))
+    times = scope_times(args.logdir)
+    print(scope_table(times, args.by))
+    if args.inner:
+        rows = [{**r, "phase/op": f"{r['phase']:<10}{r['op']}"}
+                for r in times["rows"] if r["inner"] == args.inner]
+        print(scope_table({**times, "rows": rows}, "phase/op"))
     return 0
 
 

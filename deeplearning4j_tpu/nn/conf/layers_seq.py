@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from ...common.profiler import OpProfiler
-from ...ops.moe import (GMM_ROW_TILE, grouped_matmul, rotary_embedding,
+from ...ops.moe import (GMM_ROW_TILE, grouped_gated_mlp, rotary_embedding,
                         route_topk)
 from ...ops.pallas_attention import causal_attention
 from ...ops.ssm import selective_scan
@@ -567,12 +567,15 @@ class RoutedExpertsLayer(Layer):
     all selected experts, held or not, what the experts that are not held
     would add is left out, and a token that selects no held expert gets
     zero. No capacity: every (token, held expert) pair is computed, for any
-    routing. The pairs are sorted by expert into a buffer and multiplied
-    group by group (``ops.moe.grouped_matmul``, whose kernels skip the tiles
-    beyond the rows that were routed). The buffer holds ``k * tokens`` rows,
-    the worst case (every selection held), so no routing drops a token; the
-    gathers and the elementwise passes around the kernels run over all of
-    it, whatever was routed.
+    routing. The pairs are sorted by expert into a buffer that one op takes
+    through both products and the activation between them
+    (``ops.moe.grouped_gated_mlp``: on the TPU six kernels, forward and
+    backward, over the tiles that hold routed rows and no other, so the
+    buffer's rows beyond the routed total are never written and nothing here
+    reads them). The buffer holds ``k * tokens`` rows, the worst case (every
+    selection held), so no routing drops a token; the two gathers, into the
+    buffer and back, and their backward run over all of it, whatever was
+    routed.
 
     A layer that holds a share of the experts and trains ALONE sees its load
     grow: its router is a replica whose gradient the deployment sums over
@@ -642,9 +645,10 @@ class RoutedExpertsLayer(Layer):
             src = jnp.pad(order, (0, cap - n * k))
             rows = _take_rows(xt, src, dst, live)
         with jax.named_scope("moe_experts"):
-            g, u = jnp.split(grouped_matmul(rows, params["W1"], sizes), 2,
-                             axis=-1)
-            out = grouped_matmul(u * jax.nn.silu(g), params["W2"], sizes)
+            # on the kernel path the rows from the routed total on are
+            # not defined: ``_combine`` and both backward gathers index a
+            # live pair's row, which lies below it
+            out = grouped_gated_mlp(rows, params["W1"], params["W2"], sizes)
         with jax.named_scope("moe_combine"):
             y = _combine(out, p, src, dst, live).astype(xt.dtype)
         y = y.reshape(b, T, d)

@@ -256,6 +256,207 @@ def test_grouped_matmul_takes_the_xla_path_off_the_tiling():
     assert set(prof.moe_stats()) >= {"gmm_fallback", "gmm_kernel"}
 
 
+# --- the gated MLP as one op (``ops.moe.grouped_gated_mlp``) ---------------------
+
+GATED_GROUPS = {
+    **GROUPS,
+    "empty_group_first": [0, 40, 30],
+    "empty_group_in_the_middle": [30, 0, 0, 40],
+    "empty_group_last": [40, 30, 0],
+    "three_groups_share_a_tile": [3, 4, 5, 60],
+    "total_is_the_buffer": [50, 46],
+    "total_off_the_row_tile": [20, 17],
+}
+
+
+def _loop_gated(rows, w1, w2, sizes):
+    """Group by group, no kernel and no ragged product: the live rows only."""
+    ff, outs, r = w2.shape[1], [], 0
+    for g, n in enumerate(sizes):
+        h = jnp.dot(rows[r:r + n], w1[g], precision="highest")
+        outs.append(jnp.dot(jax.nn.silu(h[:, :ff]) * h[:, ff:], w2[g],
+                            precision="highest"))
+        r += n
+    return jnp.concatenate(outs)
+
+
+@pytest.mark.parametrize("case", sorted(GATED_GROUPS))
+def test_grouped_gated_mlp_kernels_match_a_loop_over_the_groups(case):
+    """The six kernels in interpret mode against a per-group loop: the
+    forward on the live rows, the gradients to ``rows`` (live rows), ``w1``
+    and ``w2`` (an empty group's are zero). The rows beyond the total are
+    not defined and are not looked at (interpret mode leaves them NaN)."""
+    sizes = GATED_GROUPS[case]
+    rng = np.random.RandomState(len(sizes))
+    m, d, ff, total = 96, 128, 128, sum(sizes)
+    rows = jnp.asarray(rng.randn(m, d), F32)
+    w1 = jnp.asarray(rng.randn(len(sizes), d, 2 * ff) * 0.1, F32)
+    w2 = jnp.asarray(rng.randn(len(sizes), ff, d) * 0.1, F32)
+    c = jnp.asarray(rng.randn(m, d), F32)
+    gs = jnp.asarray(sizes, jnp.int32)
+    kern = lambda r, a, b: moe.grouped_gated_mlp(       # noqa: E731
+        r, a, b, gs, row_tile=16, interpret=True)[:total]
+    loop = lambda r, a, b: _loop_gated(r, a, b, sizes)  # noqa: E731
+    assert kern(rows, w1, w2).shape == (total, d)
+    gk = jax.grad(lambda *a: jnp.sum(kern(*a) * c[:total]), (0, 1, 2))(
+        rows, w1, w2)
+    empty = [g for g, n in enumerate(sizes) if n == 0]
+    assert not np.asarray(gk[1])[empty].any()
+    assert not np.asarray(gk[2])[empty].any()
+    if not total:
+        return
+    _close(kern(rows, w1, w2), loop(rows, w1, w2))
+    gl = jax.grad(lambda *a: jnp.sum(loop(*a) * c[:total]), (0, 1, 2))(
+        rows, w1, w2)
+    _close(gk[0][:total], gl[0][:total], 2e-5)
+    _tree_close(gk[1:], gl[1:], 2e-5)
+
+
+def test_grouped_gated_mlp_counts_its_path_and_its_two_products():
+    """Once a call site as the program is traced: ``moe/gated_kernel`` or
+    ``moe/gated_fallback``, and the two grouped products it replaces still
+    count as ``moe/gmm_kernel`` / ``moe/gmm_fallback``, so the benchmark's
+    ``*_kernel_fallbacks`` readers find their counters."""
+    prof = OpProfiler.get()
+    names = ("gated_kernel", "gated_fallback", "gmm_kernel", "gmm_fallback")
+    rows, gs = jnp.ones((32, 128), F32), jnp.asarray([20, 5])
+    w1, w2 = jnp.ones((2, 128, 256), F32), jnp.ones((2, 128, 128), F32)
+
+    def bumped(fn, *args):
+        before = prof.moe_stats()
+        jitted = jax.jit(fn)
+        jitted(*args), jitted(*args)        # traced once, run twice
+        return tuple(prof.moe_stats().get(n, 0) - before.get(n, 0)
+                     for n in names)
+
+    assert bumped(lambda *a: moe.grouped_gated_mlp(
+        *a, gs, interpret=True), rows, w1, w2) == (1, 0, 2, 0)
+    # the CPU's default, and widths off the 128-lane tiling anywhere
+    assert bumped(lambda *a: moe.grouped_gated_mlp(*a, gs),
+                  rows, w1, w2) == (0, 1, 0, 2)
+    assert bumped(lambda *a: moe.grouped_gated_mlp(*a, gs, interpret=True),
+                  rows[:, :24], w1[:, :24, :80], w2[:, :40, :24]) \
+        == (0, 1, 0, 2)
+    assert not moe.supports_gated_kernel(24, 40, 4)
+    assert moe.supports_gated_kernel(2048, 1536, 2)
+    assert moe.supports_gated_kernel(2048, 768, 2)
+    # a second matrix too large for one block: the two products apart
+    assert moe.supports_gmm_kernel(4096, 4096, 2)
+    assert not moe.supports_gated_kernel(4096, 4096, 2)
+
+
+WIDE, WIDE_T = 128, 128      # widths on the 128-lane tiling: the kernel path
+
+
+def _wide_routed():
+    """Four held experts of sixteen, top-2, 256 tokens: a buffer of 512 rows
+    (no other axis here is 512 long) of which the held take about a
+    quarter."""
+    layer = L.RoutedExpertsLayer(n_routed=16, n_experts=4, first_expert=2,
+                                 n_ff=WIDE, top_k=2)
+    layer.set_input_type(RNNInput(WIDE, WIDE_T))
+    p = jax.tree.map(lambda a: a * 5.0,
+                     layer.init_params(jax.random.PRNGKey(3), F32))
+    x = jax.random.normal(jax.random.PRNGKey(8), (B, WIDE_T, WIDE), F32)
+    w = jax.random.normal(jax.random.PRNGKey(9), x.shape, F32)
+    loss = lambda p, x: jnp.sum(layer.apply(        # noqa: E731
+        p, x, layer.init_state(), True, None)[0] * w)
+    return layer, p, x, loss
+
+
+@pytest.fixture
+def kernel_path(monkeypatch):
+    """The layer on its kernel path here: the op in interpret mode."""
+    import functools
+
+    from deeplearning4j_tpu.nn.conf import layers_seq
+
+    monkeypatch.setattr(layers_seq, "grouped_gated_mlp", functools.partial(
+        moe.grouped_gated_mlp, interpret=True))
+
+
+def test_routed_layer_on_the_kernels_reads_no_row_beyond_the_total(
+        monkeypatch):
+    """The layer's output and every gradient on the kernel path equal the
+    fallback path's with the buffer's undefined rows POISONED: every array
+    a ``moe_gmm`` kernel hands back (the first product, the result, the
+    first product's cotangent, the buffer's cotangent) has its rows from
+    the routed total on set to NaN, the boundary tile's too. Nothing reads
+    them: the next kernel masks by its group's rows, the layer gathers by a
+    live pair's row."""
+    import functools
+
+    from deeplearning4j_tpu.nn.conf import layers_seq
+
+    layer, p, x, loss = _wide_routed()
+    prof = OpProfiler.get()
+    ref = jax.jit(jax.value_and_grad(loss, (0, 1)))(p, x)   # the CPU's path
+    assert prof.counter_value("moe/gated_fallback") >= 1
+    real, poisoned = moe._gmm_pallas, []
+
+    def poison(x_, w_, items, *a, **kw):
+        y = real(x_, w_, items, *a, **kw)
+        poisoned.append(y.shape)
+        return jnp.where(jnp.arange(y.shape[0])[:, None] < items[3][-1], y,
+                         jnp.nan)
+
+    monkeypatch.setattr(moe, "_gmm_pallas", poison)
+    monkeypatch.setattr(layers_seq, "grouped_gated_mlp", functools.partial(
+        moe.grouped_gated_mlp, interpret=True))
+    before = prof.counter_value("moe/gated_kernel")
+    got = jax.jit(jax.value_and_grad(loss, (0, 1)))(p, x)
+    assert prof.counter_value("moe/gated_kernel") == before + 1
+    assert sorted(poisoned) == [(512, 128)] * 2 + [(512, 256)] * 2
+    assert np.isfinite(np.asarray(got[0]))
+    _close(got[0], ref[0])
+    _tree_close(got[1], ref[1], 2e-5)
+    # the held experts took under a half of the buffer: the rest was NaN
+    _, st = layer.apply(p, x, layer.init_state(), True, None)
+    assert 0 < float(st["expert_load"][2:6].sum()) < 256
+
+
+def _eqns(jaxpr, outer=""):
+    """Every equation with its whole name stack, a Pallas call's body left
+    out (what runs inside a kernel is a tile's, not the buffer's)."""
+    for e in jaxpr.eqns:
+        stack = outer + "/" + str(e.source_info.name_stack)
+        yield e, stack
+        if e.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from _eqns(sub, stack)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "gradient"])
+def test_no_buffer_sized_pass_between_the_dispatch_and_the_combine(
+        kernel_path, grad):
+    """On the kernel path the scope ``moe_experts`` holds the kernels, the
+    work items' arithmetic and the clear of an empty group's gradient: no
+    equation but a ``pallas_call`` takes or gives an array with the
+    buffer's 512 rows — no ``select_n``, ``mul`` or ``logistic`` over it
+    comes back with a later edit without failing here."""
+    layer, p, x, loss = _wide_routed()
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)) if grad else loss)(p, x)
+    seen = {"pallas_call": 0}
+    for e, stack in _eqns(jaxpr.jaxpr):
+        if "moe_experts" not in stack:
+            continue
+        rows = [v.aval.shape for v in (*e.invars, *e.outvars)
+                if getattr(v.aval, "shape", ()) and v.aval.shape[0] == 512]
+        if e.primitive.name == "pallas_call":
+            seen["pallas_call"] += 1
+            assert rows and "moe_gmm" in stack
+        elif jax.core.jaxprs_in_params(e.params):
+            continue        # a call: its body is walked
+        else:
+            assert not rows, (e.primitive.name, stack, rows)
+    assert seen["pallas_call"] == (6 if grad else 2)
+    # the reader is not blind: the other scopes do run over the buffer
+    assert any("moe_combine" in stack and e.primitive.name == "gather"
+               and any(getattr(v.aval, "shape", ())[:1] == (512,)
+                       for v in e.invars)
+               for e, stack in _eqns(jaxpr.jaxpr))
+
+
 @pytest.mark.parametrize("t", [T, 256])
 def test_routed_experts_dropless_when_every_token_goes_to_the_held_experts(t):
     """A bias that sends all four selections of every token to the eight

@@ -1356,6 +1356,26 @@ class TestRoutedExpertOps:
                           interpret=interpret)
             np.testing.assert_allclose(np.asarray(got), ref, atol=2e-5)
 
+    def test_grouped_gated_mlp(self):
+        rng = np.random.RandomState(11)
+        x = rng.randn(48, 128).astype(np.float32)
+        w1 = rng.randn(3, 128, 256).astype(np.float32) * 0.1
+        w2 = rng.randn(3, 128, 128).astype(np.float32) * 0.1
+        sizes = np.asarray([20, 0, 17], np.int32)
+
+        def expert(rows, g):
+            h = rows @ w1[g]
+            gate, up = h[:, :128], h[:, 128:]
+            return (gate / (1.0 + np.exp(-gate)) * up) @ w2[g]
+
+        ref = np.concatenate([expert(x[:20], 0), expert(x[20:37], 2)])
+        for interpret in (None, True):
+            got = exec_op("grouped_gated_mlp", x, w1, w2, sizes, row_tile=16,
+                          interpret=interpret)
+            # the rows beyond the total are the fallback's zeros and the
+            # kernels' undefined
+            np.testing.assert_allclose(np.asarray(got)[:37], ref, atol=2e-5)
+
     def test_rotary_embedding(self):
         rng = np.random.RandomState(10)
         x = rng.randn(2, 5, 8).astype(np.float32)

@@ -425,6 +425,25 @@ def test_recorded_phases_and_kinds(recorded):
     assert t["unattributed_ms"] < 0.25 * t["step_ms"]
 
 
+def test_cli_lists_one_layer_scope_by_phase_and_op(recorded, capsys):
+    """``--inner moe_experts`` (PR 37's step 0): after the by-phase table,
+    the rows of that layer scope by phase and op, summed over the vertices,
+    adding up to the scope's own time."""
+    t, _ = recorded
+    assert xprof._main([FIXTURE, "--by", "phase", "--inner",
+                        "moe_experts"]) == 0
+    first, second = capsys.readouterr().out.split(" steps, ")[1:]
+    listed = [line.split() for line in second.splitlines()[1:]]
+    want = sum(r["ms"] for r in t["rows"] if r["inner"] == "moe_experts")
+    assert sum(float(l[0]) for l in listed) == pytest.approx(want, abs=1e-3 *
+                                                             len(listed))
+    assert {l[3] for l in listed} <= {"forward", "recompute", "backward"}
+    # (the recorded decoder is 64 wide: its experts ran ``ragged_dot`` and
+    # the masks PR 37 took off the kernel path)
+    assert any(l[4] == "broadcast_select_fusion[kLoop]" for l in listed)
+    assert "  update" in first and "fusion" not in first
+
+
 def test_recorded_while_is_self_time(recorded):
     """The head's token-block loops: the ``while`` rows hold the loops' own
     time, not their bodies' again (the reducer's ``short_name`` sum counts a
@@ -554,6 +573,69 @@ def test_stop_profiles_once_and_survives_a_program_without_the_reader(
     old = {"job": Job(), "data": None, "conf": Conf, "phase": None}
     _metric("scope_ms.head").stop(old)
     assert old["scope_table"] is None and calls == [1]
+
+
+@pytest.mark.parametrize("name", ["moe_kernel_fallbacks",
+                                  "mla_moe_kernel_fallbacks"])
+def test_kernel_fallback_readers_still_count_the_gated_op(name):
+    """The routed layer calls one gated op where it called two grouped
+    products (PR 37); the op counts its two products under the names the
+    benchmark's readers (files this PR does not touch) add up: a kernel-path
+    trace moves ``moe/gmm_kernel`` and leaves the reading alone, a fallback
+    trace adds its two products to it, and the reading is a number, not
+    "nothing to read"."""
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops import moe
+
+    metric = _metric(name)
+
+    def reading():
+        ctx = {}
+        metric.stop(ctx)
+        return metric.read(ctx)
+
+    f32 = jnp.float32
+    args = (jnp.ones((32, 128), f32), jnp.ones((2, 128, 256), f32),
+            jnp.ones((2, 128, 128), f32), jnp.asarray([20, 5]))
+    prof = OpProfiler.get()
+    kernels = prof.counter_value("moe/gmm_kernel")
+    moe.grouped_gated_mlp(*args, interpret=True)
+    assert prof.counter_value("moe/gmm_kernel") == kernels + 2
+    before = reading()
+    assert isinstance(before, (int, float))
+    moe.grouped_gated_mlp(*args, interpret=True)
+    assert reading() == before
+    moe.grouped_gated_mlp(*args)            # the CPU's own path
+    assert reading() == before + 2
+
+
+def test_compile_step_lists_a_scope_outside_its_kernels(capsys):
+    """``tools/compile_step.py --scope``: the compiled entry's instructions
+    under a scope, Pallas calls left out, largest result first — how PR 37
+    saw, with no chip, that nothing buffer-sized was left between the routed
+    layer's kernels."""
+    spec = importlib.util.spec_from_file_location(
+        "compile_step_tool", os.path.join(ROOT, "tools", "compile_step.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    name = 'metadata={op_name="jit(step)/jvp(forward)/l2_ffn/%s"}'
+    tool.print_scope("\n".join([
+        "ENTRY %main {",
+        "  %moe_gmm.1 = bf16[65536,3072]{1,0} custom-call(%a), "
+        'custom_call_target="tpu_custom_call", ' + name % "moe_experts/moe_gmm",
+        "  %broadcast_select_fusion.7 = bf16[65536,3072]{1,0:T(8,128)(2,1)} "
+        "fusion(%b), kind=kLoop, " + name % "moe_experts/select_n",
+        "  %fusion.3 = s32[263]{0} fusion(%c), kind=kLoop, "
+        + name % "moe_experts/cumsum",
+        "  %fusion.9 = bf16[65536,2048]{1,0} fusion(%d), kind=kCustom, "
+        + name % "moe_combine/gather",
+        "}"]), "moe_experts")
+    out = capsys.readouterr().out.splitlines()
+    assert "'instructions': 2" in out[0]
+    assert f"'elements_of_results': {65536 * 3072 + 263}" in out[0]
+    assert "broadcast_select_fusion.7" in out[1] and "fusion.3" in out[2]
+    assert len(out) == 3
 
 
 def test_manifest_lists_the_six_metrics():

@@ -5,8 +5,8 @@ heads, a window and bfloat16 operands
 (``ops/pallas_attention.causal_attention``); and at the shapes of
 ``lfm2_moe.train_b2_s8k``: the grouped expert products forward and backward
 (``ops/moe.py``: 65,536 dispatch rows, 8 experts of 2048 x 3072 and 1536 x
-2048) and the attention with 32 query heads over 8 key/value heads; and at
-the shapes of ``joyai_llm_flash.train_b2_s8k``: the attention with 32 heads
+2048; apart, and as the one gated op the routed layer calls) and the
+attention with 32 query heads over 8 key/value heads; and at the shapes of ``joyai_llm_flash.train_b2_s8k``: the attention with 32 heads
 whose keys are 192 wide and whose values are 128 wide (latent attention as it
 is trained), and the grouped products over 131,072 dispatch rows and 16
 experts of 2048 x 1536 and 768 x 2048. As ``tests/test_tpu_compile.py``:
@@ -106,6 +106,24 @@ def _grouped(k, n, grad, rows=65536, held=8):
     return (both if grad else fwd), shapes
 
 
+def _gated(ff, grad, rows=65536, held=8, d=2048):
+    # the one op the routed layer calls: two kernels forward; the gradient
+    # keeps the first of them and runs four more (the second product's
+    # result is no residual)
+    bf16 = jnp.bfloat16
+    shapes = [((rows, d), bf16), ((held, d, 2 * ff), bf16),
+              ((held, ff, d), bf16), ((held,), jnp.int32)]
+
+    def fwd(x, w1, w2, sizes):
+        return moe._gated(x, w1, w2, sizes, moe.GMM_ROW_TILE, False)
+
+    def both(x, w1, w2, sizes):
+        return jax.grad(lambda x, w1, w2: fwd(x, w1, w2, sizes).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(x, w1, w2)
+
+    return (both if grad else fwd), shapes
+
+
 def _gqa(grad):
     # lfm2_moe: batch 2, 32 query heads of 64 in groups of 4 over 8 heads
     bf16 = jnp.bfloat16
@@ -147,6 +165,10 @@ CASES = {
         lambda: _grouped(2048, 1536, True, 131072, 16), 2),
     "moe_gmm_e16_down_fwd_bwd": (
         lambda: _grouped(768, 2048, True, 131072, 16), 2),
+    "moe_gated_mlp_fwd": (lambda: _gated(1536, False), 2),
+    "moe_gated_mlp_fwd_bwd": (lambda: _gated(1536, True), 5),
+    "moe_gated_mlp_e16_fwd": (lambda: _gated(768, False, 131072, 16), 2),
+    "moe_gated_mlp_e16_fwd_bwd": (lambda: _gated(768, True, 131072, 16), 5),
     "moe_gmm_up_fwd": (lambda: _grouped(2048, 3072, False), 1),
     # input-gradient and weight-gradient kernels (the forward's result is
     # not needed for them and is dropped)
@@ -188,6 +210,9 @@ def supports(case):
     if "mla" in case:
         return (pa.supports_band_kernel(T, 192, 128, pa.BAND_BLOCK)
                 and pa.supports_band_bwd_kernel(T, 192, 1, 2))
+    if case.startswith("moe_gated"):
+        return moe.supports_gated_kernel(
+            2048, 768 if "e16" in case else 1536, 2)
     if case.startswith("moe_gmm_e16"):
         return (moe.supports_gmm_kernel(2048, 1536, 2)
                 and moe.supports_gmm_kernel(768, 2048, 2))

@@ -4,6 +4,7 @@ no chip: what the step's scratch will be, and how XLA fused the stochastic
 rounding of the updater state.
 
     JAX_PLATFORMS=cpu python3 tools/compile_step.py <workload> [--root CHECKOUT]
+                                                    [--scope NAME] [--dump FILE]
 
 It builds the cell's job at its real sizes (the model is initialised on the
 CPU: a few GB for the decoder cells), traces ``nn.train_step``'s step with
@@ -18,13 +19,19 @@ on the chip for ``lfm2_moe.train_b2_s8k``), so a change's effect on
 ``seq/*`` counters that tracing the step bumped (call sites on a kernel or on
 the XLA path, the attention band's pairs and the forward's grid steps).
 Nothing runs: no time, no rate. One process at a time (libtpu's lock).
-``--root`` takes an unpacked parent commit, to compare. Only
-``ComputationGraph.fit`` cells.
+``--root`` takes an unpacked parent commit, to compare. ``--scope`` lists what
+the compiled step runs under a named scope apart from its Pallas calls (PR
+37: ``--scope moe_experts`` shows whether a buffer-sized fusion, copy or
+convert stands between the routed layer's kernels), largest result first;
+``--dump`` writes the compiled module's text. Only ``ComputationGraph.fit``
+cells.
 """
 
 import argparse
 import collections
+import math
 import os
+import re
 import sys
 import time
 
@@ -34,6 +41,8 @@ def main() -> None:
     ap.add_argument("workload")
     ap.add_argument("--root", default=os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
+    ap.add_argument("--scope", default="")
+    ap.add_argument("--dump", default="")
     args = ap.parse_args()
     root = os.path.abspath(args.root)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -95,7 +104,36 @@ def main() -> None:
            "pallas_calls": text.count("tpu_custom_call"),
            "rounding_fusions_by_bf16_outputs": sorted(rounding.items()),
            # what tracing the step bumped, the kernels' switch on
-           "sequence_stats": OpProfiler.get().sequence_stats()})
+           "sequence_stats": OpProfiler.get().sequence_stats(),
+           "moe_stats": OpProfiler.get().moe_stats()})
+    if args.scope:
+        print_scope(entry, args.scope)
+    if args.dump:
+        with open(args.dump, "w") as f:
+            f.write(text)
+
+
+def print_scope(entry: str, scope: str) -> None:
+    """The entry computation's instructions whose ``op_name`` holds
+    ``scope``, Pallas calls left out: how many, the elements of their
+    (largest) results together, then the twelve largest."""
+    found = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?(\S+) = (.*?) ([\w\-]+)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if not m or not name or scope not in name.group(1) \
+                or "tpu_custom_call" in line:
+            continue
+        size = max((math.prod(map(int, dims.split(",")))
+                    for dims in re.findall(r"\[([\d,]+)\]", m.group(2))),
+                   default=1)
+        found.append((size, m.group(3), m.group(1), m.group(2)[:70],
+                      name.group(1)[-70:]))
+    found.sort(reverse=True)
+    print({"scope": scope, "instructions": len(found),
+           "elements_of_results": sum(f[0] for f in found)})
+    for row in found[:12]:
+        print("  ", row)
 
 
 if __name__ == "__main__":
