@@ -24,7 +24,7 @@ from ..nn.conf import layers as L
 from ..nn.conf.builder import NeuralNetConfiguration
 from ..nn.conf.inputs import InputType
 from ..nn.graph import (ComputationGraph, ComputationGraphConfiguration,
-                        ElementWiseVertex, MergeVertex)
+                        ElementWiseVertex, MergeVertex, ScaleVertex)
 from ..nn.multilayer import MultiLayerNetwork
 
 
@@ -1336,6 +1336,147 @@ class JoyAILLMFlash(ZooModel):
         tokens = InputType.recurrent(self.vocab_rows, self.seq_len)
         conf = (gb.set_outputs(*outputs)
                 .set_input_types(*[tokens] * len(inputs)).build())
+        gc = conf.global_conf
+        gc.compute_dtype = self.compute_dtype
+        gc.remat_policy = self.remat_policy
+        return ComputationGraph(conf).init()
+
+
+class TrinityMini(ZooModel):
+    """Trinity-Mini (``model_type`` ``afmoe``, Arcee's 26B-A3B;
+    huggingface.co/arcee-ai/Trinity-Mini, ``config.json``; the family's
+    modelling code is ``models/afmoe`` of Hugging Face ``transformers``): a
+    decoder of sandwich-norm blocks ``x + RMSNorm(Attn(RMSNorm(x)))``, ``x +
+    RMSNorm(FFN(RMSNorm(x)))``. Attention is grouped-query with per-head
+    RMSNorm on queries and keys and a sigmoid gate on its output
+    (``RotaryAttentionLayer(output_gate=True)``); ``layer_types`` makes a
+    layer ``sliding_attention`` (a window of ``sliding_window``, rotary
+    positions) or ``full_attention`` (every earlier position, no rotation:
+    NoPE). The feed-forward is a dense gated MLP in the first
+    ``num_dense_layers`` layers and, after them, ``num_experts`` routed
+    experts, ``num_experts_per_tok`` a token (sigmoid scores, a selection
+    bias moved by the balance rule at rate ``load_balance_coeff``, weights
+    normalised over the selected with the family's 1e-20, times
+    ``route_scale``) plus ``num_shared_experts`` shared experts, summed
+    before the post-MLP norm. ``mup_enabled``: the embedding is scaled by
+    ``sqrt(hidden_size)`` after the lookup (``ScaleVertex``). A final
+    RMSNorm and an untied head (``LMHeadLayer``).
+
+    ``layers``: the published layer indices to build, in order (all when
+    None). ``vocab_rows``: rows of the embedding and of the head held here.
+    ``experts_held``: ``(first, count)`` of the experts of every routed layer
+    that live here (all when None), as ``Lfm2Moe``'s. Defaults are the
+    published sizes. Trained through ``ComputationGraph.fit`` on ``[B, T]``
+    integer ids with ``[B, T]`` integer next-token labels; each routed
+    layer's state holds its ``bias`` and ``expert_load``."""
+
+    def __init__(self, layers: Optional[Sequence[int]] = None,
+                 vocab_rows: int = 200192,
+                 experts_held: Optional[Tuple[int, int]] = None,
+                 hidden_size: int = 2048, intermediate_size: int = 6144,
+                 moe_intermediate_size: int = 1024,
+                 num_attention_heads: int = 32, num_key_value_heads: int = 4,
+                 head_dim: int = 128, num_experts: int = 128,
+                 num_experts_per_tok: int = 8, num_shared_experts: int = 1,
+                 route_scale: float = 2.826, num_dense_layers: int = 2,
+                 num_hidden_layers: int = 32,
+                 layer_types: Optional[Sequence[str]] = None,
+                 global_attn_every_n_layers: int = 4,
+                 sliding_window: int = 2048, rms_norm_eps: float = 1e-5,
+                 rope_theta: float = 10000.0, mup_enabled: bool = True,
+                 load_balance_coeff: float = 0.001,
+                 seq_len: Optional[int] = None,
+                 compute_dtype: Optional[str] = "bfloat16",
+                 state_dtype: Optional[str] = "bfloat16",
+                 remat_policy="full", learning_rate: float = 1e-4,
+                 weight_decay: float = 0.1, seed: int = 123):
+        self.layers = list(range(num_hidden_layers) if layers is None
+                           else layers)
+        self.layer_types = list(layer_types or [
+            "full_attention" if (l + 1) % global_attn_every_n_layers == 0
+            else "sliding_attention" for l in range(num_hidden_layers)])
+        self.vocab_rows = vocab_rows
+        self.experts_held = experts_held or (0, num_experts)
+        self.d, self.ff, self.moe_ff = (hidden_size, intermediate_size,
+                                        moe_intermediate_size)
+        self.heads, self.kv_heads, self.head_dim = (
+            num_attention_heads, num_key_value_heads, head_dim)
+        self.experts, self.shared = num_experts, num_shared_experts
+        self.top_k, self.scale = num_experts_per_tok, route_scale
+        self.dense_layers, self.window = num_dense_layers, sliding_window
+        self.eps, self.theta = rms_norm_eps, rope_theta
+        self.mup, self.balance_rate = mup_enabled, load_balance_coeff
+        self.seq_len = seq_len
+        self.compute_dtype, self.state_dtype = compute_dtype, state_dtype
+        self.remat_policy = remat_policy
+        self.learning_rate, self.weight_decay = learning_rate, weight_decay
+        self.seed = seed
+
+    def is_sliding(self, l: int) -> bool:
+        return self.layer_types[l] == "sliding_attention"
+
+    def _block(self, gb, l: int, prev: str) -> str:
+        """One sandwich-norm block under the nodes ``l<l>_*``; returns its
+        output node."""
+        norm = lambda: L.RMSNormLayer(eps=self.eps)         # noqa: E731
+        name, sliding = f"l{l}", self.is_sliding(l)
+        gb.add_layer(f"{name}_ln1", norm(), prev)
+        gb.add_layer(f"{name}_attn", L.RotaryAttentionLayer(
+            n_heads=self.heads, n_kv_heads=self.kv_heads,
+            head_dim=self.head_dim, rope_theta=self.theta, eps=self.eps,
+            window=self.window if sliding else None, rope=sliding,
+            output_gate=True), f"{name}_ln1")
+        gb.add_layer(f"{name}_post_ln1", norm(), f"{name}_attn")
+        gb.add_vertex(f"{name}_add1", ElementWiseVertex(op="add"),
+                      prev, f"{name}_post_ln1")
+        gb.add_layer(f"{name}_ln2", norm(), f"{name}_add1")
+        if l < self.dense_layers:
+            gb.add_layer(f"{name}_ffn", L.GatedMLPLayer(n_ff=self.ff),
+                         f"{name}_ln2")
+            ffn = f"{name}_ffn"
+        else:
+            first, held = self.experts_held
+            gb.add_layer(f"{name}_ffn", L.RoutedExpertsLayer(
+                n_routed=self.experts, n_experts=held, first_expert=first,
+                n_ff=self.moe_ff, top_k=self.top_k, scale=self.scale,
+                norm_eps=1e-20, bias_update_rate=self.balance_rate),
+                f"{name}_ln2")
+            # the shared experts: whole on every chip that shares the layer
+            gb.add_layer(f"{name}_shared", L.GatedMLPLayer(
+                n_ff=self.shared * self.moe_ff, scope="shared_expert"),
+                f"{name}_ln2")
+            gb.add_vertex(f"{name}_moe", ElementWiseVertex(op="add"),
+                          f"{name}_ffn", f"{name}_shared")
+            ffn = f"{name}_moe"
+        gb.add_layer(f"{name}_post_ln2", norm(), ffn)
+        gb.add_vertex(f"{name}_add2", ElementWiseVertex(op="add"),
+                      f"{name}_add1", f"{name}_post_ln2")
+        return f"{name}_add2"
+
+    def init(self) -> ComputationGraph:
+        updater = AdamW(learning_rate=self.learning_rate, beta1=0.9,
+                        beta2=0.95, epsilon=1e-8,
+                        weight_decay=self.weight_decay)
+        updater.state_dtype = self.state_dtype
+        gb = (ComputationGraphConfiguration
+              .graph_builder(NeuralNetConfiguration.builder()
+                             .seed(self.seed).updater(updater))
+              .add_inputs("ids"))
+        gb.add_layer("embed", L.EmbeddingSequenceLayer(
+            n_out=self.d, weight_init="normal"), "ids")
+        prev = "embed"
+        if self.mup:
+            gb.add_vertex("embed_scale", ScaleVertex(scale=self.d ** 0.5),
+                          prev)
+            prev = "embed_scale"
+        for l in self.layers:
+            prev = self._block(gb, l, prev)
+        gb.add_layer("final_ln", L.RMSNormLayer(eps=self.eps), prev)
+        gb.add_layer("head", L.LMHeadLayer(n_out=self.vocab_rows), "final_ln")
+        conf = (gb.set_outputs("head")
+                .set_input_types(InputType.recurrent(self.vocab_rows,
+                                                     self.seq_len))
+                .build())
         gc = conf.global_conf
         gc.compute_dtype = self.compute_dtype
         gc.remat_policy = self.remat_policy

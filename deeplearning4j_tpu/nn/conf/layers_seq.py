@@ -10,10 +10,13 @@ depthwise causal convolution of a few taps), ``RotaryAttentionLayer``
 (grouped-query attention with per-head RMSNorm on queries and keys and rotary
 positions) and ``RoutedExpertsLayer`` (a dropless top-k expert layer that
 holds a share of the experts; the second kind of layer with state, after
-BatchNorm: a constant selection bias and the accumulated load of each
-expert): ``models.Lfm2Moe`` (the ``lfm2_moe`` family of Hugging Face
-``transformers``). ``LatentAttentionLayer`` (attention through low-rank
-latents, a rotated slice of each head, keys wider than values),
+BatchNorm: a selection bias and the accumulated load of each expert):
+``models.Lfm2Moe`` (the ``lfm2_moe`` family of Hugging Face
+``transformers``); the same two with a window, an output gate and full
+layers without rotation, and a selection bias moved by a balance rule:
+``models.TrinityMini`` (the ``afmoe`` family). ``LatentAttentionLayer``
+(attention through low-rank latents, a rotated slice of each head, keys
+wider than values),
 ``MTPMergeLayer`` (the entry of a multi-token-prediction module) and
 ``LMHeadLayer`` (the head that owns its matrix; ``TiedOutputLayer`` is the
 same head reading another node's): ``models.JoyAILLMFlash`` (the DeepSeek-V3
@@ -337,13 +340,25 @@ class RotaryAttentionLayer(Layer):
     gain each (``q_norm``, ``k_norm``); rotate-half rotary embedding at
     positions 0..T-1; query head ``h`` reads key/value head ``h //
     (n_heads / n_kv_heads)``; ``softmax(q k^T / sqrt(head_dim) + causal
-    mask) v``; heads concatenated into ``Wo``."""
+    mask) v``; heads concatenated into ``Wo``.
+
+    ``window``: query ``i`` sees keys ``i - window < j <= i`` (all ``j <=
+    i`` when None). ``rope`` False: no rotation, no position at all (NoPE:
+    the full layers of the ``afmoe`` family). ``output_gate``: the heads'
+    output is gated before ``Wo``, ``o * sigmoid(x W_gate)`` with ``W_gate``
+    ``[d, n_heads * head_dim]`` from the same ``x`` (scope ``attn_gate``).
+    As a step is traced, ``seq/attn_nope_layers`` and
+    ``seq/attn_gated_layers`` count the layers that skip the rotation and
+    that gate."""
 
     n_heads: int = 0
     n_kv_heads: int = 0
     head_dim: int = 64
     rope_theta: float = 10000.0
     eps: float = 1e-5
+    window: Optional[int] = None
+    rope: bool = True
+    output_gate: bool = False
 
     full_precision_params = ("q_norm", "k_norm")
 
@@ -355,31 +370,43 @@ class RotaryAttentionLayer(Layer):
         ks = jax.random.split(key, 4)
         d, hd = self.n_in, self.head_dim
         nq, nkv = self.n_heads * hd, self.n_kv_heads * hd
-        return {"Wq": _normal(ks[0], (d, nq), dtype),
-                "Wk": _normal(ks[1], (d, nkv), dtype),
-                "Wv": _normal(ks[2], (d, nkv), dtype),
-                "Wo": _normal(ks[3], (nq, d), dtype),
-                "q_norm": jnp.ones((hd,), dtype),
-                "k_norm": jnp.ones((hd,), dtype)}
+        params = {"Wq": _normal(ks[0], (d, nq), dtype),
+                  "Wk": _normal(ks[1], (d, nkv), dtype),
+                  "Wv": _normal(ks[2], (d, nkv), dtype),
+                  "Wo": _normal(ks[3], (nq, d), dtype),
+                  "q_norm": jnp.ones((hd,), dtype),
+                  "k_norm": jnp.ones((hd,), dtype)}
+        if self.output_gate:
+            params["W_gate"] = _normal(jax.random.fold_in(key, 4), (d, nq),
+                                       dtype)
+        return params
 
     def apply(self, params, x, state, training, rng):
         hd = self.head_dim
+        prof = OpProfiler.get()
         with jax.named_scope("rope_attn"):
             b, T, _ = x.shape
             pos = jnp.arange(T)
+            if not self.rope:
+                prof.count("seq/attn_nope_layers")
 
             def heads(w, n, gain=None):     # -> [B, n, T, hd]
                 a = (x @ params[w]).reshape(b, T, n, hd)
                 if gain is not None:
                     a = _rms(a, params[gain], self.eps)
                 a = a.transpose(0, 2, 1, 3)
-                return (a if gain is None
+                return (a if gain is None or not self.rope
                         else rotary_embedding(a, pos, self.rope_theta))
 
             o = causal_attention(heads("Wq", self.n_heads, "q_norm"),
                                  heads("Wk", self.n_kv_heads, "k_norm"),
-                                 heads("Wv", self.n_kv_heads))
+                                 heads("Wv", self.n_kv_heads),
+                                 window=self.window)
             o = o.transpose(0, 2, 1, 3).reshape(b, T, self.n_heads * hd)
+            if self.output_gate:
+                prof.count("seq/attn_gated_layers")
+                with jax.named_scope("attn_gate"):
+                    o = o * jax.nn.sigmoid(x @ params["W_gate"])
             return o @ params["Wo"], state
 
 
@@ -583,11 +610,16 @@ class RoutedExpertsLayer(Layer):
     held experts pulls every token towards them (``PERF.md``, PR 32: the
     held experts' load grew 5.3-fold in 96 steps of AdamW).
 
-    State: ``bias`` ``[n_routed]``, the selection bias, a constant that
-    takes no gradient (whoever trains with a balance rule sets it between
-    steps), and ``expert_load`` ``[n_routed]`` float32, the tokens that
+    State: ``bias`` ``[n_routed]``, the selection bias, which takes no
+    gradient, and ``expert_load`` ``[n_routed]`` float32, the tokens that
     selected each expert, accumulated over the training steps since it was
-    last cleared."""
+    last cleared. ``bias_update_rate`` γ: auxiliary-loss-free balancing
+    (DeepSeek-V3, arXiv:2412.19437 section 2.1.2), applied in the step after
+    its selection: with ``c`` this step's selections of each of the
+    ``n_routed`` experts, ``δ = γ sign(mean(c) - c)`` and ``bias += δ -
+    mean(δ)`` (scope ``moe_bias_rule``; ``moe/bias_rule_layers`` counts the
+    layers that apply it as a step is traced). On a share the counts are
+    this chip's, as the router's gradient is. 0: a constant bias."""
 
     n_routed: int = 0
     n_experts: int = 0
@@ -597,6 +629,7 @@ class RoutedExpertsLayer(Layer):
     scale: float = 1.0
     norm_eps: float = 1e-6      # in the weights' denominator
     selection_bias: Optional[Sequence[float]] = None    # zeros when None
+    bias_update_rate: float = 0.0
 
     full_precision_params = ("Wg",)
 
@@ -654,6 +687,12 @@ class RoutedExpertsLayer(Layer):
         y = y.reshape(b, T, d)
         if training:
             state = {**state, "expert_load": state["expert_load"] + load}
+            if self.bias_update_rate:
+                OpProfiler.get().count("moe/bias_rule_layers")
+                with jax.named_scope("moe_bias_rule"):
+                    delta = self.bias_update_rate * jnp.sign(
+                        jnp.mean(load) - load)
+                    state["bias"] = state["bias"] + delta - jnp.mean(delta)
         return y, state
 
 

@@ -82,10 +82,12 @@ def test_manifest_with_the_new_entries():
     assert set(new) == set(NEW)
     assert all(x["workloads"] == [CELL] and x["layer"] == "kernels"
                and x["moves"] == "examples_per_s" for x in new.values())
-    # (PR 36 appended its six ``scope_*`` entries, whose lists name the cell)
+    # the four were appended together; PR 36 appended its six ``scope_*``
+    # entries after them (their lists name the cell), later PRs their own
     accepted = [x for x in m["per_layer"]
                 if not x["name"].startswith("scope_")]
-    assert [x["name"] for x in accepted[-4:]] == list(NEW)
+    first = [x["name"] for x in accepted].index(next(iter(NEW)))
+    assert [x["name"] for x in accepted[first:first + 4]] == list(NEW)
     # the accepted closed lists stay the accepted cells'
     assert all(CELL not in x.get("workloads", []) for x in accepted
                if x["name"] not in NEW)

@@ -9,7 +9,10 @@ heads, a window and bfloat16 operands
 attention with 32 query heads over 8 key/value heads; and at the shapes of ``joyai_llm_flash.train_b2_s8k``: the attention with 32 heads
 whose keys are 192 wide and whose values are 128 wide (latent attention as it
 is trained), and the grouped products over 131,072 dispatch rows and 16
-experts of 2048 x 1536 and 768 x 2048. As ``tests/test_tpu_compile.py``:
+experts of 2048 x 1536 and 768 x 2048; and at the shapes of
+``trinity_mini.train_s16k``: the attention forward with 8 query heads of 128
+a key/value head at 16,384 tokens, a window of 2,048 and none (the backward
+there is the XLA loops). As ``tests/test_tpu_compile.py``:
 the compiler is installed with jax and compiles for a chip that is DESCRIBED,
 not attached; a compile that passes is not a chip run.
 """
@@ -28,6 +31,7 @@ from deeplearning4j_tpu.ops import pallas_attention as pa
 from deeplearning4j_tpu.ops import ssm
 
 T, D_INNER, D_STATE = 8192, 5120, 16        # the cell's sequence and widths
+T16 = 16384                                 # trinity_mini.train_s16k's
 
 
 @pytest.fixture(scope="module")
@@ -158,7 +162,25 @@ def _mla(grad):
     return (both if grad else fwd), shapes
 
 
+def _gqa8(window):
+    # trinity_mini: one 16,384-token sequence, 32 query heads of 128 in
+    # groups of 8 over 4 key/value heads; a window layer and a full one. The
+    # group's blocks and scratch exceed the default scoped VMEM, so the
+    # forward asks for more; the backward is the XLA loops
+    bf16 = jnp.bfloat16
+    shapes = [((1, 4, 8, T16, 128), bf16), ((1, 4, T16, 128), bf16),
+              ((1, 4, T16, 128), bf16)]
+
+    def fwd(q, k, v):
+        return pa._band(q, k, v, 128 ** -0.5, window, pa.BAND_BLOCK, True,
+                        False)
+
+    return fwd, shapes
+
+
 CASES = {
+    "attention_gqa8_d128_t16384_full_fwd": (lambda: _gqa8(None), 1),
+    "attention_gqa8_d128_t16384_window_fwd": (lambda: _gqa8(2048), 1),
     "attention_mla_d192_dv128_fwd": (lambda: _mla(False), 1),
     "attention_mla_d192_dv128_fwd_bwd": (lambda: _mla(True), 2),
     "moe_gmm_e16_up_fwd_bwd": (
@@ -195,6 +217,20 @@ CASES = {
 }
 
 
+@pytest.mark.parametrize("T_", [8192, T16])
+def test_a_group_of_8_heads_of_128_takes_the_xla_backward(T_):
+    """The backward kernel keeps a key/value head's dq in VMEM: 8 x T x 128
+    at 4 + 2 x 2 bytes an element is 64 MiB at 8k and 128 MiB at 16k against
+    the 32 MiB that ``supports_band_bwd_kernel`` allows, so the cell's
+    attention backward is the XLA loops (``seq/attn_bwd_fallback``)."""
+    need = 8 * T_ * 128 * (4 + 2 * 2)
+    assert need == (64 if T_ == 8192 else 128) * 2 ** 20
+    assert not pa.supports_band_bwd_kernel(T_, 128, 8, 2), (
+        f"dq of 8 heads x {T_} x 128 = {need / 2 ** 20:.0f} MiB "
+        f"> {pa._BWD_VMEM_LIMIT // 2 / 2 ** 20:.0f} MiB")
+    assert pa.supports_band_kernel(T_, 128, 128, pa.BAND_BLOCK)
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_sequence_kernel_compiles_for_v5e(case, v5e):
     build, kernels = CASES[case]
@@ -207,6 +243,8 @@ def test_sequence_kernel_compiles_for_v5e(case, v5e):
 
 
 def supports(case):
+    if "gqa8" in case:
+        return pa.supports_band_kernel(T16, 128, 128, pa.BAND_BLOCK)
     if "mla" in case:
         return (pa.supports_band_kernel(T, 192, 128, pa.BAND_BLOCK)
                 and pa.supports_band_bwd_kernel(T, 192, 1, 2))
