@@ -374,7 +374,6 @@ def flash_attention(q, k, v, causal: bool = False,
 BAND_BLOCK = 512
 _MASKED = -1e30     # finite: a row whose first block is all masked stays finite
 _BWD_VMEM_LIMIT = 64 * 1024 * 1024      # of the v5e's 128 MiB, as ops/ssm.py
-_SCOPED_VMEM = 16 * 1024 * 1024     # what Mosaic grants a kernel that asks none
 
 
 def _band_lo(i, bs: int, window: Optional[int]):
@@ -570,13 +569,11 @@ def _band_fwd_call(h, g, T, d, dv, bs, window, scale, dtype, interpret):
 
     f32 = jnp.float32
     n = T // bs
-    # double-buffered blocks and the scratch; the tiles of the body want as
-    # much again. A group too large for the default scoped VMEM (8 query
-    # heads of 128: 12 MiB here, 19 MiB with the tiles) asks for the
-    # backward's limit; every smaller one asks nothing, as it always did.
-    held = (2 * (g * bs * (d + dv) + bs * (d + dv)) * dtype.itemsize
-            + 2 * g * n * bs * 4 + g * bs * (256 + dv) * 4)
-    limit = _BWD_VMEM_LIMIT if held > _SCOPED_VMEM // 2 else None
+    # A group of 8 query heads of 128 (g·d 1024) outgrows the 16 MiB of
+    # VMEM Mosaic grants a kernel that asks none (its blocks, scratch and
+    # tiles: 19 MiB at T 16,384) and asks the backward's limit; the smaller
+    # groups of the other cells (g·d ≤ 256) ask nothing, as they always did.
+    limit = _BWD_VMEM_LIMIT if g * d >= 1024 else None
     # q and o stay [query head, T, ·], a key/value head's g heads one block
     rows_map = lambda h, p, pj, pi: (h, pi[p], 0)           # noqa: E731
     kv_map = lambda h, p, pj, pi: (h, pj[p], 0)             # noqa: E731
