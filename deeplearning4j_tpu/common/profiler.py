@@ -420,7 +420,10 @@ class OpProfiler:
         the Pallas kernel or the plain XLA path (``scan_kernel`` /
         ``scan_fallback``, ``attn_kernel`` / ``attn_fallback`` for the
         attention forward, ``attn_bwd_kernel`` / ``attn_bwd_fallback`` for
-        its backward, counted where the backward itself is traced) and the
+        its backward, counted where the backward itself is traced, and
+        ``attn_bwd_kv_resident`` for the backward kernel's call sites that
+        take its query-block-first walk, the key/value head's dk and dv
+        resident, where a group's dq outgrows VMEM) and the
         (query block, key block) pairs the attention band computes and
         leaves out of the square (``attn_key_blocks_run`` /
         ``attn_key_blocks_skipped``, per query head);

@@ -18,10 +18,12 @@ shape of the mask:
   causal, optionally banded by a window, grouped-query heads, a value wider
   than the head, operands in the inputs' dtype with float32 accumulation. Both
   kernels walk the band as a scalar-prefetched list of (query block, key
-  block) pairs — the forward query block first, the backward key block first
-  — with a key/value head's query heads inside one grid step: no grid step is
-  issued, and nothing fetched or computed, for a pair outside the band.
-  ``seq/attn_fwd_grid_steps`` counts the steps the forward's calls issue.
+  block) pairs — the forward query block first; the backward key block first
+  where a group's dq fits VMEM, else query block first with the key/value
+  head's dk and dv resident — with a key/value head's query heads inside one
+  grid step: no grid step is issued, and nothing fetched or computed, for a
+  pair outside the band. ``seq/attn_fwd_grid_steps`` counts the steps the
+  forward's calls issue.
 
 Nothing of size T×T ever materializes in either. ``interpret=True`` runs a
 kernel in Pallas interpret mode (the CPU tests); on the TPU the same kernel
@@ -362,14 +364,18 @@ def flash_attention(q, k, v, causal: bool = False,
 # ``flash_attention_fwd`` walks the pairs query block first (two products a
 # pair, the running statistics in scratch while the query block stays) and
 # also hands back each row's log-sum-exp, laid out ``[head, group, query
-# block, row]``; ``flash_attention_bwd`` walks them key block first, one pass
-# from that log-sum-exp (five products a pair, the score tile never leaving
-# VMEM). ``seq/attn_fwd_grid_steps`` counts the grid steps the forward's call
+# block, row]``; ``flash_attention_bwd`` makes one pass from that
+# log-sum-exp (five products a pair, the score tile never leaving VMEM) in
+# one of two walks, chosen by shape (``_bwd_walk``): key block first, the
+# group's dq resident (``supports_band_bwd_kernel``), or, where that dq
+# outgrows VMEM, query block first with the key/value head's dk and dv
+# resident (``supports_band_bwd_kv_resident``: a group of 8 heads of 128 at
+# 8k or 16k). ``seq/attn_fwd_grid_steps`` counts the grid steps the forward's call
 # issues: the band's pairs a key/value head, ``attn_key_blocks_run / g``
 # (``b·hq·n·`` the band's width on the clamped rectangle this grid replaced).
-# The XLA loops below are the path of every other case (the
-# CPU, a shape ``supports_band_kernel`` / ``supports_band_bwd_kernel``
-# refuses) and what the tests hold the kernels to.
+# The XLA loops below are the path of every other case (the CPU, a shape
+# ``supports_band_kernel`` refuses, a backward that neither walk fits) and
+# what the tests hold the kernels to.
 
 BAND_BLOCK = 512
 _MASKED = -1e30     # finite: a row whose first block is all masked stays finite
@@ -616,51 +622,90 @@ def _band_fwd_pallas(q, k, v, scale, window, bs, interpret):
     return o.reshape(b, hk, g, T, dv), lse.reshape(b, hk, g, T)
 
 
-def _band_bwd_kernel(pj_ref, pi_ref, qt_ref, k_ref, kt_ref, v_ref, do_ref,
-                     lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-                     dq_scr, dk_scr, dv_scr,
-                     *, scale: float, bs: int, window: Optional[int]):
-    # One grid step is one block pair of the band (``_band_pairs``: key block
-    # j outer, the query blocks i that see it inner) for the G query heads of
-    # one key/value head: dk_j and dv_j gather in scratch while j stays, the
-    # heads' dq stays in VMEM while the key/value head's pairs run. Tiles are
-    # [key, query], so the per-query log-sum-exp and delta broadcast as rows;
-    # dk and dq are gathered transposed ([D, block]) from q and k that come
-    # transposed too: with the narrow head width as the streamed side of
-    # their products the matrix unit takes half the passes, and nothing is
-    # transposed in here. Traced in the 32-bit world, like _fa_kernel.
+def _band_bwd_kernel(pj_ref, pi_ref, *refs, scale: float, bs: int,
+                     window: Optional[int], kv_resident: bool = False):
+    # One grid step is one block pair of the band for the G query heads of
+    # one key/value head, in one of two walks. Tiles are [key, query], so the
+    # per-query log-sum-exp and delta broadcast as rows. Key block first
+    # (``_band_pairs``: key block j outer, the query blocks i that see it
+    # inner): dk_j and dv_j gather in scratch while j stays, the heads' dq
+    # stays in VMEM while the key/value head's pairs run; dk and dq are
+    # gathered transposed ([D, block]) from q and k that come transposed
+    # too: with a narrow head as the streamed side of their products the
+    # matrix unit takes half the passes, and nothing is transposed in here.
+    # ``kv_resident``, query block first (the forward's list): the heads' dq
+    # of block i gathers in scratch while i stays and leaves as i changes, dk
+    # and dv of the whole key/value head stay in VMEM while its pairs run —
+    # state of T·(D + Dv), where the first walk's is G·T·D; q, k, dq and dk
+    # as they come (at the 128-wide heads this walk serves, XLA's turns of
+    # q and dq around the call cost more than the narrow side saves: on a
+    # v5e at 16k tokens the turned form took 6% longer). Traced in the
+    # 32-bit world, like _fa_kernel.
+    if kv_resident:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+         dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr) = refs
+    else:
+        (q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref, delta_ref,
+         dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr) = refs
     f32 = jnp.float32
     nt = (((1,), (1,)), ((), ()))
+    tn = (((0,), (0,)), ((), ()))
     p, last_p = pl.program_id(1), pl.num_programs(1) - 1
     j, i = pj_ref[p], pi_ref[p]
+    if kv_resident:
+        kv_at = j           # the key block's dk / dv tile in scratch
+        first_i = (p == 0) | (pi_ref[jnp.maximum(p - 1, 0)] != i)
+        last_i = (p == last_p) | (pi_ref[jnp.minimum(p + 1, last_p)] != i)
 
-    @pl.when(p == 0)
-    def _new_head():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
+        @pl.when(p == 0)
+        def _new_head():
+            dk_scr[...] = jnp.zeros_like(dk_scr)
+            dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when((p == 0) | (pj_ref[jnp.maximum(p - 1, 0)] != j))
-    def _new_key_block():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
+        @pl.when(first_i)
+        def _new_query_block():
+            dq_scr[...] = jnp.zeros_like(dq_scr)
+    else:
+        kv_at = ...         # the one tile of the key block that stays
+
+        @pl.when(p == 0)
+        def _new_head():
+            dq_scr[...] = jnp.zeros_like(dq_scr)
+
+        @pl.when((p == 0) | (pj_ref[jnp.maximum(p - 1, 0)] != j))
+        def _new_key_block():
+            dk_scr[...] = jnp.zeros_like(dk_scr)
+            dv_scr[...] = jnp.zeros_like(dv_scr)
 
     def pair(masked: bool):
-        k, kt, v = k_ref[0], kt_ref[0], v_ref[0]
+        k = k_ref[0]
+        kt = None if kv_resident else kt_ref[0]
+        v = v_ref[0]
         if masked:
             ok = _band_mask(i, j, bs, window, keys_first=True)
-        for g in range(qt_ref.shape[1]):
-            qt, do = qt_ref[0, g], do_ref[0, g]                 # [D, bq]
-            s = jnp.dot(k, qt, preferred_element_type=f32) * scale
+        for g in range(q_ref.shape[1]):
+            q, do = q_ref[0, g], do_ref[0, g]     # q [bq, D], or [D, bq]
+            if kv_resident:
+                s = lax.dot_general(k, q, nt, preferred_element_type=f32)
+            else:
+                s = jnp.dot(k, q, preferred_element_type=f32)
+            s = s * scale
             if masked:
                 s = jnp.where(ok, s, _MASKED)
             pr = jnp.exp(s - lse_ref[0, g, pl.ds(i, 1), :])
-            dv_scr[...] += jnp.dot(pr.astype(do.dtype), do,
-                                   preferred_element_type=f32)
+            dv_scr[kv_at] += jnp.dot(pr.astype(do.dtype), do,
+                                     preferred_element_type=f32)
             dp = lax.dot_general(v, do, nt, preferred_element_type=f32)
             ds = (pr * (dp - delta_ref[0, g, pl.ds(i, 1), :])
-                  * scale).astype(qt.dtype)
-            dk_scr[...] += lax.dot_general(qt, ds, nt,
-                                           preferred_element_type=f32)
-            dq_scr[g, i] += jnp.dot(kt, ds, preferred_element_type=f32)
+                  * scale).astype(q.dtype)
+            if kv_resident:
+                dk_scr[kv_at] += jnp.dot(ds, q, preferred_element_type=f32)
+                dq_scr[g] += lax.dot_general(ds, k, tn,
+                                             preferred_element_type=f32)
+            else:
+                dk_scr[...] += lax.dot_general(q, ds, nt,
+                                               preferred_element_type=f32)
+                dq_scr[g, i] += jnp.dot(kt, ds, preferred_element_type=f32)
 
     # the mask only empties part of a tile on the diagonal and at the
     # window's far edge
@@ -669,6 +714,17 @@ def _band_bwd_kernel(pj_ref, pi_ref, qt_ref, k_ref, kt_ref, v_ref, do_ref,
         edge = edge | ((i - j + 1) * bs - 1 >= window)
     pl.when(edge)(functools.partial(pair, True))
     pl.when(jnp.logical_not(edge))(functools.partial(pair, False))
+
+    if kv_resident:
+        @pl.when(last_i)
+        def _query_block_done():
+            dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+        @pl.when(p == last_p)
+        def _head_done():
+            dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+            dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+        return
 
     @pl.when((p == last_p) | (pj_ref[jnp.minimum(p + 1, last_p)] != j))
     def _key_block_done():
@@ -681,8 +737,9 @@ def _band_bwd_kernel(pj_ref, pi_ref, qt_ref, k_ref, kt_ref, v_ref, do_ref,
 
 
 @functools.lru_cache(maxsize=None)
-def _band_bwd_call(h, g, T, d, dv, bs, window, scale, dtypes, interpret):
-    """The backward's ``pallas_call`` for one shape. Kept: what
+def _band_bwd_call(h, g, T, d, dv, bs, window, scale, dtypes, interpret,
+                   kv_resident=False):
+    """The backward's ``pallas_call`` for one shape and walk. Kept: what
     ``pallas_call`` hands back is a ``jit``, so a model's layers of one
     shape trace the kernel's body once."""
     from jax.experimental.pallas import tpu as pltpu
@@ -694,54 +751,72 @@ def _band_bwd_call(h, g, T, d, dv, bs, window, scale, dtypes, interpret):
     kv_map = lambda h, p, pj, pi: (h, pj[p], 0)             # noqa: E731
     kt_map = lambda h, p, pj, pi: (h, 0, pj[p])             # noqa: E731
     head4 = lambda h, p, pj, pi: (h, 0, 0, 0)               # noqa: E731
+    if kv_resident:
+        # q, k, dq and dk as they come: dq a query block; dk and dv the
+        # whole key/value head, a [block, ·] tile a key block
+        in_specs = [pl.BlockSpec((1, g, bs, d), rows_map),
+                    pl.BlockSpec((1, bs, d), kv_map)]
+        out_specs = [pl.BlockSpec((1, g, bs, d), rows_map),
+                     pl.BlockSpec((1, n, bs, d), head4),
+                     pl.BlockSpec((1, n, bs, dv), head4)]
+        scratch = [(g, bs, d), (n, bs, d), (n, bs, dv)]
+        out_shapes = [(h, g, T, d), (h, n, bs, d), (h, n, bs, dv)]
+    else:
+        in_specs = [pl.BlockSpec((1, g, d, bs), qt_map),
+                    pl.BlockSpec((1, bs, d), kv_map),
+                    pl.BlockSpec((1, d, bs), kt_map)]
+        out_specs = [pl.BlockSpec((1, g, n, d, bs),
+                                  lambda h, p, pj, pi: (h, 0, 0, 0, 0)),
+                     pl.BlockSpec((1, d, bs), kt_map),
+                     pl.BlockSpec((1, bs, dv), kv_map)]
+        scratch = [(g, n, d, bs), (d, bs), (bs, dv)]
+        out_shapes = [(h, g, n, d, bs), (h, d, T), (h, T, dv)]
     return pl.pallas_call(
         functools.partial(_band_bwd_kernel, scale=scale, bs=bs,
-                          window=window),
+                          window=window, kv_resident=kv_resident),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(h, len(_band_pairs(n, bs, window)[0])),
-            in_specs=[pl.BlockSpec((1, g, d, bs), qt_map),
-                      pl.BlockSpec((1, bs, d), kv_map),
-                      pl.BlockSpec((1, d, bs), kt_map),
-                      pl.BlockSpec((1, bs, dv), kv_map),
-                      pl.BlockSpec((1, g, bs, dv), rows_map),
-                      pl.BlockSpec((1, g, n, bs), head4),
-                      pl.BlockSpec((1, g, n, bs), head4)],
-            out_specs=[pl.BlockSpec((1, g, n, d, bs),
-                                    lambda h, p, pj, pi: (h, 0, 0, 0, 0)),
-                       pl.BlockSpec((1, d, bs), kt_map),
-                       pl.BlockSpec((1, bs, dv), kv_map)],
-            scratch_shapes=[pltpu.VMEM((g, n, d, bs), f32),
-                            pltpu.VMEM((d, bs), f32),
-                            pltpu.VMEM((bs, dv), f32)]),
-        out_shape=[jax.ShapeDtypeStruct((h, g, n, d, bs), dtypes[0]),
-                   jax.ShapeDtypeStruct((h, d, T), dtypes[1]),
-                   jax.ShapeDtypeStruct((h, T, dv), dtypes[2])],
+            in_specs=in_specs + [pl.BlockSpec((1, bs, dv), kv_map),
+                                 pl.BlockSpec((1, g, bs, dv), rows_map),
+                                 pl.BlockSpec((1, g, n, bs), head4),
+                                 pl.BlockSpec((1, g, n, bs), head4)],
+            out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM(s, f32) for s in scratch]),
+        out_shape=[jax.ShapeDtypeStruct(s, t)
+                   for s, t in zip(out_shapes, dtypes)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_BWD_VMEM_LIMIT),
         interpret=interpret, name="flash_attention_bwd")
 
 
-def _band_bwd_pallas(q, k, v, o, lse, do, scale, window, bs, interpret):
+def _band_bwd_pallas(q, k, v, o, lse, do, scale, window, bs, interpret,
+                     kv_resident=False):
     """Same contract as ``_band_bwd_xla``, as one kernel: five products a
     block pair, a group's heads summed into dk and dv inside it, k and v
-    never repeated. q and k go in transposed as well, dq and dk leave it as
-    [.., D, block] tiles: turned here, by XLA."""
+    never repeated; the pairs key block first, or query block first where
+    ``kv_resident`` (``_band_bwd_kernel``). In the first walk q and k go in
+    transposed as well, and dq and dk leave it as [.., D, block] tiles:
+    turned here, by XLA; the second takes and gives them as they are."""
     f32 = jnp.float32
     b, hk, g, T, d = q.shape
     dv = v.shape[-1]
     h, n = b * hk, T // bs
-    pj, pi = _band_pairs(n, bs, window)
+    pj, pi = _band_pairs(n, bs, window, query_first=kv_resident)
     delta = jnp.sum(do.astype(f32) * o.astype(f32), axis=-1)   # [B,Hk,G,T]
     q3, k3 = q.reshape(h, g, T, d), k.reshape(h, T, d)
+    qk = ((q3, k3) if kv_resident
+          else (q3.swapaxes(-1, -2), k3, k3.swapaxes(-1, -2)))
     with jax.enable_x64(False):
         dq, dk, dv_ = _band_bwd_call(
             h, g, T, d, dv, bs, window, scale, (q.dtype, k.dtype, v.dtype),
-            interpret,
-        )(jnp.asarray(pj), jnp.asarray(pi), q3.swapaxes(-1, -2), k3,
-          k3.swapaxes(-1, -2), v.reshape(h, T, dv), do.reshape(h, g, T, dv),
-          lse.reshape(h, g, n, bs), delta.reshape(h, g, n, bs))
+            interpret, kv_resident,
+        )(jnp.asarray(pj), jnp.asarray(pi), *qk, v.reshape(h, T, dv),
+          do.reshape(h, g, T, dv), lse.reshape(h, g, n, bs),
+          delta.reshape(h, g, n, bs))
+    if kv_resident:
+        return dq.reshape(q.shape), dk.reshape(k.shape), dv_.reshape(v.shape)
     return (dq.swapaxes(-1, -2).reshape(q.shape),
             dk.swapaxes(-1, -2).reshape(k.shape), dv_.reshape(v.shape))
 
@@ -761,13 +836,16 @@ def _band_fwd(q, k, v, scale, window, bs, kernel, interpret):
 
 
 def _band_bwd(scale, window, bs, kernel, interpret, res, do):
-    q = res[0]
+    q, v = res[0], res[2]
     _, _, g, T, d = q.shape
-    kernel = kernel and supports_band_bwd_kernel(T, d, g, q.dtype.itemsize)
-    OpProfiler.get().count("seq/attn_bwd_kernel" if kernel
-                           else "seq/attn_bwd_fallback")
-    if kernel:
-        return _band_bwd_pallas(*res, do, scale, window, bs, interpret)
+    walk = kernel and _bwd_walk(T, d, v.shape[-1], g, q.dtype.itemsize)
+    prof = OpProfiler.get()
+    prof.count("seq/attn_bwd_kernel" if walk else "seq/attn_bwd_fallback")
+    if walk == "kv_resident":
+        prof.count("seq/attn_bwd_kv_resident")
+    if walk:
+        return _band_bwd_pallas(*res, do, scale, window, bs, interpret,
+                                walk == "kv_resident")
     return _band_bwd_xla(*res, do, scale, window, bs)
 
 
@@ -781,11 +859,33 @@ def supports_band_kernel(T: int, d: int, dv: int, bs: int) -> bool:
 
 
 def supports_band_bwd_kernel(T: int, d: int, g: int, itemsize: int) -> bool:
-    """Beside ``supports_band_kernel``: the backward kernel keeps the dq of a
-    key/value head's ``g`` query heads in VMEM (a float32 scratch and the
-    double-buffered output block) and leaves half of its limit to the tiles.
-    A longer sequence takes the forward kernel and the XLA backward."""
+    """Beside ``supports_band_kernel``: the backward kernel's key-block-first
+    walk keeps the dq of a key/value head's ``g`` query heads in VMEM (a
+    float32 scratch and the double-buffered output block) and leaves half of
+    its limit to the tiles. Where that does not fit,
+    ``supports_band_bwd_kv_resident`` says whether the query-block-first
+    walk does; where neither does, the backward is the XLA loops."""
     return g * T * d * (4 + 2 * itemsize) <= _BWD_VMEM_LIMIT // 2
+
+
+def supports_band_bwd_kv_resident(T: int, d: int, dv: int,
+                                  itemsize: int) -> bool:
+    """The backward kernel's query-block-first walk keeps dk and dv of the
+    whole key/value head in VMEM (float32 scratch and the double-buffered
+    output blocks), whatever the group: taken where a group's dq does not
+    fit (``supports_band_bwd_kernel``), as 8 heads of 128 at 8k or 16k."""
+    return T * (d + dv) * (4 + 2 * itemsize) <= _BWD_VMEM_LIMIT // 2
+
+
+def _bwd_walk(T: int, d: int, dv: int, g: int, itemsize: int):
+    """The backward kernel's walk for a shape the forward kernel takes:
+    ``"key_first"`` where a group's dq fits, else ``"kv_resident"`` where
+    the key/value head's dk and dv do, else None (the XLA loops)."""
+    if supports_band_bwd_kernel(T, d, g, itemsize):
+        return "key_first"
+    if supports_band_bwd_kv_resident(T, d, dv, itemsize):
+        return "kv_resident"
+    return None
 
 
 @op("causal_attention", "nn")
@@ -806,11 +906,14 @@ def causal_attention(q, k, v, window: Optional[int] = None,
     Where the forward is the Pallas kernel (a TPU, ``allow_pallas``,
     ``supports_band_kernel``; or ``interpret=True``) the backward is one too
     (``flash_attention_bwd``: the same band, the same casts of ``p`` and
-    ``ds`` before their products as the XLA loops), unless a group's dq
-    does not fit VMEM (``supports_band_bwd_kernel``); everywhere else both
-    are XLA loops. ``seq/attn_kernel`` / ``seq/attn_fallback`` count the
+    ``ds`` before their products as the XLA loops): key block first where a
+    group's dq fits VMEM (``supports_band_bwd_kernel``), else query block
+    first with the key/value head's dk and dv resident where those fit
+    (``supports_band_bwd_kv_resident``), else the XLA loops; everywhere else
+    both are XLA loops. ``seq/attn_kernel`` / ``seq/attn_fallback`` count the
     forward's call sites, ``seq/attn_bwd_kernel`` / ``seq/attn_bwd_fallback``
-    the backward's, as a step is traced; ``seq/attn_key_blocks_run`` /
+    the backward's (``seq/attn_bwd_kv_resident`` those of the kernel's
+    second walk), as a step is traced; ``seq/attn_key_blocks_run`` /
     ``seq/attn_key_blocks_skipped`` the band's block pairs a query head and
     what the square has beside them; ``seq/attn_fwd_grid_steps`` the grid
     steps the forward kernel's call issues — a pair for each key/value head,

@@ -268,22 +268,37 @@ def _residuals(t, block, window, dtype, g=2, d=64, dv=128):
     return q, k, v, o.astype(dtype), lse, do.astype(dtype)
 
 
-@pytest.mark.parametrize("t,block,window,dtype,g,tol", [
+_BWD_CASES = [
     (384, 128, None, "float32", 2, 1e-5), (384, 128, 128, "float32", 2, 1e-5),
     (512, 128, 200, "float32", 1, 1e-5),
     # bfloat16 operands: both sides round p and ds to bfloat16 before their
     # products and sum in float32, in another order; the results are
     # bfloat16, so they agree to an ulp of that (2^-8) of the largest
     (384, 128, None, "bfloat16", 2, 2.0 ** -7),
-    (384, 128, 128, "bfloat16", 2, 2.0 ** -7)])
+    (384, 128, 128, "bfloat16", 2, 2.0 ** -7)]
+# the query-block-first walk: 1, 2 and 8 query heads a key/value head; the
+# whole causal band, a window within one block and one the block does not
+# divide
+_KV_RESIDENT_CASES = [
+    (384, 128, window, dtype, g, 1e-5 if dtype == "float32" else 2.0 ** -7)
+    for dtype in ("float32", "bfloat16") for g in (1, 2, 8)
+    for window in (None, 100, 200)]
+
+
+@pytest.mark.parametrize(
+    "t,block,window,dtype,g,tol,kv_resident",
+    [pytest.param(*c, False, id="-".join(map(str, c))) for c in _BWD_CASES]
+    + [pytest.param(*c, True, id="kv_resident-" + "-".join(map(str, c)))
+       for c in _KV_RESIDENT_CASES])
 def test_band_bwd_kernel_equals_the_loops_on_the_same_residuals(
-        t, block, window, dtype, g, tol):
-    """dq, dk and dv of the Pallas backward (interpret mode) against
-    ``_band_bwd_xla``'s, each by name, on the same residuals and cotangent:
-    not through ``jax.grad``, so a fault in one of the three is named."""
+        t, block, window, dtype, g, tol, kv_resident):
+    """dq, dk and dv of the Pallas backward (interpret mode), in either walk,
+    against ``_band_bwd_xla``'s, each by name, on the same residuals and
+    cotangent: not through ``jax.grad``, so a fault in one of the three is
+    named."""
     res = _residuals(t, block, window, jnp.dtype(dtype), g=g)
     want = pa._band_bwd_xla(*res, 0.125, window, block)
-    got = pa._band_bwd_pallas(*res, 0.125, window, block, True)
+    got = pa._band_bwd_pallas(*res, 0.125, window, block, True, kv_resident)
     for name, a, b in zip(("dq", "dk", "dv"), got, want):
         assert a.dtype == b.dtype and a.shape == b.shape, name
         gap = float(jnp.max(jnp.abs(a.astype(F32) - b.astype(F32)))
@@ -315,6 +330,32 @@ def test_causal_attention_band_bfloat16(window):
     _tree_close(loops, plain, 0.02)
 
 
+def test_causal_attention_kv_resident_walk_bfloat16(monkeypatch):
+    """A group of 8 query heads, bfloat16, through ``jax.grad`` of
+    ``causal_attention`` on the backward kernel's query-block-first walk:
+    against plain float32 attention on the same values within bfloat16's 2%.
+    At this size a group's dq fits, so the test refuses the key-block-first
+    walk's predicate to take the other."""
+    monkeypatch.setattr(pa, "supports_band_bwd_kernel", lambda *a: False)
+    q, k, v = (a.astype(jnp.bfloat16)
+               for a in _qkv(384, hq=8, hk=1, d=64, dv=128))
+    w = jax.random.normal(jax.random.PRNGKey(7), (B, 8, 384, 128), F32)
+    prof = OpProfiler.get()
+    was = prof.counter_value("seq/attn_bwd_kv_resident")
+
+    def grads(f, *a):
+        return jax.grad(lambda *a: jnp.sum(f(*a).astype(F32) * w),
+                        (0, 1, 2))(*a)
+
+    kernel = grads(lambda *a: causal_attention(*a, window=200, block=128,
+                                               interpret=True), q, k, v)
+    assert prof.counter_value("seq/attn_bwd_kv_resident") == was + 1
+    plain = grads(lambda *a: _plain_gqa(*a, window=200),
+                  *(a.astype(F32) for a in (q, k, v)))
+    assert all(a.dtype == jnp.bfloat16 for a in kernel)
+    _tree_close(kernel, plain, 0.02)
+
+
 @pytest.mark.parametrize("query_first", [False, True])
 @pytest.mark.parametrize("n,bs,window", [
     (16, 512, None), (16, 512, 512), (12, 128, 130), (12, 128, 200),
@@ -342,7 +383,8 @@ def test_attention_backward_is_counted_once_a_traced_call_site():
     """``seq/attn_bwd_kernel`` where the backward is the Pallas kernel,
     ``seq/attn_bwd_fallback`` where it is the XLA loops (the CPU without
     ``interpret``; a shape the forward kernel refuses); a forward alone
-    counts neither; ``sequence_stats()`` returns both."""
+    counts neither; ``seq/attn_bwd_kv_resident`` beside the first where the
+    kernel walks query block first; ``sequence_stats()`` returns them."""
     prof = OpProfiler.get()
     read = lambda: (prof.counter_value("seq/attn_bwd_kernel"),   # noqa: E731
                     prof.counter_value("seq/attn_bwd_fallback"))
@@ -360,6 +402,18 @@ def test_attention_backward_is_counted_once_a_traced_call_site():
     assert read() == (k0 + 1, f0 + 2)
     stats = prof.sequence_stats()
     assert stats["attn_bwd_kernel"] >= 1 and stats["attn_bwd_fallback"] >= 2
+    # the kernel's second walk, counted beside ``attn_bwd_kernel``: a group
+    # of 8 heads of 128 at 8k (its dq outgrows VMEM) and, not, a group of 2
+    # heads of 64 — traced only, through ``eval_shape``
+    kvr = lambda: prof.counter_value("seq/attn_bwd_kv_resident")  # noqa: E731
+    for hq, d, moved in ((8, 128, 1), (2, 64, 0)):
+        spec = [jax.ShapeDtypeStruct((1, h, 8192, w), jnp.bfloat16)
+                for h, w in ((hq, d), (1, d), (1, 128))]
+        was, k1 = kvr(), read()[0]
+        jax.eval_shape(jax.grad(lambda *a: causal_attention(
+            *a, interpret=True).astype(F32).sum(), (0, 1, 2)), *spec)
+        assert (kvr() - was, read()[0] - k1) == (moved, 1), (hq, d)
+    assert prof.sequence_stats()["attn_bwd_kv_resident"] >= 1
 
 
 def test_backward_kernel_has_its_own_shape_predicate():

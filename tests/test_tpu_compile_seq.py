@@ -10,9 +10,10 @@ attention with 32 query heads over 8 key/value heads; and at the shapes of ``joy
 whose keys are 192 wide and whose values are 128 wide (latent attention as it
 is trained), and the grouped products over 131,072 dispatch rows and 16
 experts of 2048 x 1536 and 768 x 2048; and at the shapes of
-``trinity_mini.train_s16k``: the attention forward with 8 query heads of 128
-a key/value head at 16,384 tokens, a window of 2,048 and none (the backward
-there is the XLA loops). As ``tests/test_tpu_compile.py``:
+``trinity_mini.train_s16k``: the attention forward and backward with 8 query
+heads of 128 a key/value head at 16,384 tokens, a window of 2,048 and none
+(the backward there walks query block first, the key/value head's dk and dv
+resident in VMEM). As ``tests/test_tpu_compile.py``:
 the compiler is installed with jax and compiles for a chip that is DESCRIBED,
 not attached; a compile that passes is not a chip run.
 """
@@ -162,11 +163,12 @@ def _mla(grad):
     return (both if grad else fwd), shapes
 
 
-def _gqa8(window):
+def _gqa8(window, grad=False):
     # trinity_mini: one 16,384-token sequence, 32 query heads of 128 in
     # groups of 8 over 4 key/value heads; a window layer and a full one. The
     # group's blocks and scratch exceed the default scoped VMEM, so the
-    # forward asks for more; the backward is the XLA loops
+    # forward asks for more; the backward walks query block first, the
+    # key/value head's dk and dv resident (a group's dq does not fit)
     bf16 = jnp.bfloat16
     shapes = [((1, 4, 8, T16, 128), bf16), ((1, 4, T16, 128), bf16),
               ((1, 4, T16, 128), bf16)]
@@ -175,12 +177,20 @@ def _gqa8(window):
         return pa._band(q, k, v, 128 ** -0.5, window, pa.BAND_BLOCK, True,
                         False)
 
-    return fwd, shapes
+    def both(*a):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(*a)
+
+    return (both if grad else fwd), shapes
 
 
 CASES = {
     "attention_gqa8_d128_t16384_full_fwd": (lambda: _gqa8(None), 1),
     "attention_gqa8_d128_t16384_window_fwd": (lambda: _gqa8(2048), 1),
+    "attention_gqa8_d128_t16384_full_fwd_bwd": (
+        lambda: _gqa8(None, True), 2),
+    "attention_gqa8_d128_t16384_window_fwd_bwd": (
+        lambda: _gqa8(2048, True), 2),
     "attention_mla_d192_dv128_fwd": (lambda: _mla(False), 1),
     "attention_mla_d192_dv128_fwd_bwd": (lambda: _mla(True), 2),
     "moe_gmm_e16_up_fwd_bwd": (
@@ -218,17 +228,36 @@ CASES = {
 
 
 @pytest.mark.parametrize("T_", [8192, T16])
-def test_a_group_of_8_heads_of_128_takes_the_xla_backward(T_):
-    """The backward kernel keeps a key/value head's dq in VMEM: 8 x T x 128
-    at 4 + 2 x 2 bytes an element is 64 MiB at 8k and 128 MiB at 16k against
-    the 32 MiB that ``supports_band_bwd_kernel`` allows, so the cell's
-    attention backward is the XLA loops (``seq/attn_bwd_fallback``)."""
+def test_a_group_of_8_heads_of_128_takes_the_kv_resident_walk(T_):
+    """The key-block-first walk keeps a key/value head's dq in VMEM: 8 x T x
+    128 at 4 + 2 x 2 bytes an element is 64 MiB at 8k and 128 MiB at 16k
+    against the 32 MiB that ``supports_band_bwd_kernel`` allows. The
+    query-block-first walk keeps the head's dk and dv instead, T x (128 +
+    128) x 8 bytes, 16 and 32 MiB whatever the group: the cell's attention
+    backward is that walk (``seq/attn_bwd_kv_resident``)."""
     need = 8 * T_ * 128 * (4 + 2 * 2)
     assert need == (64 if T_ == 8192 else 128) * 2 ** 20
     assert not pa.supports_band_bwd_kernel(T_, 128, 8, 2), (
         f"dq of 8 heads x {T_} x 128 = {need / 2 ** 20:.0f} MiB "
         f"> {pa._BWD_VMEM_LIMIT // 2 / 2 ** 20:.0f} MiB")
+    assert T_ * 256 * 8 == (16 if T_ == 8192 else 32) * 2 ** 20
+    assert pa.supports_band_bwd_kv_resident(T_, 128, 128, 2)
+    assert pa._bwd_walk(T_, 128, 128, 8, 2) == "kv_resident"
     assert pa.supports_band_kernel(T_, 128, 128, pa.BAND_BLOCK)
+
+
+@pytest.mark.parametrize("T_,d,dv,g,walk", [
+    (T, 64, 128, 2, "key_first"),       # phi4_mini_flash.train_s8k
+    (T, 64, 64, 4, "key_first"),        # lfm2_moe.train_b2_s8k
+    (T, 192, 128, 1, "key_first"),      # joyai_llm_flash.train_b2_s8k
+    (65536, 64, 128, 2, None)])         # neither walk fits: the XLA loops
+def test_the_other_decoder_cells_keep_the_key_first_walk(T_, d, dv, g, walk):
+    """The walk is chosen by shape alone: the other decoder cells' groups fit
+    the key-block-first walk and keep it (their kernels lower as before), and
+    at 65,536 tokens of 64-wide heads neither the group's dq (64 MiB) nor the
+    head's dk and dv (96 MiB) fit, so the backward stays the XLA loops."""
+    assert pa._bwd_walk(T_, d, dv, g, 2) == walk
+    assert pa.supports_band_kernel(T_, d, dv, pa.BAND_BLOCK)
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -244,7 +273,9 @@ def test_sequence_kernel_compiles_for_v5e(case, v5e):
 
 def supports(case):
     if "gqa8" in case:
-        return pa.supports_band_kernel(T16, 128, 128, pa.BAND_BLOCK)
+        return (pa.supports_band_kernel(T16, 128, 128, pa.BAND_BLOCK)
+                and (not case.endswith("bwd")
+                     or pa._bwd_walk(T16, 128, 128, 8, 2) == "kv_resident"))
     if "mla" in case:
         return (pa.supports_band_kernel(T, 192, 128, pa.BAND_BLOCK)
                 and pa.supports_band_bwd_kernel(T, 192, 1, 2))
