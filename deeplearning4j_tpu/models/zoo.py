@@ -1481,3 +1481,123 @@ class TrinityMini(ZooModel):
         gc.compute_dtype = self.compute_dtype
         gc.remat_policy = self.remat_policy
         return ComputationGraph(conf).init()
+
+
+class GraniteHybrid(ZooModel):
+    """Granite-4.0-H (``model_type`` ``granitemoehybrid``; IBM's
+    granite-4.0-h-micro, huggingface.co/ibm-granite/granite-4.0-h-micro,
+    ``config.json``; the family's modelling code is ``models/granitemoehybrid``
+    of Hugging Face ``transformers``): a decoder of pre-norm blocks ``x +
+    residual_multiplier * Mixer(RMSNorm(x))``, ``x + residual_multiplier *
+    MLP(RMSNorm(x))`` whose mixer ``layer_types`` makes a Mamba-2 layer
+    (``Mamba2Layer``: ``mamba_n_heads`` heads of ``mamba_d_head``, state
+    ``mamba_d_state``, ``mamba_n_groups`` groups of B and C, a causal
+    convolution of ``mamba_d_conv`` taps with bias, a gated norm; the scan is
+    ``ops.ssm.ssd_scan`` in chunks of ``mamba_chunk_size``) or grouped-query
+    attention without position, bias or per-head norm whose softmax scale is
+    ``attention_multiplier`` (``RotaryAttentionLayer(rope=False,
+    qk_norm=False)``). The MLP is the family's shared MLP with no experts
+    (``GatedMLPLayer``, ``shared_intermediate_size`` wide). The embedding is
+    scaled by ``embedding_multiplier`` after the lookup and the final norm's
+    output by ``1 / logits_scaling`` before the tied head (``ScaleVertex``:
+    ``logits = (x E^T) / logits_scaling``); ``residual_multiplier`` rides in
+    the residual adds (``ElementWiseVertex(branch_scale=...)``), so it costs
+    no vertex of its own.
+
+    ``layers``: the published layer indices to build, in order (all when
+    None). ``vocab_rows``: rows of the tied embedding/head held here.
+    Defaults are the published sizes. Trained through
+    ``ComputationGraph.fit`` on ``[B, T]`` integer ids with ``[B, T]``
+    integer next-token labels."""
+
+    def __init__(self, layers: Optional[Sequence[int]] = None,
+                 vocab_rows: int = 100352, hidden_size: int = 2048,
+                 shared_intermediate_size: int = 8192,
+                 num_attention_heads: int = 32, num_key_value_heads: int = 8,
+                 attention_multiplier: float = 0.015625,
+                 embedding_multiplier: float = 12.0,
+                 residual_multiplier: float = 0.22,
+                 logits_scaling: float = 8.0, mamba_n_heads: int = 64,
+                 mamba_d_head: int = 64, mamba_d_state: int = 128,
+                 mamba_n_groups: int = 1, mamba_d_conv: int = 4,
+                 mamba_chunk_size: int = 256, num_hidden_layers: int = 40,
+                 layer_types: Optional[Sequence[str]] = None,
+                 rms_norm_eps: float = 1e-5,
+                 seq_len: Optional[int] = None,
+                 compute_dtype: Optional[str] = "bfloat16",
+                 state_dtype: Optional[str] = "bfloat16",
+                 remat_policy="full", learning_rate: float = 1e-4,
+                 weight_decay: float = 0.1, seed: int = 123):
+        self.layers = list(range(num_hidden_layers) if layers is None
+                           else layers)
+        # the published pattern: attention at layer 5 of every ten
+        self.layer_types = list(layer_types or [
+            "attention" if l % 10 == 5 else "mamba"
+            for l in range(num_hidden_layers)])
+        self.vocab_rows = vocab_rows
+        self.d, self.ff = hidden_size, shared_intermediate_size
+        self.heads, self.kv_heads = num_attention_heads, num_key_value_heads
+        self.attention_multiplier = attention_multiplier
+        self.embedding_multiplier = embedding_multiplier
+        self.residual_multiplier = residual_multiplier
+        self.logits_scaling = logits_scaling
+        self.mamba = dict(d_inner=mamba_n_heads * mamba_d_head,
+                          n_heads=mamba_n_heads, d_state=mamba_d_state,
+                          n_groups=mamba_n_groups, d_conv=mamba_d_conv,
+                          chunk=mamba_chunk_size, eps=rms_norm_eps)
+        self.eps = rms_norm_eps
+        self.seq_len = seq_len
+        self.compute_dtype, self.state_dtype = compute_dtype, state_dtype
+        self.remat_policy = remat_policy
+        self.learning_rate, self.weight_decay = learning_rate, weight_decay
+        self.seed = seed
+
+    def is_attention(self, l: int) -> bool:
+        return self.layer_types[l] == "attention"
+
+    def init(self) -> ComputationGraph:
+        updater = AdamW(learning_rate=self.learning_rate, beta1=0.9,
+                        beta2=0.95, epsilon=1e-8,
+                        weight_decay=self.weight_decay)
+        updater.state_dtype = self.state_dtype
+        gb = (ComputationGraphConfiguration
+              .graph_builder(NeuralNetConfiguration.builder()
+                             .seed(self.seed).updater(updater))
+              .add_inputs("ids"))
+        gb.add_layer("embed", L.EmbeddingSequenceLayer(
+            n_out=self.d, weight_init="normal"), "ids")
+        gb.add_vertex("embed_scale", ScaleVertex(
+            scale=self.embedding_multiplier), "embed")
+        norm = lambda: L.RMSNormLayer(eps=self.eps)         # noqa: E731
+        add = lambda: ElementWiseVertex(                    # noqa: E731
+            op="add", branch_scale=self.residual_multiplier)
+        prev = "embed_scale"
+        for l in self.layers:
+            if self.is_attention(l):
+                mixer, mixer_layer = f"l{l}_attn", L.RotaryAttentionLayer(
+                    n_heads=self.heads, n_kv_heads=self.kv_heads,
+                    head_dim=self.d // self.heads, eps=self.eps, rope=False,
+                    qk_norm=False, sm_scale=self.attention_multiplier)
+            else:
+                mixer, mixer_layer = f"l{l}_mamba", L.Mamba2Layer(
+                    **self.mamba)
+            gb.add_layer(f"l{l}_ln1", norm(), prev)
+            gb.add_layer(mixer, mixer_layer, f"l{l}_ln1")
+            gb.add_vertex(f"l{l}_add1", add(), prev, mixer)
+            gb.add_layer(f"l{l}_ln2", norm(), f"l{l}_add1")
+            gb.add_layer(f"l{l}_mlp", L.GatedMLPLayer(n_ff=self.ff),
+                         f"l{l}_ln2")
+            gb.add_vertex(f"l{l}_add2", add(), f"l{l}_add1", f"l{l}_mlp")
+            prev = f"l{l}_add2"
+        gb.add_layer("final_ln", norm(), prev)
+        gb.add_vertex("head_scale", ScaleVertex(
+            scale=1.0 / self.logits_scaling), "final_ln")
+        gb.add_layer("head", L.TiedOutputLayer(tied_to="embed"), "head_scale")
+        conf = (gb.set_outputs("head")
+                .set_input_types(InputType.recurrent(self.vocab_rows,
+                                                     self.seq_len))
+                .build())
+        gc = conf.global_conf
+        gc.compute_dtype = self.compute_dtype
+        gc.remat_policy = self.remat_policy
+        return ComputationGraph(conf).init()

@@ -20,6 +20,9 @@ wider than values),
 ``MTPMergeLayer`` (the entry of a multi-token-prediction module) and
 ``LMHeadLayer`` (the head that owns its matrix; ``TiedOutputLayer`` is the
 same head reading another node's): ``models.JoyAILLMFlash`` (the DeepSeek-V3
+family). ``Mamba2Layer`` (the Mamba-2 mixer over ``ops.ssm.ssd_scan``) and
+``RotaryAttentionLayer`` without rotation or per-head norm and with the
+family's softmax scale: ``models.GraniteHybrid`` (the ``granitemoehybrid``
 family). All take and give ``[B, T, F]``. They are plain layer
 configurations: a ``ComputationGraph`` wires them with a norm layer and
 ``ElementWiseVertex(add)`` into pre-norm residual blocks.
@@ -50,7 +53,7 @@ from ...common.profiler import OpProfiler
 from ...ops.moe import (GMM_ROW_TILE, grouped_gated_mlp, rotary_embedding,
                         route_topk)
 from ...ops.pallas_attention import causal_attention
-from ...ops.ssm import selective_scan
+from ...ops.ssm import SSD_CHUNK, selective_scan, ssd_scan
 from ..losses import LossSparseMCXENT
 from .inputs import RNNInput
 from .layers import Layer, LossLayer
@@ -142,13 +145,8 @@ class MambaLayer(Layer):
         n, r = self.d_state, self.dt_rank
         with jax.named_scope("mamba"):
             u, z = jnp.split(x @ params["W_in"], 2, axis=-1)
-            # causal depthwise convolution: tap k reads d_conv-1-k steps back
-            T = u.shape[1]
-            padded = jnp.pad(u, ((0, 0), (self.d_conv - 1, 0), (0, 0)))
-            u = params["conv_b"] + sum(
-                padded[:, k:k + T] * params["conv_w"][k]
-                for k in range(self.d_conv))
-            u = jax.nn.silu(u)
+            u = jax.nn.silu(params["conv_b"]
+                            + _causal_conv(u, params["conv_w"]))
             proj = u @ params["W_x"]
             delta, Bm, Cm = (proj[..., :r], proj[..., r:r + n],
                              proj[..., r + n:])
@@ -159,6 +157,76 @@ class MambaLayer(Layer):
             y = y + (_f32(params["D"]) * _f32(u)).astype(y.dtype)
             out = (y * jax.nn.silu(z)) @ params["W_out"]
         return ((out, y) if self.emit_memory else out), state
+
+
+@dataclass
+class Mamba2Layer(Layer):
+    """Mamba-2 mixer (Dao & Gu, arXiv:2405.21060; the ``granitemoehybrid``
+    family's ``mamba`` layer). ``[z | xBC | dt] = x W_in`` (``d_inner``,
+    ``d_inner + 2 n_groups d_state``, ``n_heads`` wide; no bias); ``xBC <-
+    silu(causal depthwise conv of d_conv taps + conv_b)``; ``xBC = [X | B |
+    C]`` with X ``n_heads`` heads of ``d_inner / n_heads`` and B, C
+    ``n_groups`` groups of ``d_state`` (head ``h`` reads group ``h //
+    (n_heads / n_groups)``); ``dt = softplus(dt + dt_bias)`` and ``A =
+    -exp(A_log)``, one each a head; ``Y = ssd_scan(X, dt, A, B, C) + D X``;
+    ``out = (RMSNorm(Y * silu(z)) * norm) W_out``, the gated norm over all
+    ``d_inner`` channels in float32. Scopes: ``mamba2``, and inside it
+    ``ssd`` (the scan) and ``gated_norm``; ``seq/mamba2_layers`` counts the
+    layers as a step is traced."""
+
+    d_inner: int = 0
+    n_heads: int = 0
+    d_state: int = 128
+    n_groups: int = 1
+    d_conv: int = 4
+    chunk: int = SSD_CHUNK
+    eps: float = 1e-5
+
+    full_precision_params = ("A_log", "dt_bias", "D", "norm")
+
+    def set_input_type(self, input_type):
+        self.n_in = input_type.size
+        return input_type
+
+    def init_params(self, key, dtype=jnp.float32):
+        ks = jax.random.split(key, 3)
+        di, h = self.d_inner, self.n_heads
+        conv = di + 2 * self.n_groups * self.d_state
+        return {
+            "W_in": _normal(ks[0], (self.n_in, di + conv + h), dtype),
+            "conv_w": _normal(ks[1], (self.d_conv, conv), dtype),
+            "conv_b": jnp.zeros((conv,), dtype),
+            "dt_bias": jnp.ones((h,), dtype),
+            "A_log": jnp.log(jnp.arange(1, h + 1, dtype=jnp.float32)).astype(
+                dtype),
+            "D": jnp.ones((h,), dtype),
+            "norm": jnp.ones((di,), dtype),
+            "W_out": _normal(ks[2], (di, self.n_in), dtype),
+        }
+
+    def apply(self, params, x, state, training, rng):
+        di, h, gn = self.d_inner, self.n_heads, self.n_groups * self.d_state
+        OpProfiler.get().count("seq/mamba2_layers")
+        with jax.named_scope("mamba2"):
+            b, T, _ = x.shape
+            proj = x @ params["W_in"]
+            z, xbc, dt = (proj[..., :di], proj[..., di:2 * di + 2 * gn],
+                          proj[..., 2 * di + 2 * gn:])
+            xbc = jax.nn.silu(params["conv_b"]
+                              + _causal_conv(xbc, params["conv_w"]))
+            xs = xbc[..., :di].reshape(b, T, h, di // h)
+            groups = lambda a: a.reshape(b, T, self.n_groups,  # noqa: E731
+                                         self.d_state)
+            dt = jax.nn.softplus(_f32(dt) + _f32(params["dt_bias"]))
+            with jax.named_scope("ssd"):
+                y = ssd_scan(xs, dt, -jnp.exp(_f32(params["A_log"])),
+                             groups(xbc[..., di:di + gn]),
+                             groups(xbc[..., di + gn:]), chunk=self.chunk)
+            y = _f32(y) + _f32(params["D"])[:, None] * _f32(xs)
+            with jax.named_scope("gated_norm"):
+                g = y.reshape(b, T, di) * jax.nn.silu(_f32(z))
+                g = _rms(g, params["norm"], self.eps).astype(x.dtype)
+            return g @ params["W_out"], state
 
 
 @dataclass
@@ -274,6 +342,15 @@ class GatedMemoryUnit(Layer):
             return (m * jax.nn.silu(x @ params["W1"])) @ params["W2"], state
 
 
+def _causal_conv(x, w):
+    """Causal depthwise convolution over the time axis of ``x`` ``[B, T, F]``
+    with taps ``w`` ``[k, F]``: tap ``j`` reads ``k - 1 - j`` steps back, ``x``
+    zero before the sequence."""
+    T, k = x.shape[1], w.shape[0]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(padded[:, j:j + T] * w[j] for j in range(k))
+
+
 def _rms(x, gain, eps):
     """``x * rsqrt(mean(x^2) + eps) * gain`` over the last axis in float32,
     back in ``x``'s dtype."""
@@ -324,11 +401,7 @@ class ShortConvLayer(Layer):
     def apply(self, params, x, state, training, rng):
         with jax.named_scope("short_conv"):
             b, c, u = jnp.split(x @ params["W_in"], 3, axis=-1)
-            v = b * u
-            T = v.shape[1]
-            padded = jnp.pad(v, ((0, 0), (self.taps - 1, 0), (0, 0)))
-            conv = sum(padded[:, j:j + T] * params["conv_w"][j]
-                       for j in range(self.taps))
+            conv = _causal_conv(b * u, params["conv_w"])
             return (c * conv) @ params["W_out"], state
 
 
@@ -347,8 +420,11 @@ class RotaryAttentionLayer(Layer):
     the full layers of the ``afmoe`` family). ``output_gate``: the heads'
     output is gated before ``Wo``, ``o * sigmoid(x W_gate)`` with ``W_gate``
     ``[d, n_heads * head_dim]`` from the same ``x`` (scope ``attn_gate``).
-    As a step is traced, ``seq/attn_nope_layers`` and
-    ``seq/attn_gated_layers`` count the layers that skip the rotation and
+    ``qk_norm`` False: no per-head norm and no ``q_norm`` / ``k_norm``
+    leaves. ``sm_scale``: the softmax's scale in place of ``1 /
+    sqrt(head_dim)`` (the ``granitemoehybrid`` family's
+    ``attention_multiplier``). As a step is traced, ``seq/attn_nope_layers``
+    and ``seq/attn_gated_layers`` count the layers that skip the rotation and
     that gate."""
 
     n_heads: int = 0
@@ -359,6 +435,8 @@ class RotaryAttentionLayer(Layer):
     window: Optional[int] = None
     rope: bool = True
     output_gate: bool = False
+    qk_norm: bool = True
+    sm_scale: Optional[float] = None
 
     full_precision_params = ("q_norm", "k_norm")
 
@@ -373,9 +451,10 @@ class RotaryAttentionLayer(Layer):
         params = {"Wq": _normal(ks[0], (d, nq), dtype),
                   "Wk": _normal(ks[1], (d, nkv), dtype),
                   "Wv": _normal(ks[2], (d, nkv), dtype),
-                  "Wo": _normal(ks[3], (nq, d), dtype),
-                  "q_norm": jnp.ones((hd,), dtype),
-                  "k_norm": jnp.ones((hd,), dtype)}
+                  "Wo": _normal(ks[3], (nq, d), dtype)}
+        if self.qk_norm:
+            params.update(q_norm=jnp.ones((hd,), dtype),
+                          k_norm=jnp.ones((hd,), dtype))
         if self.output_gate:
             params["W_gate"] = _normal(jax.random.fold_in(key, 4), (d, nq),
                                        dtype)
@@ -392,7 +471,7 @@ class RotaryAttentionLayer(Layer):
 
             def heads(w, n, gain=None):     # -> [B, n, T, hd]
                 a = (x @ params[w]).reshape(b, T, n, hd)
-                if gain is not None:
+                if gain is not None and self.qk_norm:
                     a = _rms(a, params[gain], self.eps)
                 a = a.transpose(0, 2, 1, 3)
                 return (a if gain is None or not self.rope
@@ -401,7 +480,7 @@ class RotaryAttentionLayer(Layer):
             o = causal_attention(heads("Wq", self.n_heads, "q_norm"),
                                  heads("Wk", self.n_kv_heads, "k_norm"),
                                  heads("Wv", self.n_kv_heads),
-                                 window=self.window)
+                                 window=self.window, sm_scale=self.sm_scale)
             o = o.transpose(0, 2, 1, 3).reshape(b, T, self.n_heads * hd)
             if self.output_gate:
                 prof.count("seq/attn_gated_layers")

@@ -66,13 +66,19 @@ class MergeVertex(GraphVertex):
 
 @dataclass
 class ElementWiseVertex(GraphVertex):
-    """reference ElementWiseVertex.Op: Add/Subtract/Product/Average/Max."""
+    """reference ElementWiseVertex.Op: Add/Subtract/Product/Average/Max.
+    ``branch_scale`` (add only): ``inputs[0] + branch_scale * (the rest)``,
+    a residual add whose branch is scaled (the ``granitemoehybrid`` family's
+    ``residual_multiplier``) without a vertex of its own."""
 
     op: str = "add"
+    branch_scale: Optional[float] = None
 
     def apply(self, *inputs):
         op = self.op.lower()
         if op == "add":
+            if self.branch_scale is not None:
+                return inputs[0] + self.branch_scale * sum(inputs[1:])
             out = inputs[0]
             for v in inputs[1:]:
                 out = out + v
@@ -460,6 +466,7 @@ class ComputationGraph(FitLoop):
             if (add_node is None or add_node.kind != "vertex"
                     or not isinstance(add_node.vertex, ElementWiseVertex)
                     or add_node.vertex.op.lower() != "add"
+                    or add_node.vertex.branch_scale is not None
                     or len(add_node.inputs) != 2
                     or add_name in outputs
                     or consumers.get(add_name) != {name}):

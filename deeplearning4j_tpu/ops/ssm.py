@@ -1,18 +1,39 @@
-"""Selective state-space scan (Mamba-1, Gu & Dao arXiv:2312.00752 Alg. 2).
+"""State-space scans: Mamba-1's selective scan and Mamba-2's state-space dual.
+
+``selective_scan`` (Mamba-1, Gu & Dao arXiv:2312.00752 Alg. 2; called by
+``nn.conf.layers_seq.MambaLayer``, ``models.Phi4MiniFlash``):
 
     h_t = exp(dt_t * A) * h_{t-1} + (dt_t * u_t) (x) B_t,   h_0 = 0
     y_t = h_t . C_t
 
-``u``, ``dt``: ``[B, T, D]`` (``dt`` after its softplus); ``A``: ``[D, N]``
-(negative); ``Bm``, ``Cm``: ``[B, T, N]``; ``y``: ``[B, T, D]``. The skip
-``D * u`` and the gate stay with the layer. State, ``dt`` and ``A`` are
-float32 whatever the inputs are; ``y`` comes back in ``u``'s dtype.
+with a decay for every channel and state index. ``ssd_scan`` (Mamba-2, Dao &
+Gu arXiv:2405.21060; called by ``nn.conf.layers_seq.Mamba2Layer``,
+``models.GraniteHybrid``) is the same recurrence with ONE decay a head,
+heads of ``P`` channels and a state ``[P, N]`` a head, and ``B``, ``C`` shared
+by the heads of a group:
 
-Time runs in chunks of ``chunk`` steps with the state carried between
-them. The forward keeps only the state at each chunk's start
-(``[T/chunk, B, D, N]`` float32); the backward walks the chunks in
-reverse, recomputes one chunk's states from its start and differentiates
-that chunk alone, so nothing of size ``T x D x N`` is ever stored.
+    H_t^h = exp(dt_t^h A^h) H_{t-1}^h + dt_t^h X_t^h (x) B_t^g,   H_0 = 0
+    Y_t^h = H_t^h C_t^g                                 (g = h // (H/G))
+
+A scalar decay a head is what makes a chunk of the recurrence a masked
+matrix product (the "dual" form, section 6 of the paper): with ``s_t`` the
+cumulative log-decay inside a chunk, ``Y_t = sum_{j<=t} (C_t . B_j)
+exp(s_t - s_j) dt_j X_j + exp(s_t) C_t H_in``, and the chunk's end state is
+``exp(s_L) H_in + sum_j exp(s_L - s_j) dt_j X_j (x) B_j``. That is matrix
+unit work, where the selective scan is the vector unit's, a step at a time.
+
+Both scans run time in chunks of ``chunk`` steps with the state carried
+between them. The forward keeps only the state at each chunk's start; the
+backward walks the chunks in reverse, recomputes one chunk from its start
+and differentiates that chunk alone, carrying the state's cotangent, so
+nothing of size ``T x state`` is ever stored. A ``T`` the chunk does not
+divide is padded with steps of ``dt = 0``, which leave the state as it is.
+
+The selective scan: ``u``, ``dt``: ``[B, T, D]`` (``dt`` after its
+softplus); ``A``: ``[D, N]`` (negative); ``Bm``, ``Cm``: ``[B, T, N]``;
+``y``: ``[B, T, D]``. The skip ``D * u`` and the gate stay with the layer.
+State, ``dt`` and ``A`` are float32 whatever the inputs are; ``y`` comes
+back in ``u``'s dtype. Chunk starts are ``[T/chunk, B, D, N]`` float32.
 
 Two implementations behind one ``custom_vjp``:
 
@@ -31,6 +52,31 @@ Two implementations behind one ``custom_vjp``:
 
 Which one ran is counted when the step is traced (``seq/scan_kernel``,
 ``seq/scan_fallback``).
+
+The state-space dual: ``x``: ``[B, T, H, P]``; ``dt``: ``[B, T, H]`` (after
+its softplus); ``A``: ``[H]`` (negative); ``B``, ``C``: ``[B, T, G, N]``;
+``y``: ``[B, T, H, P]`` in ``x``'s dtype, without the ``D`` skip (the layer's,
+with the gate). Decays, their cumulative sums, ``exp`` and the carried state
+are float32; the products take ``x``'s dtype as operands (bfloat16 in a
+bfloat16 layer) and accumulate in float32. Chunk starts are ``[B, T/chunk, H,
+N, P]`` float32 (a head's state is kept as ``H^T``). Two implementations
+behind one ``custom_vjp``:
+
+- a Pallas TPU kernel each way, both under the one name ``ssd_scan``; grid
+  (batch row, group, chunk), the chunk axis sequential, every head of the
+  group in one grid step with its ``[N, P]`` float32 state in VMEM scratch.
+  ``C B^T`` is formed once a chunk for the group; a head's decay mask, its
+  intra-chunk product, the off-diagonal term and the state update follow.
+  The cumulative log-decays are XLA's (``s``, handed in beside ``dt``), and
+  the backward hands back ``ds``, whose map to ``dA`` and ``ddt`` (a reverse
+  cumulative sum in the chunk) is XLA's too. ``dB`` and ``dC`` leave the
+  kernel summed over the group's heads;
+- plain XLA (``lax.scan`` over chunks of the dual form, the backward by
+  ``jax.vjp`` of one chunk): the path off the TPU, for shapes that
+  ``supports_ssd_kernel`` refuses, and the tests' oracle for the kernel.
+
+``seq/ssd_kernel`` / ``seq/ssd_fallback`` count the call sites as a step is
+traced.
 """
 
 from __future__ import annotations
@@ -71,15 +117,15 @@ def _chunk_xla(h, u, dt, A, Bm, Cm):
 
 
 def _chunks(a, L):
-    """[B, T, F] -> [T/L, L, B, F]."""
-    b, t, f = a.shape
-    return jnp.moveaxis(a, 1, 0).reshape(t // L, L, b, f)
+    """[B, T, ...] -> [T/L, L, B, ...]."""
+    b, t = a.shape[:2]
+    return jnp.moveaxis(a, 1, 0).reshape(t // L, L, b, *a.shape[2:])
 
 
 def _unchunk(a):
-    """[T/L, L, B, F] -> [B, T, F]."""
-    c, L, b, f = a.shape
-    return jnp.moveaxis(a.reshape(c * L, b, f), 0, 1)
+    """[T/L, L, B, ...] -> [B, T, ...]."""
+    c, L, b = a.shape[:3]
+    return jnp.moveaxis(a.reshape(c * L, b, *a.shape[3:]), 0, 1)
 
 
 def _fwd_xla(u, dt, A, Bm, Cm, L):
@@ -351,3 +397,391 @@ def selective_scan(u, dt, A, Bm, Cm, chunk: Optional[int] = None,
             args[i] = jnp.pad(args[i], ((0, 0), (0, pad), (0, 0)))
     y = _scan(*args, L, kernel, bool(interpret))
     return y[:, :t].astype(u.dtype)
+
+
+# --- the state-space dual (Mamba-2) ------------------------------------------
+
+SSD_CHUNK = 256         # steps a chunk: Mamba-2's ``chunk_size``
+_SSD_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def supports_ssd_kernel(H: int, G: int, P: int, N: int, L: int,
+                        itemsize: int) -> bool:
+    """The kernels' shapes: a group's heads in whole sublane tiles, a head
+    and a state as wide as the lanes the products want, a chunk of whole
+    lane tiles; the backward's blocks (x, dy and dx double-buffered, the
+    chunk starts double-buffered, the carried cotangent) and its ``[L, L]``
+    temporaries, as counted here, within half the VMEM it asks for. The
+    count reads low — Mosaic allocates 37.4 MB where it counts 24.1 (64 heads
+    of 64 in bfloat16) and 66.2 where it counts 36.7 (in float32, refused) —
+    so the cut at half the limit is what keeps a shape it takes inside the
+    limit (``tests/test_tpu_compile_seq.py`` compiles the widest)."""
+    if H % G:
+        return False
+    hg = H // G
+    blocks = (2 * 3 * hg * L * P * itemsize + 3 * hg * N * P * 4
+              + 16 * L * L * 4 + 8 * L * N * 4)
+    return (hg % 8 == 0 and P % 64 == 0 and N % 128 == 0 and L % 128 == 0
+            and blocks <= _SSD_VMEM_LIMIT // 2)
+
+
+def _ssd_chunk_xla(h, x, dt, A, B, C):
+    """One chunk of the dual form, time-major. h ``[b, H, N, P]`` (a head's
+    state as ``H^T``); x ``[L, b, H, P]``; dt ``[L, b, H]``; B, C ``[L, b,
+    G, N]`` -> (h after the chunk, y ``[L, b, H, P]``)."""
+    L, H, G = x.shape[0], x.shape[2], B.shape[2]
+    Bh = jnp.repeat(B, H // G, axis=2)
+    Ch = jnp.repeat(C, H // G, axis=2)
+    s = jnp.cumsum(dt * A, axis=0)                          # [L, b, H]
+    causal = jnp.tril(jnp.ones((L, L), bool))[:, :, None, None]
+    decay = jnp.exp(jnp.where(causal, s[:, None] - s[None], -jnp.inf))
+    scores = jnp.einsum("tbhn,jbhn->tjbh", Ch, Bh) * decay * dt[None]
+    y = (jnp.einsum("tjbh,jbhp->tbhp", scores, x)
+         + jnp.exp(s)[..., None] * jnp.einsum("tbhn,bhnp->tbhp", Ch, h))
+    w = jnp.exp(s[-1:] - s) * dt                            # [L, b, H]
+    h = (jnp.exp(s[-1])[..., None, None] * h
+         + jnp.einsum("jbhn,jbhp->bhnp", Bh * w[..., None], x))
+    return h, y
+
+
+def _ssd_fwd_xla(x, dt, A, B, C, L):
+    b, _, H, P = x.shape
+    wide = jnp.promote_types(x.dtype, jnp.float32)
+    h0 = jnp.zeros((b, H, B.shape[-1], P), wide)
+
+    def chunk(h, xs):
+        xc, dtc, bc, cc = xs
+        h_new, y = _ssd_chunk_xla(h, xc.astype(wide), dtc, A,
+                                  bc.astype(wide), cc.astype(wide))
+        return h_new, (y.astype(x.dtype), h)
+
+    _, (y, starts) = lax.scan(chunk, h0, (
+        _chunks(x, L), _chunks(dt, L), _chunks(B, L), _chunks(C, L)))
+    return _unchunk(y), jnp.moveaxis(starts, 0, 1).astype(jnp.float32)
+
+
+def _ssd_bwd_xla(x, dt, A, B, C, starts, dy, L):
+    wide = jnp.promote_types(x.dtype, jnp.float32)
+
+    def chunk(carry, xs):
+        gh, dA = carry
+        h, xc, dtc, bc, cc, dyc = xs
+        _, vjp = jax.vjp(_ssd_chunk_xla, h.astype(wide), xc.astype(wide),
+                         dtc, A, bc.astype(wide), cc.astype(wide))
+        gh, dx, ddt, dA_c, dB, dC = vjp((gh, dyc.astype(wide)))
+        return (gh, dA + dA_c), (dx.astype(x.dtype), ddt, dB.astype(B.dtype),
+                                 dC.astype(C.dtype))
+
+    (_, dA), (dx, ddt, dB, dC) = lax.scan(
+        chunk, (jnp.zeros(starts.shape[:1] + starts.shape[2:], wide),
+                jnp.zeros_like(A)),
+        (jnp.moveaxis(starts, 1, 0), _chunks(x, L), _chunks(dt, L),
+         _chunks(B, L), _chunks(C, L), _chunks(dy, L)), reverse=True)
+    return (_unchunk(dx), _unchunk(ddt), dA, _unchunk(dB), _unchunk(dC))
+
+
+# The kernels trace in the 32-bit world as the selective scan's. Per grid
+# step (batch row, group, chunk) the heads of the group are walked in blocks
+# of ``sub`` (a sublane tile of their ``dt`` and ``s`` rows), a head at a
+# time. ``s`` is the chunk's cumulative log-decay as a row ``[1, L]``; its
+# column ``[L, 1]`` is taken on the diagonal (a select and a lane sum), so
+# nothing is transposed in the kernel.
+
+_NT = (((1,), (1,)), ((), ()))     # a @ b^T
+_TN = (((0,), (0,)), ((), ()))     # a^T @ b
+
+
+def _ssd_masks(L):
+    rows = lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    return cols <= rows, cols == rows
+
+
+def _ssd_head(s_row, eye, causal):
+    """(s as a column, the chunk's decay mask exp(s_t - s_j) [j <= t], its
+    last log-decay ``[1, 1]``) of one head."""
+    L = s_row.shape[-1]
+    s_col = jnp.sum(jnp.where(eye, s_row, 0.0), axis=1, keepdims=True)
+    decay = jnp.exp(jnp.where(causal, s_col - s_row, -jnp.inf))
+    lane = lax.broadcasted_iota(jnp.int32, (1, L), 1)
+    s_last = jnp.sum(jnp.where(lane == L - 1, s_row, 0.0), axis=1,
+                     keepdims=True)
+    return s_col, decay, s_last
+
+
+def _ssd_fwd_kernel(x_ref, dt_ref, s_ref, bt_ref, c_ref, y_ref, start_ref,
+                    h_scr, *, sub: int):
+    f32 = jnp.float32
+    hg, L = dt_ref.shape[1], dt_ref.shape[2]
+    op = x_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        h_scr[...] = jnp.zeros_like(h_scr)
+
+    start_ref[0, 0] = h_scr[...]
+    bt, c = bt_ref[0, 0], c_ref[0, 0]                       # [N, L], [L, N]
+    cb = jnp.dot(c, bt, preferred_element_type=f32)         # [L, L]
+    causal, eye = _ssd_masks(L)
+
+    def block(q, carry):
+        base = pl.multiple_of(q * sub, sub)
+        s_blk = s_ref[0, pl.ds(base, sub), :]
+        dt_blk = dt_ref[0, pl.ds(base, sub), :]
+        for i in range(sub):
+            h = base + i
+            s_row, dt_row = s_blk[i:i + 1], dt_blk[i:i + 1]
+            s_col, decay, s_last = _ssd_head(s_row, eye, causal)
+            xh, h0 = x_ref[0, h], h_scr[h]                  # [L, P], [N, P]
+            y = (jnp.dot((cb * decay * dt_row).astype(op), xh,
+                         preferred_element_type=f32)
+                 + jnp.exp(s_col) * jnp.dot(c, h0.astype(op),
+                                            preferred_element_type=f32))
+            y_ref[0, h] = y.astype(y_ref.dtype)
+            w = jnp.exp(s_last - s_row) * dt_row             # [1, L]
+            h_scr[h] = jnp.exp(s_last) * h0 + jnp.dot(
+                (bt * w).astype(op), xh, preferred_element_type=f32)
+        return carry
+
+    lax.fori_loop(0, hg // sub, block, 0)
+
+
+def _ssd_bwd_kernel(x_ref, dt_ref, s_ref, bt_ref, c_ref, start_ref, dy_ref,
+                    dx_ref, ddt_ref, ds_ref, dbt_ref, dc_ref,
+                    dh_scr, dcb_scr, dc_scr, dbt_scr, *, sub: int):
+    """Chunks arrive last first; ``dh_scr`` carries each head's cotangent of
+    the state the chunk hands on. ``dcb_scr``, ``dc_scr``, ``dbt_scr`` sum
+    the group's heads' parts of ``d(C B^T)``, ``dC`` and ``dB^T``."""
+    f32 = jnp.float32
+    hg, L = dt_ref.shape[1], dt_ref.shape[2]
+    op = x_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dh_scr[...] = jnp.zeros_like(dh_scr)
+
+    dcb_scr[...] = jnp.zeros_like(dcb_scr)
+    dc_scr[...] = jnp.zeros_like(dc_scr)
+    dbt_scr[...] = jnp.zeros_like(dbt_scr)
+    bt, c = bt_ref[0, 0], c_ref[0, 0]
+    cb = jnp.dot(c, bt, preferred_element_type=f32)
+    causal, eye = _ssd_masks(L)
+    lane = lax.broadcasted_iota(jnp.int32, (1, L), 1)
+    row_of = lax.broadcasted_iota(jnp.int32, (sub, L), 0)
+
+    def block(q, carry):
+        base = pl.multiple_of(q * sub, sub)
+        s_blk = s_ref[0, pl.ds(base, sub), :]
+        dt_blk = dt_ref[0, pl.ds(base, sub), :]
+        ds_rows = jnp.zeros((sub, L), f32)
+        ddt_rows = jnp.zeros((sub, L), f32)
+        for i in range(sub):
+            h = base + i
+            s_row, dt_row = s_blk[i:i + 1], dt_blk[i:i + 1]
+            s_col, decay, s_last = _ssd_head(s_row, eye, causal)
+            e_last = jnp.exp(s_last)
+            cbm = cb * decay
+            scores = cbm * dt_row
+            xh, dyh = x_ref[0, h], dy_ref[0, h]            # [L, P]
+            h0, dh1 = start_ref[0, 0, h], dh_scr[h]       # [N, P]
+            w = jnp.exp(s_last - s_row) * dt_row           # [1, L]
+            # the intra-chunk product and its scores
+            dsc = lax.dot_general(dyh, xh, _NT, preferred_element_type=f32)
+            btw = (bt * w).astype(op)
+            dx = (lax.dot_general(scores.astype(op), dyh, _TN,
+                                  preferred_element_type=f32)
+                  + lax.dot_general(btw, dh1.astype(op), _TN,
+                                    preferred_element_type=f32))
+            dx_ref[0, h] = dx.astype(dx_ref.dtype)
+            both = dsc * scores
+            ds_col = jnp.sum(both, axis=1, keepdims=True)
+            ds_row = -jnp.sum(both, axis=0, keepdims=True)
+            ddt_row = jnp.sum(dsc * cbm, axis=0, keepdims=True)
+            dcb_scr[...] += dsc * decay * dt_row
+            # the state coming in: exp(s_t) C_t H_in
+            e_col = jnp.exp(s_col)
+            z = jnp.dot(c, h0.astype(op), preferred_element_type=f32)
+            ds_col = ds_col + e_col * jnp.sum(dyh.astype(f32) * z, axis=1,
+                                              keepdims=True)
+            ey = (e_col * dyh).astype(op)
+            dc_scr[...] += lax.dot_general(ey, h0.astype(op), _NT,
+                                           preferred_element_type=f32)
+            dh_scr[h] = e_last * dh1 + lax.dot_general(
+                c, ey, _TN, preferred_element_type=f32)
+            # the state going out: exp(s_L) H_in + (B^T w) X
+            v = lax.dot_general(dh1.astype(op), xh, _NT,
+                                preferred_element_type=f32)  # [N, L]
+            dbt_scr[...] += w * v
+            dw = jnp.sum(bt.astype(f32) * v, axis=0, keepdims=True)
+            ddt_row = ddt_row + dw * jnp.exp(s_last - s_row)
+            ds_row = ds_row - dw * w
+            ds_last = (jnp.sum(dw * w, axis=1, keepdims=True)
+                       + e_last * jnp.sum(jnp.sum(dh1 * h0, axis=1,
+                                                  keepdims=True),
+                                          axis=0, keepdims=True))
+            ds_row = (ds_row
+                      + jnp.sum(jnp.where(eye, ds_col, 0.0), axis=0,
+                                keepdims=True)
+                      + jnp.where(lane == L - 1, ds_last, 0.0))
+            ds_rows = jnp.where(row_of == i, ds_row, ds_rows)
+            ddt_rows = jnp.where(row_of == i, ddt_row, ddt_rows)
+        ds_ref[0, pl.ds(base, sub), :] = ds_rows
+        ddt_ref[0, pl.ds(base, sub), :] = ddt_rows
+        return carry
+
+    lax.fori_loop(0, hg // sub, block, 0)
+    dcb = dcb_scr[...].astype(op)
+    dc_ref[0, 0] = dc_scr[...] + lax.dot_general(
+        dcb, bt, _NT, preferred_element_type=f32)
+    dbt_ref[0, 0] = dbt_scr[...] + lax.dot_general(
+        c, dcb, _TN, preferred_element_type=f32)
+
+
+def _ssd_layouts(x, dt, A, B, C, L):
+    """The kernels' operands: x ``[b, H, T, P]``; dt and the cumulative
+    log-decay within each chunk ``[b, H, T]`` float32; ``B^T`` ``[b, G, N,
+    T]`` and C ``[b, G, T, N]`` in x's dtype."""
+    b, T, H, _ = x.shape
+    a = (dt * A).reshape(b, T // L, L, H)
+    s = jnp.cumsum(a, axis=2).reshape(b, T, H)
+    return (x.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1),
+            s.transpose(0, 2, 1), B.astype(x.dtype).transpose(0, 2, 3, 1),
+            C.astype(x.dtype).transpose(0, 2, 1, 3))
+
+
+def _ssd_sub(hg):
+    return 8 if hg % 8 == 0 else hg
+
+
+def _ssd_compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_SSD_VMEM_LIMIT)
+
+
+def _ssd_fwd_pallas(x, dt, A, B, C, L, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, T, H, P = x.shape
+    G, N = B.shape[2:]
+    hg, nc = H // G, T // L
+    f32 = jnp.float32
+    heads = pl.BlockSpec((1, hg, L, P), lambda i, g, c: (i, g, c, 0))
+    rows = pl.BlockSpec((1, hg, L), lambda i, g, c: (i, g, c))
+    with jax.enable_x64(False):
+        y, starts = pl.pallas_call(
+            functools.partial(_ssd_fwd_kernel, sub=_ssd_sub(hg)),
+            grid=(b, G, nc),
+            in_specs=[heads, rows, rows,
+                      pl.BlockSpec((1, 1, N, L), lambda i, g, c: (i, g, 0, c)),
+                      pl.BlockSpec((1, 1, L, N), lambda i, g, c: (i, g, c, 0))],
+            out_specs=[heads, pl.BlockSpec((1, 1, hg, N, P),
+                                           lambda i, g, c: (i, c, g, 0, 0))],
+            out_shape=[jax.ShapeDtypeStruct((b, H, T, P), x.dtype),
+                       jax.ShapeDtypeStruct((b, nc, H, N, P), f32)],
+            scratch_shapes=[pltpu.VMEM((hg, N, P), f32)],
+            compiler_params=None if interpret else _ssd_compiler_params(),
+            interpret=interpret, name="ssd_scan",
+        )(*_ssd_layouts(x, dt, A, B, C, L))
+    return y.transpose(0, 2, 1, 3), starts
+
+
+def _ssd_bwd_pallas(x, dt, A, B, C, starts, dy, L, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, T, H, P = x.shape
+    G, N = B.shape[2:]
+    hg, nc = H // G, T // L
+    f32 = jnp.float32
+    # grid step c works on chunk nc-1-c
+    heads = pl.BlockSpec((1, hg, L, P), lambda i, g, c: (i, g, nc - 1 - c, 0))
+    rows = pl.BlockSpec((1, hg, L), lambda i, g, c: (i, g, nc - 1 - c))
+    bt_spec = pl.BlockSpec((1, 1, N, L), lambda i, g, c: (i, g, 0, nc - 1 - c))
+    c_spec = pl.BlockSpec((1, 1, L, N), lambda i, g, c: (i, g, nc - 1 - c, 0))
+    with jax.enable_x64(False):
+        dx, ddt, ds, dbt, dc = pl.pallas_call(
+            functools.partial(_ssd_bwd_kernel, sub=_ssd_sub(hg)),
+            grid=(b, G, nc),
+            in_specs=[heads, rows, rows, bt_spec, c_spec,
+                      pl.BlockSpec((1, 1, hg, N, P),
+                                   lambda i, g, c: (i, nc - 1 - c, g, 0, 0)),
+                      heads],
+            out_specs=[heads, rows, rows, bt_spec, c_spec],
+            out_shape=[jax.ShapeDtypeStruct((b, H, T, P), x.dtype),
+                       jax.ShapeDtypeStruct((b, H, T), f32),
+                       jax.ShapeDtypeStruct((b, H, T), f32),
+                       jax.ShapeDtypeStruct((b, G, N, T), f32),
+                       jax.ShapeDtypeStruct((b, G, T, N), f32)],
+            scratch_shapes=[pltpu.VMEM((hg, N, P), f32),
+                            pltpu.VMEM((L, L), f32),
+                            pltpu.VMEM((L, N), f32),
+                            pltpu.VMEM((N, L), f32)],
+            compiler_params=None if interpret else _ssd_compiler_params(),
+            interpret=interpret, name="ssd_scan",
+        )(*_ssd_layouts(x, dt, A, B, C, L), starts,
+          dy.astype(x.dtype).transpose(0, 2, 1, 3))
+    # s_t = sum_{i<=t} dt_i A in the chunk: ds flows back to every earlier
+    # step of the chunk, and from there to dt and A
+    da = ds.transpose(0, 2, 1).reshape(b, nc, L, H)
+    da = jnp.flip(jnp.cumsum(jnp.flip(da, 2), axis=2), 2).reshape(b, T, H)
+    return (dx.transpose(0, 2, 1, 3), ddt.transpose(0, 2, 1) + da * A,
+            jnp.sum(da * dt, axis=(0, 1)),
+            dbt.transpose(0, 3, 1, 2).astype(B.dtype),
+            dc.transpose(0, 2, 1, 3).astype(C.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _ssd(x, dt, A, B, C, L, kernel, interpret):
+    return _ssd_fwd(x, dt, A, B, C, L, kernel, interpret)[0]
+
+
+def _ssd_fwd(x, dt, A, B, C, L, kernel, interpret):
+    if kernel:
+        y, starts = _ssd_fwd_pallas(x, dt, A, B, C, L, interpret)
+    else:
+        y, starts = _ssd_fwd_xla(x, dt, A, B, C, L)
+    return y, (x, dt, A, B, C, starts)
+
+
+def _ssd_bwd(L, kernel, interpret, res, dy):
+    if kernel:
+        return _ssd_bwd_pallas(*res, dy, L, interpret)
+    return _ssd_bwd_xla(*res, dy, L)
+
+
+_ssd.defvjp(_ssd_fwd, _ssd_bwd)
+
+
+@op("ssd_scan", "nn")
+def ssd_scan(x, dt, A, B, C, chunk: Optional[int] = None,
+             interpret: Optional[bool] = None):
+    """The state-space dual of the module's docstring. ``chunk``: steps a
+    chunk (``SSD_CHUNK``); a ``T`` it does not divide is padded with steps of
+    ``dt = 0``. ``interpret`` as ``selective_scan``'s: True runs the Pallas
+    kernels in interpret mode whatever the backend, False never, None takes
+    them on a TPU where ``supports_ssd_kernel`` allows and the XLA path
+    otherwise. ``dt`` and ``A`` are taken in float32 (float64 stays: gradient
+    checks), ``B`` and ``C`` in ``x``'s dtype on the kernel path."""
+    from ..common.environment import Environment
+
+    b, t, H, P = x.shape
+    G, N = B.shape[2:]
+    L = int(chunk or SSD_CHUNK)
+    wide = jnp.promote_types(x.dtype, jnp.float32)
+    fits = (supports_ssd_kernel(H, G, P, N, L, x.dtype.itemsize)
+            and wide == jnp.float32)
+    if interpret is None:
+        kernel = (Environment.get().allow_pallas()
+                  and jax.default_backend() == "tpu" and fits)
+    else:
+        kernel = bool(interpret) and H % G == 0 and wide == jnp.float32
+    OpProfiler.get().count("seq/ssd_kernel" if kernel else "seq/ssd_fallback")
+    dt, A = dt.astype(wide), A.astype(wide)
+    pad = -t % L
+    if pad:
+        widen = lambda a: jnp.pad(                          # noqa: E731
+            a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        x, dt, B, C = widen(x), widen(dt), widen(B), widen(C)
+    return _ssd(x, dt, A, B, C, L, kernel, bool(interpret))[:, :t]

@@ -1324,6 +1324,24 @@ class TestPallasOps:
         np.testing.assert_allclose(np.asarray(got)[0], np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
 
+    def test_ssd_scan_matches_single_steps(self):
+        """Mamba-2's scan: one decay a head, heads of 4 channels, one B/C
+        group for both heads; the cases are in tests/test_ssd_scan.py."""
+        rng = np.random.RandomState(9)
+        x = rng.randn(1, 20, 2, 4).astype(np.float32)
+        dt = np.log1p(np.exp(rng.randn(1, 20, 2).astype(np.float32) - 1))
+        A = -np.exp(rng.randn(2).astype(np.float32))
+        Bm = rng.randn(1, 20, 1, 8).astype(np.float32)
+        Cm = rng.randn(1, 20, 1, 8).astype(np.float32)
+        h, ref = np.zeros((2, 4, 8), np.float64), []
+        for t in range(20):
+            h = np.exp(dt[0, t] * A)[:, None, None] * h \
+                + (dt[0, t, :, None] * x[0, t])[:, :, None] * Bm[0, t, 0]
+            ref.append(h @ Cm[0, t, 0])
+        got = exec_op("ssd_scan", x, dt, A, Bm, Cm, chunk=8)
+        np.testing.assert_allclose(np.asarray(got)[0], np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+
 
 class TestRoutedExpertOps:
     """``ops/moe.py``; the layers' cases are in tests/test_moe_layers.py."""

@@ -13,7 +13,12 @@ experts of 2048 x 1536 and 768 x 2048; and at the shapes of
 ``trinity_mini.train_s16k``: the attention forward and backward with 8 query
 heads of 128 a key/value head at 16,384 tokens, a window of 2,048 and none
 (the backward there walks query block first, the key/value head's dk and dv
-resident in VMEM). As ``tests/test_tpu_compile.py``:
+resident in VMEM); and at the shapes of ``granite_4_h_micro.train_s16k``:
+the state-space dual scan forward and backward (``ops/ssm.ssd_scan``: 16,384
+steps, 64 heads of 64 on one B/C group, a state of 128, chunks of 256; the
+widest groups ``supports_ssd_kernel`` takes, and the float32 group it
+refuses failing for want of VMEM) and the attention with 32 query heads of 64 over 8 key/value heads at the
+family's softmax scale. As ``tests/test_tpu_compile.py``:
 the compiler is installed with jax and compiles for a chip that is DESCRIBED,
 not attached; a compile that passes is not a chip run.
 """
@@ -71,6 +76,43 @@ def _scan(grad):
 
     return (both if grad else fwd), [wide, wide, ((D_INNER, D_STATE), f32),
                                      narrow, narrow]
+
+
+def _ssd(grad, heads=64, dtype=jnp.bfloat16, t=T16):
+    # granite_4_h_micro: x [1, T, 64, 64] and B, C [1, T, 1, 128] in
+    # bfloat16, dt float32 after its softplus
+    f32 = jnp.float32
+    shapes = [((1, t, heads, 64), dtype), ((1, t, heads), f32),
+              ((heads,), f32), ((1, t, 1, 128), dtype),
+              ((1, t, 1, 128), dtype)]
+
+    def fwd(*a):
+        return ssm._ssd(*a, ssm.SSD_CHUNK, True, False)
+
+    def both(*a):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2, 3, 4))(*a)
+
+    return (both if grad else fwd), shapes
+
+
+def _gqa4_nope(grad):
+    # granite_4_h_micro's attention layer: one 16,384-token sequence, 32
+    # query heads of 64 in groups of 4 over 8 key/value heads, the softmax
+    # scaled by the family's attention_multiplier; a group's dq (4 x 16,384
+    # x 64 at 4 + 2 x 2 bytes) is 32 MiB, half the limit: key block first
+    bf16 = jnp.bfloat16
+    shapes = [((1, 8, 4, T16, 64), bf16), ((1, 8, T16, 64), bf16),
+              ((1, 8, T16, 64), bf16)]
+
+    def fwd(q, k, v):
+        return pa._band(q, k, v, 0.015625, None, pa.BAND_BLOCK, True, False)
+
+    def both(*a):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(*a)
+
+    return (both if grad else fwd), shapes
 
 
 def _attention(window, grad, plain=None, t=T):
@@ -185,6 +227,13 @@ def _gqa8(window, grad=False):
 
 
 CASES = {
+    "ssd_scan_fwd": (lambda: _ssd(False), 1),
+    "ssd_scan_fwd_bwd": (lambda: _ssd(True), 2),
+    # the widest groups supports_ssd_kernel takes at these widths
+    "ssd_scan_fwd_bwd_96_heads_bf16": (lambda: _ssd(True, 96, t=4096), 2),
+    "ssd_scan_fwd_bwd_56_heads_f32": (
+        lambda: _ssd(True, 56, jnp.float32, 4096), 2),
+    "attention_gqa4_d64_t16384_nope_fwd_bwd": (lambda: _gqa4_nope(True), 2),
     "attention_gqa8_d128_t16384_full_fwd": (lambda: _gqa8(None), 1),
     "attention_gqa8_d128_t16384_window_fwd": (lambda: _gqa8(2048), 1),
     "attention_gqa8_d128_t16384_full_fwd_bwd": (
@@ -271,7 +320,27 @@ def test_sequence_kernel_compiles_for_v5e(case, v5e):
     assert supports(case)
 
 
+def test_the_ssd_shape_it_refuses_does_not_fit_vmem(v5e):
+    """64 heads of 64 in float32: ``supports_ssd_kernel`` refuses them, and
+    the backward kernel would need 66.2 MB of scoped VMEM against the 64 it
+    asks for (its estimate reads low; its cut at half the limit keeps every
+    shape it takes inside, as the two widest cases above compile)."""
+    assert not ssm.supports_ssd_kernel(64, 1, 64, 128, ssm.SSD_CHUNK, 4)
+    fn, shapes = _ssd(True, 64, jnp.float32, 4096)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
+    with pytest.raises(Exception, match="vmem"):
+        jax.jit(fn).lower(*args).compile()
+
+
 def supports(case):
+    if case.startswith("ssd_scan"):
+        heads, itemsize = ((56, 4) if case.endswith("f32") else
+                           (96, 2) if case.endswith("bf16") else (64, 2))
+        return ssm.supports_ssd_kernel(heads, 1, 64, 128, ssm.SSD_CHUNK,
+                                       itemsize)
+    if "gqa4_d64_t16384" in case:
+        return (pa.supports_band_kernel(T16, 64, 64, pa.BAND_BLOCK)
+                and pa._bwd_walk(T16, 64, 64, 4, 2) == "key_first")
     if "gqa8" in case:
         return (pa.supports_band_kernel(T16, 128, 128, pa.BAND_BLOCK)
                 and (not case.endswith("bwd")
