@@ -214,17 +214,20 @@ class Mamba2Layer(Layer):
                           proj[..., 2 * di + 2 * gn:])
             xbc = jax.nn.silu(params["conv_b"]
                               + _causal_conv(xbc, params["conv_w"]))
-            xs = xbc[..., :di].reshape(b, T, h, di // h)
+            # X stays token-major, [b, T, di]: the scan's kernels read it so
+            xs = xbc[..., :di]
             groups = lambda a: a.reshape(b, T, self.n_groups,  # noqa: E731
                                          self.d_state)
             dt = jax.nn.softplus(_f32(dt) + _f32(params["dt_bias"]))
             with jax.named_scope("ssd"):
-                y = ssd_scan(xs, dt, -jnp.exp(_f32(params["A_log"])),
+                y = ssd_scan(xs.reshape(b, T, h, di // h), dt,
+                             -jnp.exp(_f32(params["A_log"])),
                              groups(xbc[..., di:di + gn]),
-                             groups(xbc[..., di + gn:]), chunk=self.chunk)
-            y = _f32(y) + _f32(params["D"])[:, None] * _f32(xs)
+                             groups(xbc[..., di + gn:]),
+                             chunk=self.chunk).reshape(b, T, di)
+            y = _f32(y) + jnp.repeat(_f32(params["D"]), di // h) * _f32(xs)
             with jax.named_scope("gated_norm"):
-                g = y.reshape(b, T, di) * jax.nn.silu(_f32(z))
+                g = y * jax.nn.silu(_f32(z))
                 g = _rms(g, params["norm"], self.eps).astype(x.dtype)
             return g @ params["W_out"], state
 

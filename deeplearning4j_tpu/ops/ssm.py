@@ -58,22 +58,28 @@ its softplus); ``A``: ``[H]`` (negative); ``B``, ``C``: ``[B, T, G, N]``;
 ``y``: ``[B, T, H, P]`` in ``x``'s dtype, without the ``D`` skip (the layer's,
 with the gate). Decays, their cumulative sums, ``exp`` and the carried state
 are float32; the products take ``x``'s dtype as operands (bfloat16 in a
-bfloat16 layer) and accumulate in float32. Chunk starts are ``[B, T/chunk, H,
-N, P]`` float32 (a head's state is kept as ``H^T``). Two implementations
-behind one ``custom_vjp``:
+bfloat16 layer) and accumulate in float32. Two implementations behind one
+``custom_vjp``:
 
 - a Pallas TPU kernel each way, both under the one name ``ssd_scan``; grid
   (batch row, group, chunk), the chunk axis sequential, every head of the
-  group in one grid step with its ``[N, P]`` float32 state in VMEM scratch.
-  ``C B^T`` is formed once a chunk for the group; a head's decay mask, its
-  intra-chunk product, the off-diagonal term and the state update follow.
-  The cumulative log-decays are XLA's (``s``, handed in beside ``dt``), and
-  the backward hands back ``ds``, whose map to ``dA`` and ``ddt`` (a reverse
-  cumulative sum in the chunk) is XLA's too. ``dB`` and ``dC`` leave the
-  kernel summed over the group's heads;
+  group in one grid step with the group's ``[N, hg*P]`` float32 state in
+  VMEM scratch. x, y, dy and dx stay token-major: the kernels view them as
+  ``[B, T, H*P]``, a chunk of a group one ``[L, hg*P]`` block, and the
+  heads are walked a pack of 128 lanes at a time (two heads of 64; one of
+  128 or wider), each head's products taking the pack's tile and a lane
+  select keeping the head's lanes. ``C B^T`` is formed once a chunk for the
+  group, ``C H_in`` once a pack; a head's decay mask, its intra-chunk
+  product and the state update follow. Chunk starts are ``[B, T/chunk, N,
+  H*P]`` float32 (a head's state kept as ``H^T``, side by side along the
+  lanes). The cumulative log-decays are XLA's (``s``, handed in beside
+  ``dt``, both ``[B, H, T]``), and the backward hands back ``ds``, whose map
+  to ``dA`` and ``ddt`` (a reverse cumulative sum in the chunk) is XLA's
+  too. ``dB`` and ``dC`` leave the kernel summed over the group's heads;
 - plain XLA (``lax.scan`` over chunks of the dual form, the backward by
-  ``jax.vjp`` of one chunk): the path off the TPU, for shapes that
-  ``supports_ssd_kernel`` refuses, and the tests' oracle for the kernel.
+  ``jax.vjp`` of one chunk; chunk starts ``[B, T/chunk, H, N, P]``): the
+  path off the TPU, for shapes that ``supports_ssd_kernel`` refuses, and
+  the tests' oracle for the kernel.
 
 ``seq/ssd_kernel`` / ``seq/ssd_fallback`` count the call sites as a step is
 traced.
@@ -82,6 +88,7 @@ traced.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -408,21 +415,25 @@ _SSD_VMEM_LIMIT = 64 * 1024 * 1024
 def supports_ssd_kernel(H: int, G: int, P: int, N: int, L: int,
                         itemsize: int) -> bool:
     """The kernels' shapes: a group's heads in whole sublane tiles, a head
-    and a state as wide as the lanes the products want, a chunk of whole
-    lane tiles; the backward's blocks (x, dy and dx double-buffered, the
-    chunk starts double-buffered, the carried cotangent) and its ``[L, L]``
-    temporaries, as counted here, within half the VMEM it asks for. The
-    count reads low — Mosaic allocates 37.4 MB where it counts 24.1 (64 heads
-    of 64 in bfloat16) and 66.2 where it counts 36.7 (in float32, refused) —
-    so the cut at half the limit is what keeps a shape it takes inside the
-    limit (``tests/test_tpu_compile_seq.py`` compiles the widest)."""
+    and a state as wide as the lanes the products want, a group of whole
+    128-lane tiles (a chunk of it is one token-major block ``[L, hg*P]``), a
+    chunk of whole lane tiles; the backward's blocks (x, dy and dx
+    double-buffered, the chunk starts double-buffered, the carried
+    cotangent) and its ``[L, L]`` temporaries, as counted here, within three
+    quarters of the VMEM it asks for. Mosaic allocates within 2 MiB of the
+    count at 16,384 steps (24 MiB where it counts 23 for 64 heads of 64 in
+    bfloat16, 37 where it counts 35 in float32, 67 where it counts 65 for 128
+    heads in float32, which is refused); the quarter left over is for what
+    the count leaves out (``tests/test_tpu_compile_seq.py`` compiles the
+    widest groups it takes)."""
     if H % G:
         return False
     hg = H // G
     blocks = (2 * 3 * hg * L * P * itemsize + 3 * hg * N * P * 4
               + 16 * L * L * 4 + 8 * L * N * 4)
-    return (hg % 8 == 0 and P % 64 == 0 and N % 128 == 0 and L % 128 == 0
-            and blocks <= _SSD_VMEM_LIMIT // 2)
+    return (hg % 8 == 0 and P % 64 == 0 and (hg * P) % 128 == 0
+            and N % 128 == 0 and L % 128 == 0
+            and blocks <= _SSD_VMEM_LIMIT * 3 // 4)
 
 
 def _ssd_chunk_xla(h, x, dt, A, B, C):
@@ -480,10 +491,18 @@ def _ssd_bwd_xla(x, dt, A, B, C, starts, dy, L):
     return (_unchunk(dx), _unchunk(ddt), dA, _unchunk(dB), _unchunk(dC))
 
 
-# The kernels trace in the 32-bit world as the selective scan's. Per grid
-# step (batch row, group, chunk) the heads of the group are walked in blocks
-# of ``sub`` (a sublane tile of their ``dt`` and ``s`` rows), a head at a
-# time. ``s`` is the chunk's cumulative log-decay as a row ``[1, L]``; its
+# The kernels trace in the 32-bit world as the selective scan's. They read
+# and write x, y, dy and dx in the caller's token-major layout ``[b, T, H*P]``:
+# a chunk of a group is one ``[L, hg*P]`` block, and the chunk starts are
+# ``[N, hg*P]`` a group. Per grid step (batch row, group, chunk) the heads of
+# the group are walked in blocks of ``sub`` (a sublane tile of their ``dt``
+# and ``s`` rows), a pack at a time: a pack is the fewest heads that fill
+# whole 128-lane tiles (two heads of 64, one of 128 or wider). Each head's
+# products take the pack's whole tile as their operand, which costs the
+# matrix unit no more passes than the head's own lanes, and a lane select
+# keeps the head's lanes of the result; the products that every head of a
+# pack shares (``C H_in``, ``dC``, ``C^T`` times the cotangent) run once a
+# pack. ``s`` is the chunk's cumulative log-decay as a row ``[1, L]``; its
 # column ``[L, 1]`` is taken on the diagonal (a select and a lane sum), so
 # nothing is transposed in the kernel.
 
@@ -509,8 +528,29 @@ def _ssd_head(s_row, eye, causal):
     return s_col, decay, s_last
 
 
+def _ssd_pack(hg, P):
+    """Heads a pack: the fewest whole heads that fill whole 128-lane tiles
+    (two of 64, one of 128 or wider), at most the group's (the tests' narrow
+    heads in interpret mode)."""
+    return min(hg, 128 // math.gcd(P, 128))
+
+
+def _ssd_walk(hg, P):
+    """(heads a block of the walk, heads a pack): a block is a sublane tile
+    of ``dt`` and ``s`` rows, or the whole group where 8 do not divide it,
+    and whole packs."""
+    k = _ssd_pack(hg, P)
+    sub = max(8, k)
+    return (sub if hg % sub == 0 else hg), k
+
+
+def _ssd_lanes(base, j, P, k):
+    """The lanes of pack ``j`` of the block whose first head is ``base``."""
+    return pl.ds(pl.multiple_of(base * P + j * k * P, k * P), k * P)
+
+
 def _ssd_fwd_kernel(x_ref, dt_ref, s_ref, bt_ref, c_ref, y_ref, start_ref,
-                    h_scr, *, sub: int):
+                    h_scr, *, sub: int, P: int, k: int):
     f32 = jnp.float32
     hg, L = dt_ref.shape[1], dt_ref.shape[2]
     op = x_ref.dtype
@@ -523,24 +563,34 @@ def _ssd_fwd_kernel(x_ref, dt_ref, s_ref, bt_ref, c_ref, y_ref, start_ref,
     bt, c = bt_ref[0, 0], c_ref[0, 0]                       # [N, L], [L, N]
     cb = jnp.dot(c, bt, preferred_element_type=f32)         # [L, L]
     causal, eye = _ssd_masks(L)
+    owner = lax.broadcasted_iota(jnp.int32, (1, k * P), 1) // P
 
     def block(q, carry):
         base = pl.multiple_of(q * sub, sub)
         s_blk = s_ref[0, pl.ds(base, sub), :]
         dt_blk = dt_ref[0, pl.ds(base, sub), :]
-        for i in range(sub):
-            h = base + i
-            s_row, dt_row = s_blk[i:i + 1], dt_blk[i:i + 1]
-            s_col, decay, s_last = _ssd_head(s_row, eye, causal)
-            xh, h0 = x_ref[0, h], h_scr[h]                  # [L, P], [N, P]
-            y = (jnp.dot((cb * decay * dt_row).astype(op), xh,
-                         preferred_element_type=f32)
-                 + jnp.exp(s_col) * jnp.dot(c, h0.astype(op),
-                                            preferred_element_type=f32))
-            y_ref[0, h] = y.astype(y_ref.dtype)
-            w = jnp.exp(s_last - s_row) * dt_row             # [1, L]
-            h_scr[h] = jnp.exp(s_last) * h0 + jnp.dot(
-                (bt * w).astype(op), xh, preferred_element_type=f32)
+        for j in range(sub // k):
+            lanes = _ssd_lanes(base, j, P, k)
+            xp, h0 = x_ref[0, :, lanes], h_scr[:, lanes]    # [L, W], [N, W]
+            z = jnp.dot(c, h0.astype(op), preferred_element_type=f32)
+            y = h1 = None
+            for i in range(k):
+                r = j * k + i
+                s_row, dt_row = s_blk[r:r + 1], dt_blk[r:r + 1]
+                s_col, decay, s_last = _ssd_head(s_row, eye, causal)
+                y_i = (jnp.dot((cb * decay * dt_row).astype(op), xp,
+                               preferred_element_type=f32)
+                       + jnp.exp(s_col) * z)
+                w = jnp.exp(s_last - s_row) * dt_row         # [1, L]
+                h1_i = jnp.exp(s_last) * h0 + jnp.dot(
+                    (bt * w).astype(op), xp, preferred_element_type=f32)
+                if i == 0:
+                    y, h1 = y_i, h1_i
+                else:
+                    y = jnp.where(owner == i, y_i, y)
+                    h1 = jnp.where(owner == i, h1_i, h1)
+            y_ref[0, :, lanes] = y.astype(y_ref.dtype)
+            h_scr[:, lanes] = h1
         return carry
 
     lax.fori_loop(0, hg // sub, block, 0)
@@ -548,10 +598,13 @@ def _ssd_fwd_kernel(x_ref, dt_ref, s_ref, bt_ref, c_ref, y_ref, start_ref,
 
 def _ssd_bwd_kernel(x_ref, dt_ref, s_ref, bt_ref, c_ref, start_ref, dy_ref,
                     dx_ref, ddt_ref, ds_ref, dbt_ref, dc_ref,
-                    dh_scr, dcb_scr, dc_scr, dbt_scr, *, sub: int):
+                    dh_scr, dcb_scr, dc_scr, dbt_scr, *, sub: int, P: int,
+                    k: int):
     """Chunks arrive last first; ``dh_scr`` carries each head's cotangent of
     the state the chunk hands on. ``dcb_scr``, ``dc_scr``, ``dbt_scr`` sum
-    the group's heads' parts of ``d(C B^T)``, ``dC`` and ``dB^T``."""
+    the group's heads' parts of ``d(C B^T)``, ``dC`` and ``dB^T``. A head's
+    contractions over its own lanes (``dY X^T``, ``dH X^T``) take its lanes
+    of ``dy`` and ``dh`` with the pack's other lanes zeroed."""
     f32 = jnp.float32
     hg, L = dt_ref.shape[1], dt_ref.shape[2]
     op = x_ref.dtype
@@ -568,6 +621,9 @@ def _ssd_bwd_kernel(x_ref, dt_ref, s_ref, bt_ref, c_ref, start_ref, dy_ref,
     causal, eye = _ssd_masks(L)
     lane = lax.broadcasted_iota(jnp.int32, (1, L), 1)
     row_of = lax.broadcasted_iota(jnp.int32, (sub, L), 0)
+    owner = lax.broadcasted_iota(jnp.int32, (1, k * P), 1) // P
+    only = lambda a, i: a if k == 1 else jnp.where(      # noqa: E731
+        owner == i, a, jnp.zeros_like(a))
 
     def block(q, carry):
         base = pl.multiple_of(q * sub, sub)
@@ -575,56 +631,69 @@ def _ssd_bwd_kernel(x_ref, dt_ref, s_ref, bt_ref, c_ref, start_ref, dy_ref,
         dt_blk = dt_ref[0, pl.ds(base, sub), :]
         ds_rows = jnp.zeros((sub, L), f32)
         ddt_rows = jnp.zeros((sub, L), f32)
-        for i in range(sub):
-            h = base + i
-            s_row, dt_row = s_blk[i:i + 1], dt_blk[i:i + 1]
-            s_col, decay, s_last = _ssd_head(s_row, eye, causal)
-            e_last = jnp.exp(s_last)
-            cbm = cb * decay
-            scores = cbm * dt_row
-            xh, dyh = x_ref[0, h], dy_ref[0, h]            # [L, P]
-            h0, dh1 = start_ref[0, 0, h], dh_scr[h]       # [N, P]
-            w = jnp.exp(s_last - s_row) * dt_row           # [1, L]
-            # the intra-chunk product and its scores
-            dsc = lax.dot_general(dyh, xh, _NT, preferred_element_type=f32)
-            btw = (bt * w).astype(op)
-            dx = (lax.dot_general(scores.astype(op), dyh, _TN,
-                                  preferred_element_type=f32)
-                  + lax.dot_general(btw, dh1.astype(op), _TN,
-                                    preferred_element_type=f32))
-            dx_ref[0, h] = dx.astype(dx_ref.dtype)
-            both = dsc * scores
-            ds_col = jnp.sum(both, axis=1, keepdims=True)
-            ds_row = -jnp.sum(both, axis=0, keepdims=True)
-            ddt_row = jnp.sum(dsc * cbm, axis=0, keepdims=True)
-            dcb_scr[...] += dsc * decay * dt_row
-            # the state coming in: exp(s_t) C_t H_in
-            e_col = jnp.exp(s_col)
+        for j in range(sub // k):
+            lanes = _ssd_lanes(base, j, P, k)
+            xp, dyp = x_ref[0, :, lanes], dy_ref[0, :, lanes]     # [L, W]
+            h0, dh1 = start_ref[0, 0, :, lanes], dh_scr[:, lanes]  # [N, W]
             z = jnp.dot(c, h0.astype(op), preferred_element_type=f32)
-            ds_col = ds_col + e_col * jnp.sum(dyh.astype(f32) * z, axis=1,
-                                              keepdims=True)
-            ey = (e_col * dyh).astype(op)
+            dh1_op, dh1_h0 = dh1.astype(op), dh1 * h0
+            dx = ey = e_last_w = None
+            for i in range(k):
+                r = j * k + i
+                s_row, dt_row = s_blk[r:r + 1], dt_blk[r:r + 1]
+                s_col, decay, s_last = _ssd_head(s_row, eye, causal)
+                e_last = jnp.exp(s_last)
+                cbm = cb * decay
+                scores = cbm * dt_row
+                w = jnp.exp(s_last - s_row) * dt_row       # [1, L]
+                dyh = only(dyp, i)
+                # the intra-chunk product and its scores
+                dsc = lax.dot_general(dyh, xp, _NT, preferred_element_type=f32)
+                btw = (bt * w).astype(op)
+                dx_i = (lax.dot_general(scores.astype(op), dyp, _TN,
+                                        preferred_element_type=f32)
+                        + lax.dot_general(btw, dh1_op, _TN,
+                                          preferred_element_type=f32))
+                both = dsc * scores
+                ds_col = jnp.sum(both, axis=1, keepdims=True)
+                ds_row = -jnp.sum(both, axis=0, keepdims=True)
+                ddt_row = jnp.sum(dsc * cbm, axis=0, keepdims=True)
+                dcb_scr[...] += dsc * decay * dt_row
+                # the state coming in: exp(s_t) C_t H_in
+                e_col = jnp.exp(s_col)
+                ds_col = ds_col + e_col * jnp.sum(dyh.astype(f32) * z, axis=1,
+                                                  keepdims=True)
+                ey_i = e_col * dyh
+                # the state going out: exp(s_L) H_in + (B^T w) X
+                v = lax.dot_general(only(dh1_op, i), xp, _NT,
+                                    preferred_element_type=f32)  # [N, L]
+                dbt_scr[...] += w * v
+                dw = jnp.sum(bt.astype(f32) * v, axis=0, keepdims=True)
+                ddt_row = ddt_row + dw * jnp.exp(s_last - s_row)
+                ds_row = ds_row - dw * w
+                ds_last = (jnp.sum(dw * w, axis=1, keepdims=True)
+                           + e_last * jnp.sum(jnp.sum(only(dh1_h0, i), axis=1,
+                                                      keepdims=True),
+                                              axis=0, keepdims=True))
+                ds_row = (ds_row
+                          + jnp.sum(jnp.where(eye, ds_col, 0.0), axis=0,
+                                    keepdims=True)
+                          + jnp.where(lane == L - 1, ds_last, 0.0))
+                ds_rows = jnp.where(row_of == r, ds_row, ds_rows)
+                ddt_rows = jnp.where(row_of == r, ddt_row, ddt_rows)
+                if i == 0:
+                    dx, ey, e_last_w = dx_i, ey_i, e_last
+                else:
+                    dx = jnp.where(owner == i, dx_i, dx)
+                    ey = ey + ey_i
+                    e_last_w = jnp.where(owner == i, e_last, e_last_w)
+            dx_ref[0, :, lanes] = dx.astype(dx_ref.dtype)
+            # the pack's heads at once: their lanes of e_col dY are disjoint
+            ey = ey.astype(op)
             dc_scr[...] += lax.dot_general(ey, h0.astype(op), _NT,
                                            preferred_element_type=f32)
-            dh_scr[h] = e_last * dh1 + lax.dot_general(
+            dh_scr[:, lanes] = e_last_w * dh1 + lax.dot_general(
                 c, ey, _TN, preferred_element_type=f32)
-            # the state going out: exp(s_L) H_in + (B^T w) X
-            v = lax.dot_general(dh1.astype(op), xh, _NT,
-                                preferred_element_type=f32)  # [N, L]
-            dbt_scr[...] += w * v
-            dw = jnp.sum(bt.astype(f32) * v, axis=0, keepdims=True)
-            ddt_row = ddt_row + dw * jnp.exp(s_last - s_row)
-            ds_row = ds_row - dw * w
-            ds_last = (jnp.sum(dw * w, axis=1, keepdims=True)
-                       + e_last * jnp.sum(jnp.sum(dh1 * h0, axis=1,
-                                                  keepdims=True),
-                                          axis=0, keepdims=True))
-            ds_row = (ds_row
-                      + jnp.sum(jnp.where(eye, ds_col, 0.0), axis=0,
-                                keepdims=True)
-                      + jnp.where(lane == L - 1, ds_last, 0.0))
-            ds_rows = jnp.where(row_of == i, ds_row, ds_rows)
-            ddt_rows = jnp.where(row_of == i, ddt_row, ddt_rows)
         ds_ref[0, pl.ds(base, sub), :] = ds_rows
         ddt_ref[0, pl.ds(base, sub), :] = ddt_rows
         return carry
@@ -638,19 +707,16 @@ def _ssd_bwd_kernel(x_ref, dt_ref, s_ref, bt_ref, c_ref, start_ref, dy_ref,
 
 
 def _ssd_layouts(x, dt, A, B, C, L):
-    """The kernels' operands: x ``[b, H, T, P]``; dt and the cumulative
-    log-decay within each chunk ``[b, H, T]`` float32; ``B^T`` ``[b, G, N,
-    T]`` and C ``[b, G, T, N]`` in x's dtype."""
-    b, T, H, _ = x.shape
+    """The kernels' operands: x as ``[b, T, H*P]`` (a view of the caller's
+    row-major array: no copy); dt and the cumulative log-decay within each
+    chunk ``[b, H, T]`` float32; ``B^T`` ``[b, G, N, T]`` and C ``[b, G, T,
+    N]`` in x's dtype."""
+    b, T, H, P = x.shape
     a = (dt * A).reshape(b, T // L, L, H)
     s = jnp.cumsum(a, axis=2).reshape(b, T, H)
-    return (x.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1),
+    return (x.reshape(b, T, H * P), dt.transpose(0, 2, 1),
             s.transpose(0, 2, 1), B.astype(x.dtype).transpose(0, 2, 3, 1),
             C.astype(x.dtype).transpose(0, 2, 1, 3))
-
-
-def _ssd_sub(hg):
-    return 8 if hg % 8 == 0 else hg
 
 
 def _ssd_compiler_params():
@@ -667,25 +733,26 @@ def _ssd_fwd_pallas(x, dt, A, B, C, L, interpret):
     b, T, H, P = x.shape
     G, N = B.shape[2:]
     hg, nc = H // G, T // L
+    sub, k = _ssd_walk(hg, P)
     f32 = jnp.float32
-    heads = pl.BlockSpec((1, hg, L, P), lambda i, g, c: (i, g, c, 0))
+    chunk = pl.BlockSpec((1, L, hg * P), lambda i, g, c: (i, c, g))
     rows = pl.BlockSpec((1, hg, L), lambda i, g, c: (i, g, c))
     with jax.enable_x64(False):
         y, starts = pl.pallas_call(
-            functools.partial(_ssd_fwd_kernel, sub=_ssd_sub(hg)),
+            functools.partial(_ssd_fwd_kernel, sub=sub, P=P, k=k),
             grid=(b, G, nc),
-            in_specs=[heads, rows, rows,
+            in_specs=[chunk, rows, rows,
                       pl.BlockSpec((1, 1, N, L), lambda i, g, c: (i, g, 0, c)),
                       pl.BlockSpec((1, 1, L, N), lambda i, g, c: (i, g, c, 0))],
-            out_specs=[heads, pl.BlockSpec((1, 1, hg, N, P),
-                                           lambda i, g, c: (i, c, g, 0, 0))],
-            out_shape=[jax.ShapeDtypeStruct((b, H, T, P), x.dtype),
-                       jax.ShapeDtypeStruct((b, nc, H, N, P), f32)],
-            scratch_shapes=[pltpu.VMEM((hg, N, P), f32)],
+            out_specs=[chunk, pl.BlockSpec((1, 1, N, hg * P),
+                                           lambda i, g, c: (i, c, 0, g))],
+            out_shape=[jax.ShapeDtypeStruct((b, T, H * P), x.dtype),
+                       jax.ShapeDtypeStruct((b, nc, N, H * P), f32)],
+            scratch_shapes=[pltpu.VMEM((N, hg * P), f32)],
             compiler_params=None if interpret else _ssd_compiler_params(),
             interpret=interpret, name="ssd_scan",
         )(*_ssd_layouts(x, dt, A, B, C, L))
-    return y.transpose(0, 2, 1, 3), starts
+    return y.reshape(b, T, H, P), starts
 
 
 def _ssd_bwd_pallas(x, dt, A, B, C, starts, dy, L, interpret):
@@ -694,39 +761,40 @@ def _ssd_bwd_pallas(x, dt, A, B, C, starts, dy, L, interpret):
     b, T, H, P = x.shape
     G, N = B.shape[2:]
     hg, nc = H // G, T // L
+    sub, k = _ssd_walk(hg, P)
     f32 = jnp.float32
     # grid step c works on chunk nc-1-c
-    heads = pl.BlockSpec((1, hg, L, P), lambda i, g, c: (i, g, nc - 1 - c, 0))
+    chunk = pl.BlockSpec((1, L, hg * P), lambda i, g, c: (i, nc - 1 - c, g))
     rows = pl.BlockSpec((1, hg, L), lambda i, g, c: (i, g, nc - 1 - c))
     bt_spec = pl.BlockSpec((1, 1, N, L), lambda i, g, c: (i, g, 0, nc - 1 - c))
     c_spec = pl.BlockSpec((1, 1, L, N), lambda i, g, c: (i, g, nc - 1 - c, 0))
     with jax.enable_x64(False):
         dx, ddt, ds, dbt, dc = pl.pallas_call(
-            functools.partial(_ssd_bwd_kernel, sub=_ssd_sub(hg)),
+            functools.partial(_ssd_bwd_kernel, sub=sub, P=P, k=k),
             grid=(b, G, nc),
-            in_specs=[heads, rows, rows, bt_spec, c_spec,
-                      pl.BlockSpec((1, 1, hg, N, P),
-                                   lambda i, g, c: (i, nc - 1 - c, g, 0, 0)),
-                      heads],
-            out_specs=[heads, rows, rows, bt_spec, c_spec],
-            out_shape=[jax.ShapeDtypeStruct((b, H, T, P), x.dtype),
+            in_specs=[chunk, rows, rows, bt_spec, c_spec,
+                      pl.BlockSpec((1, 1, N, hg * P),
+                                   lambda i, g, c: (i, nc - 1 - c, 0, g)),
+                      chunk],
+            out_specs=[chunk, rows, rows, bt_spec, c_spec],
+            out_shape=[jax.ShapeDtypeStruct((b, T, H * P), x.dtype),
                        jax.ShapeDtypeStruct((b, H, T), f32),
                        jax.ShapeDtypeStruct((b, H, T), f32),
                        jax.ShapeDtypeStruct((b, G, N, T), f32),
                        jax.ShapeDtypeStruct((b, G, T, N), f32)],
-            scratch_shapes=[pltpu.VMEM((hg, N, P), f32),
+            scratch_shapes=[pltpu.VMEM((N, hg * P), f32),
                             pltpu.VMEM((L, L), f32),
                             pltpu.VMEM((L, N), f32),
                             pltpu.VMEM((N, L), f32)],
             compiler_params=None if interpret else _ssd_compiler_params(),
             interpret=interpret, name="ssd_scan",
         )(*_ssd_layouts(x, dt, A, B, C, L), starts,
-          dy.astype(x.dtype).transpose(0, 2, 1, 3))
+          dy.astype(x.dtype).reshape(b, T, H * P))
     # s_t = sum_{i<=t} dt_i A in the chunk: ds flows back to every earlier
     # step of the chunk, and from there to dt and A
     da = ds.transpose(0, 2, 1).reshape(b, nc, L, H)
     da = jnp.flip(jnp.cumsum(jnp.flip(da, 2), axis=2), 2).reshape(b, T, H)
-    return (dx.transpose(0, 2, 1, 3), ddt.transpose(0, 2, 1) + da * A,
+    return (dx.reshape(b, T, H, P), ddt.transpose(0, 2, 1) + da * A,
             jnp.sum(da * dt, axis=(0, 1)),
             dbt.transpose(0, 3, 1, 2).astype(B.dtype),
             dc.transpose(0, 2, 1, 3).astype(C.dtype))
@@ -776,7 +844,8 @@ def ssd_scan(x, dt, A, B, C, chunk: Optional[int] = None,
         kernel = (Environment.get().allow_pallas()
                   and jax.default_backend() == "tpu" and fits)
     else:
-        kernel = bool(interpret) and H % G == 0 and wide == jnp.float32
+        kernel = (bool(interpret) and H % G == 0 and wide == jnp.float32
+                  and (H // G) % _ssd_pack(H // G, P) == 0)
     OpProfiler.get().count("seq/ssd_kernel" if kernel else "seq/ssd_fallback")
     dt, A = dt.astype(wide), A.astype(wide)
     pad = -t % L

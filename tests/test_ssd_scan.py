@@ -53,6 +53,10 @@ CASES = {       # (batch, T, heads, head width, groups, state, chunk)
     "t_not_a_multiple": (1, 40, 4, 8, 1, 16, 16),
     "heads_share_a_group": (1, 48, 8, 16, 1, 16, 16),
     "two_groups_two_rows": (2, 48, 4, 8, 2, 16, 16),
+    # the kernels' lane walk: three pairs of 64-wide heads in a 128-lane
+    # tile each, and heads of 128 one to a tile
+    "three_pairs_of_64": (1, 48, 6, 64, 1, 16, 16),
+    "heads_of_128": (1, 48, 4, 128, 1, 16, 16),
 }
 
 
@@ -139,13 +143,28 @@ def test_each_call_site_is_counted_by_its_path():
 
 def test_the_kernel_shapes_it_supports():
     """A group's heads in whole sublane tiles, heads of 64 lanes or more, a
-    state of whole lane tiles and a chunk of them: the cell's 64 heads of 64
-    on one group of 128 at chunks of 256 are taken in bfloat16; in float32
-    their blocks outgrow the VMEM budget."""
+    group of whole 128-lane tiles, a state of whole lane tiles and a chunk of
+    them: the cell's 64 heads of 64 on one group of 128 at chunks of 256 are
+    taken in bfloat16 and, since the token-major blocks carry no padded
+    64-lane head, in float32; 128 heads in float32 outgrow the VMEM
+    budget."""
     assert ssm.supports_ssd_kernel(64, 1, 64, 128, 256, 2)
-    assert not ssm.supports_ssd_kernel(64, 1, 64, 128, 256, 4)
+    assert ssm.supports_ssd_kernel(64, 1, 64, 128, 256, 4)
+    assert not ssm.supports_ssd_kernel(128, 1, 64, 128, 256, 4)
     assert ssm.supports_ssd_kernel(32, 1, 64, 128, 256, 4)
+    assert ssm.supports_ssd_kernel(16, 1, 128, 128, 256, 2)
     assert not ssm.supports_ssd_kernel(64, 3, 64, 128, 256, 2)
     assert not ssm.supports_ssd_kernel(4, 1, 64, 128, 256, 2)
+    assert not ssm.supports_ssd_kernel(3, 1, 64, 128, 256, 2)
     assert not ssm.supports_ssd_kernel(64, 1, 64, 16, 256, 2)
     assert not ssm.supports_ssd_kernel(64, 1, 64, 128, 96, 2)
+
+
+def test_a_pack_fills_whole_lane_tiles():
+    """Two heads of 64 share a 128-lane tile, heads of 128 or wider take
+    their own; the tests' narrow heads pack up to the group."""
+    assert ssm._ssd_walk(64, 64) == (8, 2)
+    assert ssm._ssd_walk(6, 64) == (6, 2)
+    assert ssm._ssd_walk(16, 128) == (8, 1)
+    assert ssm._ssd_walk(8, 16) == (8, 8)
+    assert ssm._ssd_walk(4, 8) == (4, 4)

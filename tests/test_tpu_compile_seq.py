@@ -17,13 +17,16 @@ resident in VMEM); and at the shapes of ``granite_4_h_micro.train_s16k``:
 the state-space dual scan forward and backward (``ops/ssm.ssd_scan``: 16,384
 steps, 64 heads of 64 on one B/C group, a state of 128, chunks of 256; the
 widest groups ``supports_ssd_kernel`` takes, and the float32 group it
-refuses failing for want of VMEM) and the attention with 32 query heads of 64 over 8 key/value heads at the
+refuses failing for want of VMEM), the Mamba-2 mixer around it with no
+layout copy of x, y or their cotangents between the layer and the kernels,
+and the attention with 32 query heads of 64 over 8 key/value heads at the
 family's softmax scale. As ``tests/test_tpu_compile.py``:
 the compiler is installed with jax and compiles for a chip that is DESCRIBED,
 not attached; a compile that passes is not a chip run.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -229,10 +232,14 @@ def _gqa8(window, grad=False):
 CASES = {
     "ssd_scan_fwd": (lambda: _ssd(False), 1),
     "ssd_scan_fwd_bwd": (lambda: _ssd(True), 2),
-    # the widest groups supports_ssd_kernel takes at these widths
     "ssd_scan_fwd_bwd_96_heads_bf16": (lambda: _ssd(True, 96, t=4096), 2),
     "ssd_scan_fwd_bwd_56_heads_f32": (
         lambda: _ssd(True, 56, jnp.float32, 4096), 2),
+    # the cell's group in float32: its token-major blocks pad no head
+    "ssd_scan_fwd_bwd_64_heads_f32": (lambda: _ssd(True, 64, jnp.float32), 2),
+    # the widest groups supports_ssd_kernel takes at these widths
+    "ssd_scan_fwd_bwd_152_heads_bf16": (lambda: _ssd(True, 152), 2),
+    "ssd_scan_fwd_bwd_88_heads_f32": (lambda: _ssd(True, 88, jnp.float32), 2),
     "attention_gqa4_d64_t16384_nope_fwd_bwd": (lambda: _gqa4_nope(True), 2),
     "attention_gqa8_d128_t16384_full_fwd": (lambda: _gqa8(None), 1),
     "attention_gqa8_d128_t16384_window_fwd": (lambda: _gqa8(2048), 1),
@@ -321,21 +328,73 @@ def test_sequence_kernel_compiles_for_v5e(case, v5e):
 
 
 def test_the_ssd_shape_it_refuses_does_not_fit_vmem(v5e):
-    """64 heads of 64 in float32: ``supports_ssd_kernel`` refuses them, and
-    the backward kernel would need 66.2 MB of scoped VMEM against the 64 it
-    asks for (its estimate reads low; its cut at half the limit keeps every
-    shape it takes inside, as the two widest cases above compile)."""
-    assert not ssm.supports_ssd_kernel(64, 1, 64, 128, ssm.SSD_CHUNK, 4)
-    fn, shapes = _ssd(True, 64, jnp.float32, 4096)
+    """128 heads of 64 in float32: ``supports_ssd_kernel`` refuses them, and
+    the backward kernel would need 66.8 MB of scoped VMEM against the 64 it
+    asks for (its count reads within 2 MiB of Mosaic's; its cut at three
+    quarters of the limit keeps every shape it takes inside, as the widest
+    cases above compile)."""
+    assert not ssm.supports_ssd_kernel(128, 1, 64, 128, ssm.SSD_CHUNK, 4)
+    fn, shapes = _ssd(True, 128, jnp.float32, 4096)
     args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
     with pytest.raises(Exception, match="vmem"):
         jax.jit(fn).lower(*args).compile()
 
 
+def _big_copies(text, scopes, least=64 * 2 ** 20):
+    """The compiled module's ``copy`` instructions of ``least`` bytes or more
+    whose ``op_name`` holds one of ``scopes``: (bytes, shape, op_name)."""
+    size = {"bf16": 2, "f32": 4}
+    found = []
+    for m in re.finditer(r"= (\w+)\[([\d,]+)\]\{[^}]*\} copy\(.*?"
+                         r'op_name="([^"]*)"', text):
+        dtype, dims, name = m.groups()
+        n = size.get(dtype, 4)
+        for d in dims.split(","):
+            n *= int(d)
+        if n >= least and any(s in name for s in scopes):
+            found.append((n, f"{dtype}[{dims}]", name))
+    return found
+
+
+def test_the_mamba2_mixer_keeps_the_token_major_layout(v5e, monkeypatch):
+    """One ``Mamba2Layer`` at granite_4_h_micro's widths (16,384 tokens, d
+    2048, 64 heads of 64, state 128, bfloat16 operands), value and gradient
+    under ``jax.checkpoint`` as per-vertex remat runs it: the scan's kernels
+    read x and dy and write y and dx in the layer's ``[b, T, H*P]`` layout,
+    so no copy of 64 MiB or more turns them head-major and back (the
+    head-major kernels cost ten such copies a mixer, 2.5 GiB written)."""
+    from deeplearning4j_tpu.common.profiler import OpProfiler
+    from deeplearning4j_tpu.nn.conf import layers_seq
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = layers_seq.Mamba2Layer(d_inner=4096, n_heads=64, d_state=128,
+                                   n_groups=1, d_conv=4)
+    layer.n_in = 2048
+    shapes = jax.eval_shape(lambda: layer.init_params(jax.random.key(0)))
+    params = {k: jax.ShapeDtypeStruct(
+        v.shape, jnp.float32 if k in layer.full_precision_params
+        else jnp.bfloat16, sharding=v5e) for k, v in shapes.items()}
+    x = jax.ShapeDtypeStruct((1, T16, 2048), jnp.bfloat16, sharding=v5e)
+
+    def loss(p, x):
+        run = jax.checkpoint(lambda p, x: layer.apply(p, x, None, True,
+                                                      None)[0])
+        return run(p, x).astype(jnp.float32).sum()
+
+    before = OpProfiler.get().counter_value("seq/ssd_kernel")
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    assert OpProfiler.get().counter_value("seq/ssd_kernel") > before
+    assert text.count("tpu_custom_call") >= 3
+    assert _big_copies(text, ("mamba2/ssd", "mamba2/reshape", "mamba2/add",
+                              "gated_norm/reshape")) == []
+
+
 def supports(case):
     if case.startswith("ssd_scan"):
-        heads, itemsize = ((56, 4) if case.endswith("f32") else
-                           (96, 2) if case.endswith("bf16") else (64, 2))
+        m = re.search(r"_(\d+)_heads_(bf16|f32)$", case)
+        heads, itemsize = ((int(m.group(1)), 2 if m.group(2) == "bf16" else 4)
+                           if m else (64, 2))
         return ssm.supports_ssd_kernel(heads, 1, 64, 128, ssm.SSD_CHUNK,
                                        itemsize)
     if "gqa4_d64_t16384" in case:
